@@ -29,10 +29,6 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from xllm_service_tpu.utils import pin_cpu_platform_if_requested
-
-pin_cpu_platform_if_requested()
-
 import json
 import os
 import statistics

@@ -1,4 +1,4 @@
-"""Context-parallel paged-decode kernel benchmark (VERDICT r3 weak #2).
+"""Context-parallel paged-decode kernel benchmark.
 
 On the one real chip this Mosaic-validates the CP partial-stats Pallas
 kernel (ops/cp_paged_attention.py) and A/Bs three bodies at bench-1b
@@ -24,10 +24,6 @@ import time
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-
-from xllm_service_tpu.utils import pin_cpu_platform_if_requested
-
-pin_cpu_platform_if_requested()
 
 
 def _time(fn, *args, iters=50):
@@ -56,19 +52,18 @@ def main() -> None:
         paged_attention_pallas,
     )
 
-    backend = jax.default_backend()
-    on_accel = backend != "cpu"
+    from _chip import require_tpu
+
+    device = require_tpu()
 
     # bench-1b attention shapes (models/base.py bench_1b_config).
-    B, n_q, n_kv, hd, ps = (16, 16, 8, 128, 16) if on_accel \
-        else (4, 4, 2, 32, 16)
-    ctx = int(os.environ.get("XLLM_CP_CTX", "0")) or \
-        (2048 if on_accel else 128)
+    B, n_q, n_kv, hd, ps = 16, 16, 8, 128, 16
+    ctx = int(os.environ.get("XLLM_CP_CTX", "0")) or 2048
     if ctx > 8192:
         B = max(2, B // 4)   # keep the pool inside one chip's HBM
     pages_per_seq = ctx // ps
     num_pages = B * pages_per_seq + 64
-    dtype = jnp.bfloat16 if on_accel else jnp.float32
+    dtype = jnp.bfloat16
 
     rng = np.random.default_rng(0)
     key = jax.random.PRNGKey(0)
@@ -84,17 +79,13 @@ def main() -> None:
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("seq",))
 
-    result = {"backend": backend, "B": B, "ctx": ctx,
+    result = {"device": device, "B": B, "ctx": ctx,
               "metric": "cp_decode_attention_ms_per_step", "unit": "ms"}
 
     # 1. single-device decode kernel (reference point).
-    if on_accel:
-        single = jax.jit(paged_attention_pallas)
-        try:
-            result["single_device_kernel_ms"] = round(
-                _time(single, q, k_pages, v_pages, page_table, clens), 4)
-        except Exception as e:  # noqa: BLE001 — record, keep going
-            result["single_device_kernel_error"] = str(e)[:300]
+    single = jax.jit(paged_attention_pallas)
+    result["single_device_kernel_ms"] = round(
+        _time(single, q, k_pages, v_pages, page_table, clens), 4)
 
     # 2. CP Pallas partial kernel (Mosaic on accel; the validation target).
     def cp(qq, kk, vv, tt, cc):

@@ -2,8 +2,8 @@
 
 Compares the current per-layer `jnp.stack + dynamic_update_index_in_dim`
 pool writeback against a direct full-pool scatter
-(`kv.at[l, :, page_idx, :, slot, :]`). Run on CPU for structure (alias
-analysis) and on TPU for truth.
+(`kv.at[l, :, page_idx, :, slot, :]`). Needs the TPU: a CPU timing of a
+device writeback says nothing.
 """
 
 from __future__ import annotations
@@ -12,10 +12,6 @@ import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-
-from xllm_service_tpu.utils import pin_cpu_platform_if_requested
-
-pin_cpu_platform_if_requested()
 
 import time
 
@@ -26,6 +22,9 @@ from functools import partial
 
 
 def run(L=4, pages=1024, n_kv=4, ps=16, hd=64, B=8, steps=30):
+    from _chip import require_tpu
+
+    device = require_tpu()
     rng = np.random.default_rng(0)
     kv = jnp.zeros((L, 2, pages, n_kv, ps, hd), jnp.float32)
     k = jnp.asarray(rng.normal(size=(B, n_kv, hd)), jnp.float32)
@@ -66,7 +65,7 @@ def run(L=4, pages=1024, n_kv=4, ps=16, hd=64, B=8, steps=30):
         import json
         print(json.dumps({"variant": name, "ms_per_step": round(dt * 1e3, 3),
                           "pool_mb": round(pool.nbytes / 1e6),
-                          "backend": jax.default_backend()}))
+                          "device": device}))
 
 
 if __name__ == "__main__":

@@ -14,27 +14,24 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from xllm_service_tpu.utils import pin_cpu_platform_if_requested
-
-pin_cpu_platform_if_requested()
-
 
 def main() -> None:
     import jax
-    import jax.numpy as jnp
 
     from xllm_service_tpu.common.request import SamplingParams
     from xllm_service_tpu.engine.config import EngineConfig
     from xllm_service_tpu.engine.engine import EngineRequest, InferenceEngine
-    from xllm_service_tpu.models.base import bench_1b_config, tiny_config
+    from xllm_service_tpu.models.base import bench_1b_config
 
-    on_accel = jax.default_backend() != "cpu"
-    mcfg = bench_1b_config() if on_accel else tiny_config(dtype=jnp.float32)
+    from _chip import require_tpu
+
+    device = require_tpu()
+    mcfg = bench_1b_config()
     B = 8
     # Budgets ample enough that the timed window is pure steady state (no
     # budget-bounded horizon shrink -> no tail compiles in the window).
-    ctx, new = (256, 640) if on_accel else (64, 160)
-    max_seq = 1024 if on_accel else 256
+    ctx, new = 256, 640
+    max_seq = 1024
 
     # Repetitive prompts (the prompt-lookup draft's home turf — code/JSON
     # style repetition).
@@ -48,7 +45,7 @@ def main() -> None:
             num_pages=(B * max_seq) // 16 + 64, page_size=16,
             max_batch_size=B, max_seq_len=max_seq,
             prefill_buckets=(64, 256, max_seq),
-            hash_block_size=128 if on_accel else 32,
+            hash_block_size=128,
             decode_horizon=8 if spec_k == 0 else 1,
             speculate_k=spec_k)
         engine = InferenceEngine(cfg)
@@ -86,7 +83,7 @@ def main() -> None:
     print(json.dumps({"metric": "speculative_speedup",
                       "value": round(results[4] / results[0], 3),
                       "unit": "x",
-                      "backend": jax.default_backend()}))
+                      "device": device}))
 
 
 if __name__ == "__main__":

@@ -1,4 +1,4 @@
-"""North-star-topology worker (VERDICT r4 next #4): runs in its OWN
+"""North-star-topology worker: runs in its OWN
 process on a 64-virtual-device CPU platform (the suite's conftest pins
 8) and proves the v5e-64 serving topology's mesh math end to end:
 

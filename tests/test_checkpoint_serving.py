@@ -1,4 +1,4 @@
-"""Real-checkpoint serving drill (VERDICT r1 #9, hermetic variant): an
+"""Real-checkpoint serving drill (hermetic variant): an
 HF-layout checkpoint directory (safetensors shards + tokenizer.json +
 tokenizer_config.json with chat template and added tokens) is loaded
 through models/loader.py and served end-to-end — client → master (HF

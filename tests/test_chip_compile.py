@@ -1,0 +1,100 @@
+"""The TPU's own compiler, asked from a machine with no TPU.
+
+`jax.experimental.topologies` describes a `v5e:2x2` host that is not
+attached; compiling against its devices runs Mosaic and the XLA TPU
+backend for real, so what the chip would refuse is refused here — which
+interpret mode and the CPU backend never see (tensor-parallel serving and
+the context-parallel kernel both passed every CPU test and could not
+compile). Arms and helpers live in `benchmarks/compile_gate.py`.
+
+The topology is described inside a module-scoped fixture, never at import:
+the TPU library belongs to one process, and every xdist worker imports
+this file. Keep these tests in this one file for the same reason.
+"""
+
+import dataclasses
+import importlib.util
+import sys
+from pathlib import Path
+
+import pytest
+
+REPO = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(REPO))
+
+_spec = importlib.util.spec_from_file_location(
+    "compile_gate", REPO / "benchmarks" / "compile_gate.py")
+gate = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(gate)
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """The described devices, with the persistent compile cache off
+    around the compiles (an entry written for a described chip cannot be
+    read back without one, and every later run would warn)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        try:
+            devices = gate.describe_devices()
+        except Exception as e:  # noqa: BLE001 — any failure means no topology
+            pytest.skip(f"no {gate.TOPOLOGY} topology can be described "
+                        f"here: {e}")
+        yield devices
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        cc.reset_cache()
+
+
+@pytest.mark.parametrize("arm", [
+    "paged_b16", "paged_b64", "gemma2_softcap", "gemma2_window",
+    "page_gather_l32", "page_scatter_l32", "cp_partial_stats",
+    "paged_shard_map_tp4"])
+def test_kernel_compiles_for_v5e(chip, arm):
+    """Each served-path Pallas kernel at Llama-3-8B head shapes is
+    accepted by Mosaic and stays a kernel in the compiled program."""
+    compiled = dict(gate.kernel_arms(chip))[arm]()
+    assert compiled.as_text().count("tpu_custom_call") >= 1
+
+
+def _two_layer_cfg():
+    from xllm_service_tpu.engine.config import EngineConfig
+    from xllm_service_tpu.models.base import llama3_8b_config
+
+    mcfg = dataclasses.replace(llama3_8b_config(), num_layers=2)
+    return EngineConfig(model=mcfg, model_family="llama", num_pages=1024,
+                        max_batch_size=16, max_seq_len=1024,
+                        prefill_buckets=(128, 1024), decode_horizon=8)
+
+
+def test_decode_step_full_width_one_chip(chip):
+    """The engine's own decode program (`_build_programs`), Llama-3-8B
+    widths, 2 layers, horizon 8: compiles for one chip with the kernel in
+    it — one custom call per layer — and says so in the path record."""
+    out = gate.compile_engine_programs(_two_layer_cfg(), device=chip[0],
+                                       horizons=(8,), buckets=())
+    prog = out["decode_multi_h8"]
+    assert prog["tpu_custom_calls"] == 2
+    assert prog["fits_hbm"]
+    assert out["attention_paths"]["decode_multi"] == {
+        "paged_attention": "pallas"}
+
+
+def test_decode_step_full_width_tp4(chip):
+    """The same program partitioned over the 4-device model mesh: GSPMD
+    cannot partition a Mosaic kernel, so it sits under shard_map on a
+    head-sharded pool (KV_PAGES_SPEC) beside the row-parallel matmuls'
+    all-reduces."""
+    out = gate.compile_engine_programs(
+        _two_layer_cfg(), mesh=gate.model_mesh(chip, 4), horizons=(8,),
+        buckets=())
+    prog = out["decode_multi_h8"]
+    assert prog["tpu_custom_calls"] == 2
+    assert prog["all_reduces"] >= 2 * 2      # o_proj + down_proj per layer
+    assert out["attention_paths"]["decode_multi"] == {
+        "paged_attention": "pallas (shard_map model=4)"}

@@ -95,7 +95,7 @@ class TestChunkedMultimodal:
         """VL warmup must pre-compile the image-carrying program variant
         too (its mm operand is unit-padded, a different shape from the
         no-image dummy), and a post-warmup image request must match a
-        cold engine's output (ADVICE r2: image variants stayed cold)."""
+        cold engine's output (image variants stayed cold)."""
         cold = make_vl_engine(0)
         prompt, mm = make_prompt_and_mm(cold.cfg.model)
         want = run_one(cold, prompt, mm)

@@ -13,7 +13,6 @@ from xllm_service_tpu.common.types import InstanceType
 from xllm_service_tpu.coordination.memory import InMemoryCoordination, MemoryStore
 from xllm_service_tpu.engine.agent import AgentConfig, EngineAgent
 from xllm_service_tpu.engine.config import EngineConfig
-from xllm_service_tpu.engine.kv_transfer import device_transfer_available
 from xllm_service_tpu.master import Master
 from xllm_service_tpu.models.base import tiny_config
 
@@ -130,10 +129,6 @@ class TestPDDisaggregation:
                  for e in events[:-1] if b'"choices"' in e]
         assert len("".join(texts)) > 0
 
-    @pytest.mark.skipif(not device_transfer_available(),
-                        reason="jax.experimental.transfer not available "
-                               "in this runtime (host-msgpack fallback "
-                               "covered by the other PD tests)")
     def test_device_transfer_path_used(self, pd_cluster):
         """With transfer servers available on both sides, the handoff must
         ride the device path (KV pulled device-to-device), not the host

@@ -67,7 +67,7 @@ def naive_greedy(engine: InferenceEngine, prompt: list[int], n: int) -> list[int
     Tokens are padded to ONE fixed bucket (seq_lens masks the tail) so
     every step of every caller shares a single compiled program — the
     growing-S version compiled a fresh XLA program per generated token
-    and dominated the suite's wall-clock (VERDICT r3 weak #5)."""
+    and dominated the suite's wall-clock."""
     cfg = engine.cfg
     fam, mcfg = engine.family, cfg.model
     S_max = min(cfg.max_seq_len, 256)

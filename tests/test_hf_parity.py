@@ -6,7 +6,7 @@ FULL stack (HTTP → master → agent → engine) by the real-checkpoint
 drill's own run_drill(). Token-exact agreement proves framework output
 == HF output on the shared weights — the same machinery
 scripts/real_ckpt_drill.py points at a published checkpoint when one is
-reachable (VERDICT r4 next #2; reference boots real model dirs,
+reachable (reference boots real model dirs,
 docs/en/getting_started.md:73-90)."""
 
 import importlib.util

@@ -1,6 +1,6 @@
 """Checkpoint loading: synthetic HF safetensors round-trip + orbax.
 
-Covers every family's HF layout (VERDICT r2 missing #2): llama/qwen2
+Covers every family's HF layout: llama/qwen2
 dense, DeepSeek-V2 MLA+MoE (kv_a/kv_b splits, expert stacks, layer-0
 dense MLP), Mixtral (w1/w3/w2), and Qwen2-VL (vision tower + merger)."""
 

@@ -1,4 +1,4 @@
-"""Sarathi mixed-step forward parity (VERDICT r4 next #3): one program
+"""Sarathi mixed-step forward parity: one program
 decoding the running batch while writing/attending a prefill sub-chunk
 must be bit-equivalent to running decode_forward and the chunk write
 separately — same decode logits, same KV pool contents."""

@@ -1,4 +1,4 @@
-"""M-RoPE parity drills for the Qwen2-VL LM stack (VERDICT r3 next #4).
+"""M-RoPE parity drills for the Qwen2-VL LM stack.
 
 Hermetic HF-parity: a synthetic checkpoint is loaded BOTH into our
 qwen2_vl family and into transformers' Qwen2VLForConditionalGeneration;

@@ -1,4 +1,4 @@
-"""v5e-64 north-star topology proof (VERDICT r4 next #4): the worker
+"""v5e-64 north-star topology proof: the worker
 runs in a fresh process with 64 virtual CPU devices (this suite's own
 platform is pinned to 8, so a subprocess is the only way to get there)
 and must print every section's OK line. Any mesh-math assumption that

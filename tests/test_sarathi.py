@@ -1,4 +1,4 @@
-"""Sarathi mixed decode+chunk engine path (VERDICT r4 next #3): while a
+"""Sarathi mixed decode+chunk engine path: while a
 long prompt chunk-prefills, running decodes ride the SAME device program
 (shared GEMMs). Output must be token-exact vs the plain interleaved
 path, the ride must actually engage, and XLLM_SARATHI=0 must disable."""

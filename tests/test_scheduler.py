@@ -162,7 +162,7 @@ class TestScheduleFlow:
 
     def test_pre_token_exit_paths_leak_no_load(self, store):
         """Disconnect, error, and GC-timeout before the first token must
-        leave all load accounting at zero (ADVICE r1: FINISH_PREFILL on
+        leave all load accounting at zero (FINISH_PREFILL on
         those paths leaked decode load; GC leaked prefill load)."""
         sched = make_scheduler(store, request_timeout_s=0.0)
         fleet(sched, make_meta("m1", InstanceType.MIX))

@@ -138,7 +138,7 @@ class TestSpeculativeDecoding:
 
     def test_mixed_batch_keeps_speculating_and_matches_normal(self):
         """One sampled request must NOT disable speculation for its
-        greedy neighbor (VERDICT r2 weak #4) — and BOTH outputs must be
+        greedy neighbor — and BOTH outputs must be
         byte-identical to the non-speculative engine (the sampled slot's
         step inside spec_multi uses the same fold_in(key, clens) RNG as
         decode_multi)."""

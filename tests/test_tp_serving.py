@@ -12,7 +12,6 @@ from xllm_service_tpu.common.types import InstanceType
 from xllm_service_tpu.coordination.memory import InMemoryCoordination, MemoryStore
 from xllm_service_tpu.engine.agent import AgentConfig, EngineAgent
 from xllm_service_tpu.engine.config import EngineConfig
-from xllm_service_tpu.engine.kv_transfer import device_transfer_available
 from xllm_service_tpu.master import Master
 from xllm_service_tpu.models.base import tiny_config
 from xllm_service_tpu.parallel.mesh import MeshConfig
@@ -88,11 +87,6 @@ class TestTensorParallelServing:
             s2.close()
         assert got == want
 
-    @pytest.mark.skipif(
-        not device_transfer_available(),
-        reason="jax.experimental.transfer absent in this jax build: the "
-               "device-path KV handoff has no transport (the host-msgpack "
-               "fallback is covered by test_e2e_pd_disagg)")
     def test_tp2_pd_disaggregation_device_path(self):
         """PD pair of TP-sharded engines with identical mesh topologies:
         the handoff rides the device path shard-for-shard (the pull

@@ -10,6 +10,16 @@ from ..models.base import ModelConfig, tiny_config
 from ..parallel.mesh import MeshConfig
 
 
+def prefill_bucket_ladder(max_seq_len: int) -> tuple[int, ...]:
+    """The serving agent's prefill buckets for a context limit: a pow2
+    ladder topped by the limit itself. A prompt pads to the next bucket,
+    so a sparse ladder doubles typical prefill compute (a 256-token prompt
+    in a 512 bucket runs 2x the positions); boot compiles amortize via the
+    persistent compile cache."""
+    return tuple(sorted({b for b in (128, 256, 512, 1024, 2048)
+                         if b < max_seq_len} | {max_seq_len}))
+
+
 @dataclass
 class EngineConfig:
     model_id: str = "tiny-llama"
@@ -47,9 +57,8 @@ class EngineConfig:
     max_concurrent_prefills: int = 2
     # Decode horizon: tokens generated per host roundtrip (lax.scan inside
     # one jit call). 1 = lowest streaming latency; larger values amortize
-    # dispatch + transfer overhead (essential over remote-attached chips,
-    # still a win locally). Tokens past a stop condition within a horizon
-    # are discarded on the host.
+    # dispatch + transfer overhead. Tokens past a stop condition within a
+    # horizon are discarded on the host.
     decode_horizon: int = 1
     # TTFT guard: while requests are WAITING (or a chunked prefill is in
     # flight), decode calls shrink to this many tokens so admission isn't
@@ -72,7 +81,7 @@ class EngineConfig:
     # logprobs, or bias) verify drafts; every other slot takes a normal
     # sampled single-token step inside the SAME program, so one sampled
     # request no longer disables speculation for its greedy neighbors
-    # (VERDICT r2 weak #4). Draft proposal is also device-side (n-gram
+    #. Draft proposal is also device-side (n-gram
     # match over the device-resident history buffer), and
     # `speculate_cycles` propose+verify cycles run per host roundtrip
     # under one lax.scan — the spec analog of decode_horizon.

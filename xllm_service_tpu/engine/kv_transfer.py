@@ -24,6 +24,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import transfer as _xfer
 
 from ..common.faults import FAULTS
 from ..common.tracing import TRACER, TraceContext
@@ -31,20 +32,7 @@ from ..devtools import lifecycle as _lifecycle
 from ..devtools.locks import make_lock
 from ..utils import get_logger
 
-# `jax.experimental.transfer` only exists in jax builds with transfer-server
-# support; absent (e.g. CPU-only containers) every caller falls back to the
-# host-msgpack path and tests gate on `device_transfer_available()`.
-try:
-    from jax.experimental import transfer as _xfer
-except ImportError:
-    _xfer = None
-
 logger = get_logger(__name__)
-
-
-def device_transfer_available() -> bool:
-    """Whether this runtime can move KV pages device-to-device."""
-    return _xfer is not None
 
 # An offer the decode peer never pulled (transfer failed mid-flight) is
 # dropped after this long so the KV buffers can be freed.
@@ -299,9 +287,6 @@ class KvTransferManager:
 
     def __init__(self, device: jax.Device, listen_ip: str = "127.0.0.1",
                  mesh=None):
-        if _xfer is None:
-            raise RuntimeError(
-                "jax.experimental.transfer is unavailable in this runtime")
         self._device = device
         self._mesh = mesh
         self._server = _xfer.start_transfer_server(
@@ -315,12 +300,14 @@ class KvTransferManager:
     @classmethod
     def create(cls, device: jax.Device, listen_ip: str = "127.0.0.1",
                mesh=None) -> Optional["KvTransferManager"]:
-        """None when the runtime lacks transfer-server support (the caller
-        falls back to the host path)."""
+        """None when the transfer server cannot be started on this
+        device — said loudly, because every handoff then takes the
+        host-msgpack path."""
         try:
             return cls(device, listen_ip, mesh=mesh)
         except Exception as e:  # noqa: BLE001 — optional capability
-            logger.info("device KV transfer unavailable: %s", e)
+            logger.warning("device KV transfer server failed to start; PD "
+                           "handoffs will take the host path: %s", e)
             return None
 
     @property

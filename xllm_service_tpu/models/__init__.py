@@ -1,6 +1,6 @@
 """Model zoo for the TPU engine plane (functional JAX).
 
-Families mirror the reference's benchmark configs (BASELINE.md): Llama-3
+Families mirror the reference's benchmark configs (BASELINE.json): Llama-3
 (llama.py), Qwen2/2.5 (qwen2.py — llama family with qkv bias), DeepSeek-V2
 style MoE (deepseek_moe.py — expert-parallel decode), Qwen2-VL
 (qwen2_vl.py — vision encoder + LM for EPD), Gemma/Gemma-2 (gemma.py —

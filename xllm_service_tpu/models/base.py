@@ -242,6 +242,18 @@ def llama3_8b_config() -> ModelConfig:
                        max_context_len=8192)
 
 
+def llama3_8b_l20_config() -> ModelConfig:
+    """Llama-3-8B at every published width, cut to 20 of its 32 layers:
+    the deepest bf16 cut one 16 GB v5e chip holds with the margin the
+    full-depth int8 model has (decode program 13.1 GiB of 15.75 by the
+    described-chip compile; 24 layers leave 1.1 GiB), so the same model
+    can be served at --tp 1 and --tp 4 and compared (chip_smoke.py
+    --chips 4). Depth is the only cut."""
+    import dataclasses
+
+    return dataclasses.replace(llama3_8b_config(), num_layers=20)
+
+
 def llama3_70b_config() -> ModelConfig:
     return ModelConfig(name="llama", vocab_size=128256, hidden_size=8192,
                        num_layers=80, num_heads=64, num_kv_heads=8,

@@ -18,12 +18,82 @@ provides the hand-written TPU kernel and the engine selects per backend.
 from __future__ import annotations
 
 import contextlib
+import functools
+import logging
 import threading
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ..parallel.mesh import AXIS_MODEL
+from ..utils import get_logger
+
+logger = get_logger(__name__)
 
 _NEG_INF = -1e30
+
+
+def _backend() -> str:
+    """The backend the dispatch gates below key on. One hook so the
+    described-chip compile tests can steer every gate to the TPU branch
+    while `jax.default_backend()` still reads "cpu"."""
+    return jax.default_backend()
+
+
+# Trace-time path record. The kernel-or-XLA choice below happens while a
+# program is TRACED and is invisible afterwards, so every dispatcher
+# notes which path it took: `{program: {op: path}}`, logged once per
+# distinct entry. The engine traces each of its programs under
+# `trace_program(label, record=engine dict)` and returns that dict from
+# `stats()`; calls outside any program land in `PATH_RECORD[""]`.
+PATH_RECORD: dict[str, dict[str, str]] = {}
+_prog_ctx = threading.local()
+
+
+@contextlib.contextmanager
+def trace_program(label: str, record: dict | None = None, mesh=None):
+    """Trace context for one jitted program: names it in the path record
+    and, with a `mesh` whose model axis is > 1, makes `paged_attention`
+    run its Pallas kernel per head-shard under `shard_map` (Mosaic
+    kernels cannot be partitioned by GSPMD)."""
+    prev = getattr(_prog_ctx, "cfg", None)
+    _prog_ctx.cfg = (label, PATH_RECORD if record is None else record, mesh)
+    try:
+        yield
+    finally:
+        _prog_ctx.cfg = prev
+
+
+def note_path(op: str, path: str) -> None:
+    label, record, _ = getattr(_prog_ctx, "cfg", None) or ("", PATH_RECORD,
+                                                           None)
+    paths = record.setdefault(label, {})
+    if paths.get(op) == path:
+        return
+    paths[op] = path
+    # An accelerator program that leaves the kernel must say so loudly.
+    loud = _backend() != "cpu" and path.startswith("xla")
+    logger.log(logging.WARNING if loud else logging.INFO,
+               "attention path: program=%s op=%s -> %s",
+               label or "-", op, path)
+
+
+def program_mesh():
+    """The mesh of the program being traced (None outside one, or for a
+    single-device program)."""
+    cfg = getattr(_prog_ctx, "cfg", None)
+    mesh = cfg[2] if cfg else None
+    return mesh if mesh is not None and mesh.size > 1 else None
+
+
+def _tp_mesh():
+    """(mesh, tp) of the program being traced when its model axis is
+    sharded, else (None, 1)."""
+    mesh = program_mesh()
+    if mesh is None or mesh.shape.get(AXIS_MODEL, 1) <= 1:
+        return None, 1
+    return mesh, int(mesh.shape[AXIS_MODEL])
 
 # Sequence-parallel prefill context (SURVEY.md §5.7). The engine activates
 # this while TRACING its long-prefill program; `prefill_attention` then
@@ -237,9 +307,11 @@ def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         pf_on = (not in_verify
                  and os.environ.get("XLLM_PREFILL_PALLAS", "") == "1"
                  and S * n_heads <= 4096)
-        if (mq_on or pf_on) and _mosaic_kernel_ok(q, k_pages):
+        if (mq_on or pf_on) and _tp_mesh()[0] is None \
+                and _mosaic_kernel_ok(q, k_pages):
             from .pallas_mq_paged_attention import mq_paged_attention_pallas
 
+            note_path("prefill_attention", "pallas-mq")
             return mq_paged_attention_pallas(q, k_pages, v_pages,
                                              page_table, prefix_lens,
                                              seq_lens,
@@ -254,6 +326,7 @@ def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             "ring attention does not support attn softcap/sliding window; "
             "the engine must not enable sequence-parallel prefill for "
             "gemma-2-style models")
+    note_path("prefill_attention", "ring" if sp is not None else "xla-dense")
     if sp is not None:
         # Context-parallel path: ring attention over the seq mesh axis.
         # Queries past seq_lens are end-padding; causal masking keeps them
@@ -397,7 +470,7 @@ def _mosaic_kernel_ok(q: jax.Array, k_pages: jax.Array) -> bool:
     n_kv = k_pages.shape[1]
     return (hd % 128 == 0 and n_heads % n_kv == 0
             and q.dtype in (jnp.bfloat16, jnp.float32)
-            and (jax.default_backend() != "cpu" or _pallas_interpret())
+            and (_backend() != "cpu" or _pallas_interpret())
             and os.environ.get("XLLM_DISABLE_PALLAS_ATTENTION", "")
             in ("", "0"))
 
@@ -426,7 +499,9 @@ def decode_attention_step(q: jax.Array, k: jax.Array, v: jax.Array,
     if (kv_writeback_mode() == "fused"
             and softcap == 0.0 and window == 0 and scale is None
             and getattr(_cp_ctx, "cfg", None) is None
+            and _tp_mesh()[0] is None
             and _mosaic_kernel_ok(q, k_pages)):
+        note_path("paged_attention", "pallas-fused")
         from .pallas_fused_decode_attention import (
             fused_decode_attention_pallas,
         )
@@ -453,7 +528,7 @@ def _span_buckets_on() -> bool:
     v = os.environ.get("XLLM_XLA_SPAN_BUCKETS", "")
     if v in ("0", "1"):
         return v == "1"
-    return jax.default_backend() != "cpu"
+    return _backend() != "cpu"
 
 
 def paged_attention_xla(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -547,16 +622,47 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                                   context_lens, mesh, seq_axis=seq_axis,
                                   scale=scale)
 
-    if _mosaic_kernel_ok(q, k_pages):
+    mesh, tp = _tp_mesh()
+    n_heads, n_kv = q.shape[-2], k_pages.shape[1]
+    if not _mosaic_kernel_ok(q, k_pages):
+        import os
+
+        why = ("XLLM_DISABLE_PALLAS_ATTENTION"
+               if os.environ.get("XLLM_DISABLE_PALLAS_ATTENTION", "")
+               not in ("", "0")
+               else "cpu backend" if _backend() == "cpu"
+               else f"shape outside the kernel's tiling: hd={q.shape[-1]} "
+                    f"heads={n_heads}/{n_kv} dtype={q.dtype}")
+        note_path("paged_attention", f"xla ({why})")
+    elif n_kv % tp or n_heads % tp:
+        note_path("paged_attention",
+                  f"xla (kv heads {n_kv} do not divide over tp={tp})")
+    else:
         from .pallas_paged_attention import paged_attention_pallas
 
         # softcap/window/scale are static kernel params (gemma-2 decodes
         # through the kernel too — the XLA fallback gathers every row's
         # FULL page span dense per layer per step).
-        return paged_attention_pallas(q, k_pages, v_pages, page_table,
-                                      context_lens,
-                                      interpret=_pallas_interpret(),
-                                      scale=scale, softcap=softcap,
-                                      window=window)
+        kernel = functools.partial(paged_attention_pallas,
+                                   interpret=_pallas_interpret(),
+                                   scale=scale, softcap=softcap,
+                                   window=window)
+        if mesh is None:
+            note_path("paged_attention", "pallas")
+            return kernel(q, k_pages, v_pages, page_table, context_lens)
+        # Tensor parallel: GSPMD cannot partition a Mosaic kernel, so
+        # each device runs it on its own heads — q and the pool are
+        # head-sharded over `model` (KV_PAGES_SPEC), the page table and
+        # lengths replicated. GQA groups stay whole because both head
+        # counts divide by tp. pallas_call outputs carry no
+        # varying-axes metadata, hence check_vma=False.
+        note_path("paged_attention", f"pallas (shard_map model={tp})")
+        heads = P(None, AXIS_MODEL, None)
+        pool = P(None, AXIS_MODEL, None, None)
+        return jax.shard_map(
+            kernel, mesh=mesh,
+            in_specs=(heads, pool, pool, P(), P()),
+            out_specs=heads, check_vma=False,
+        )(q, k_pages, v_pages, page_table, context_lens)
     return paged_attention_xla(q, k_pages, v_pages, page_table, context_lens,
                                scale=scale, softcap=softcap, window=window)

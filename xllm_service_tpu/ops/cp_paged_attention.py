@@ -25,7 +25,7 @@ Two per-shard bodies, selected by the shared Mosaic gate:
   the cross-shard merge.
 - Dense XLA fallback (CPU tests / non-Mosaic shapes): gathers the local
   page span to a dense tensor per step — correctness-first (this was the
-  only body in round 2; VERDICT r2 weak #6).
+  only body in round 2).
 """
 
 from __future__ import annotations
@@ -34,10 +34,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:   # pre-0.5 spelling of the same API
-    from jax.experimental.shard_map import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
@@ -48,7 +44,6 @@ from .pallas_page_dma import (
     flash_accumulate,
     masked_kv_f32_pos,
     page_chunk_size,
-    tpu_compiler_params,
 )
 
 _NEG_INF = NEG_INF
@@ -135,8 +130,15 @@ def _partial_kernel(local_pt_ref, starts_ref, n_local_ref, clens_ref,
     def compute(c, slot):
         # Per-row global token positions: compacted pages are not
         # contiguous, so each page contributes start_j + iota(ps).
+        # Built once per orientation from an iota and `chunk` scalar
+        # selects: Mosaic has no layout for reshaping a [chunk, ps] i32
+        # tile into [1, span] / [span, 1].
         base = c * chunk
-        rows = []
+        span = chunk * page_size
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+        sub = jax.lax.broadcasted_iota(jnp.int32, (span, 1), 0)
+        pos_row = jnp.full((1, span), ctx, jnp.int32)
+        pos_col = jnp.full((span, 1), ctx, jnp.int32)
         for j in range(chunk):
             # Chunk-padding entries (base+j >= n_pages) were never
             # DMA'd — their buffer rows are stale. Position them at
@@ -148,12 +150,11 @@ def _partial_kernel(local_pt_ref, starts_ref, n_local_ref, clens_ref,
                 base + j < n_pages,
                 starts_ref[b, jnp.minimum(base + j, max_pages - 1)],
                 ctx)
-            rows.append(st + jax.lax.broadcasted_iota(
-                jnp.int32, (1, page_size), 1))
-        pos = jnp.concatenate(rows, axis=0)          # [chunk, ps]
-        span = chunk * page_size
-        pos_row = pos.reshape(1, span)
-        pos_col = pos.reshape(span, 1)
+            lo, hi = j * page_size, (j + 1) * page_size
+            pos_row = jnp.where((lane >= lo) & (lane < hi),
+                                st + lane - lo, pos_row)
+            pos_col = jnp.where((sub >= lo) & (sub < hi),
+                                st + sub - lo, pos_col)
         mask = pos_row < ctx
         q = q_ref[0].astype(jnp.float32) * scale     # [n_q, hd]
         for kv in range(n_kv):
@@ -239,7 +240,7 @@ def _paged_partial_impl(q, k_pages, v_pages, local_pt, starts, n_local,
             jax.ShapeDtypeStruct((B, n_q, 128), jnp.float32),
             jax.ShapeDtypeStruct((B, n_q, hd), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(local_pt, starts, n_local, context_lens, q, k_pages, v_pages)
@@ -302,9 +303,13 @@ def cp_paged_attention(q: jax.Array, k_pages: jax.Array,
     (or shardable) on the page axis over `seq_axis`; num_pages must divide
     by the axis size. Returns [B, n_heads, hd], identical to
     single-device paged attention (parity-tested)."""
-    from .attention import _mosaic_kernel_ok, _pallas_interpret
+    from .attention import _mosaic_kernel_ok, _pallas_interpret, note_path
 
-    if _mosaic_kernel_ok(q, k_pages):
+    kernel_ok = _mosaic_kernel_ok(q, k_pages)
+    note_path("paged_attention",
+              f"cp-pallas ({seq_axis})" if kernel_ok
+              else f"cp-xla-dense ({seq_axis})")
+    if kernel_ok:
         body = functools.partial(_local_partial_kernelized,
                                  axis_name=seq_axis, scale=scale,
                                  interpret=_pallas_interpret())
@@ -312,17 +317,12 @@ def cp_paged_attention(q: jax.Array, k_pages: jax.Array,
         body = functools.partial(_local_partial, axis_name=seq_axis,
                                  scale=scale)
     # pallas_call's out_shape carries no varying-mesh-axes metadata,
-    # which trips shard_map's replication/vma check on the kernel body —
-    # disable it under whichever name this jax spells it.
-    import inspect
-
-    relax = ("check_vma" if "check_vma"
-             in inspect.signature(shard_map).parameters else "check_rep")
-    fn = shard_map(
+    # which trips shard_map's vma check on the kernel body.
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(seq_axis), P(seq_axis), P(), P()),
         out_specs=P(),
-        **{relax: False},
+        check_vma=False,
     )
     return fn(q, k_pages, v_pages, page_table, context_lens)

@@ -58,7 +58,6 @@ from .pallas_page_dma import (
     flash_accumulate,
     masked_kv_f32,
     page_chunk_size,
-    tpu_compiler_params,
 )
 
 
@@ -233,7 +232,7 @@ def _fused_impl(q, k_new, v_new, k_pages, v_pages, page_table,
         # Flattened operand order: (page_table, context_lens, q, k_new,
         # v_new, k_pages, v_pages) -> pools at 5/6 alias outputs 1/2.
         input_output_aliases={5: 1, 6: 2},
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(page_table, context_lens, q, k_new, v_new, k_pages, v_pages)

@@ -34,7 +34,6 @@ from .pallas_page_dma import (
     flash_accumulate,
     masked_kv_f32,
     page_chunk_size,
-    tpu_compiler_params,
 )
 
 
@@ -158,7 +157,7 @@ def _mq_impl(q, k_pages, v_pages, page_table, prefix_lens, block_lens, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, s_q, n_q, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(page_table, prefix_lens, block_lens, q, k_pages, v_pages)

@@ -20,16 +20,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def tpu_compiler_params(**kw):
-    """TPU Pallas compiler params across the JAX API rename: newer
-    releases expose ``pltpu.CompilerParams``, older ones (<= 0.4.x)
-    ``pltpu.TPUCompilerParams`` — same fields either way. Every kernel in
-    this package builds its ``compiler_params`` through here so the suite
-    runs under both spellings."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kw)
-
-
 def page_chunk_size(max_pages: int, default: int = 8) -> int:
     """Pages per double-buffered DMA chunk in the paged-attention
     kernels. Bigger chunks mean fewer, larger DMAs — the decode walk is
@@ -132,8 +122,6 @@ def chunked_page_walk(page_table_ref, b, nb, n_pages, n_pages_of, chunk,
                 compute(c, slot)
                 return ()
 
-            # No unroll kwarg: older jax rejects it outright when the
-            # trip count is dynamic (and False is the default anyway).
             jax.lax.fori_loop(0, n_chunks, body, ())
         return
 
@@ -189,9 +177,16 @@ def _pallas_page_mover_on() -> bool:
     mode (parity tests on CPU)."""
     import os
 
-    if os.environ.get("XLLM_PALLAS_INTERPRET", "") == "1":
-        return True
-    return jax.default_backend() == "tpu"
+    from .attention import _backend, note_path, program_mesh
+
+    if program_mesh() is not None:
+        # The pool is sharded and GSPMD cannot partition a Mosaic kernel.
+        note_path("page_mover", "xla-gather (pool sharded over a mesh)")
+        return False
+    on = (os.environ.get("XLLM_PALLAS_INTERPRET", "") == "1"
+          or _backend() == "tpu")
+    note_path("page_mover", "pallas-dma" if on else "xla-gather")
+    return on
 
 
 def _gather_pages_kernel(ids_ref, pool, out, sem):
@@ -241,7 +236,7 @@ def gather_kv_pages(kv, page_ids):
         _gather_pages_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((L, 2, n, n_kv, ps, hd), kv.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=os.environ.get("XLLM_PALLAS_INTERPRET", "") == "1",
     )(page_ids, kv)
@@ -273,7 +268,7 @@ def scatter_kv_pages(kv, page_ids, block):
         # Flattened operand order (ids, blk, pool): pool at 2 aliases the
         # output — in-place page writes, no pool copy.
         input_output_aliases={2: 0},
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=os.environ.get("XLLM_PALLAS_INTERPRET", "") == "1",
     )(page_ids, block, kv)

@@ -37,7 +37,6 @@ from .pallas_page_dma import (
     flash_accumulate,
     masked_kv_f32,
     page_chunk_size,
-    tpu_compiler_params,
 )
 
 
@@ -183,7 +182,7 @@ def _paged_attention_impl(q: jax.Array, k_pages: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, n_q, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(page_table, context_lens, q, k_pages, v_pages)

@@ -24,10 +24,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:   # pre-0.5 spelling of the same API
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -48,13 +45,8 @@ def _pipeline_local(local_layers, x_mb, layer_fn: Callable,
 
     perm = [(i, (i + 1) % n_stage) for i in range(n_stage)]
 
-    if hasattr(jax.lax, "pcast"):
-        def _vary(v):
-            return jax.lax.pcast(v, axis_name, to="varying")
-    else:
-        # Older jax: no varying-axes typing, the zeros carry unifies as-is.
-        def _vary(v):
-            return v
+    def _vary(v):
+        return jax.lax.pcast(v, axis_name, to="varying")
 
     state = _vary(jnp.zeros_like(x_mb[0]))          # in-flight activation
     outputs = _vary(jnp.zeros_like(x_mb))

@@ -21,10 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:   # pre-0.5 spelling of the same API
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 _NEG_INF = -1e30
 
@@ -80,14 +77,9 @@ def _ring_attention_local(q: jax.Array, k: jax.Array, v: jax.Array,
         return (k_nxt, v_nxt, m_new, l_new, acc_new), None
 
     # Mark the constant initial carries as device-varying so the scan
-    # carry types line up with the ring-permuted outputs. Older jax has
-    # no pcast/varying-axes typing — there the carries already unify.
-    if hasattr(jax.lax, "pcast"):
-        def _vary(x):
-            return jax.lax.pcast(x, axis_name, to="varying")
-    else:
-        def _vary(x):
-            return x
+    # carry types line up with the ring-permuted outputs.
+    def _vary(x):
+        return jax.lax.pcast(x, axis_name, to="varying")
 
     m0 = _vary(jnp.full((B, H, S, 1), _NEG_INF, jnp.float32))
     l0 = _vary(jnp.zeros((B, H, S, 1), jnp.float32))
