@@ -1,0 +1,197 @@
+"""The readers of the engine loop's own record (`/stats`.engine_trace) and
+of its phases on the trace clock (chipbench/hostspans.py): attribution on a
+synthetic plane, each reader on a fixture `ctx` and on an empty one."""
+
+import json
+import os
+from pathlib import Path
+
+import pytest
+
+from chipbench import harness, hostspans
+
+ROOT = Path(__file__).resolve().parents[2]
+BENCH, SEARCH = harness.load_bench(ROOT / "BENCHMARK.json")
+CELL2 = "qwen25-3b-bf16.agent-prefix"
+
+
+def _span(name, start, end):
+    return {"name": name, "start": start, "dur": end - start}
+
+
+# One pump thread, two loop iterations: admit 0-10 ms, decode_dispatch
+# 10-100 with an emit 20-100 inside it that waits on the chip 30-90; then
+# admit 100-130 with a prefill_dispatch 105-115 inside; nothing after 130.
+PUMP = {"python#3": [
+    _span("admit", 0.000, 0.010),
+    _span("decode_dispatch", 0.010, 0.100),
+    _span("emit", 0.020, 0.100),
+    _span("fetch_wait", 0.030, 0.090),
+    _span("admit", 0.100, 0.130),
+    _span("prefill_dispatch", 0.105, 0.115),
+]}
+
+
+def _device(*modules, ops=None):
+    mods = [{"name": f"jit_{n}(1)", "start": a, "dur": b - a}
+            for n, a, b in modules]
+    return {"/device:TPU:0": {
+        "XLA Modules": mods,
+        "XLA Ops": ops or [dict(m, name="fusion.1") for m in mods]}}
+
+
+def test_exclusive_gives_each_moment_to_the_innermost_span():
+    got = hostspans.exclusive(PUMP["python#3"])
+    assert [(round(a, 3), round(b, 3), n) for a, b, n in got] == [
+        (0.0, 0.01, "admit"), (0.01, 0.02, "decode_dispatch"),
+        (0.02, 0.03, "emit"), (0.03, 0.09, "fetch_wait"),
+        (0.09, 0.1, "emit"), (0.1, 0.105, "admit"),
+        (0.105, 0.115, "prefill_dispatch"), (0.115, 0.13, "admit")]
+
+
+@pytest.mark.parametrize("gap,label", [
+    ((0.040, 0.060), "fetch_wait"),     # the pump was waiting for results
+    ((0.101, 0.104), "admit"),          # the pump was admitting a request
+    ((0.140, 0.150), "unnamed"),        # no span covers any of it
+    ((0.085, 0.100), "emit"),           # 5 ms of waiting, 10 ms of emitting
+])
+def test_a_gap_is_charged_to_the_phase_covering_most_of_it(gap, label):
+    ir = _device(("decode_multi", gap[0] - 0.02, gap[0]),
+                 ("decode_multi", gap[1], gap[1] + 0.02))
+    assert hostspans.idle_by_span(ir, PUMP) == [
+        [label, pytest.approx(gap[1] - gap[0])]]
+
+
+def test_idle_by_span_sums_gaps_by_phase_largest_first():
+    ir = _device(("prefill_install", 0.0, 0.035), ("decode_multi", 0.045, 0.1),
+                 ("decode_multi", 0.102, 0.135), ("decode_multi", 0.145, 0.2))
+    assert hostspans.idle_by_span(ir, PUMP) == [
+        ["fetch_wait", pytest.approx(0.010)],
+        ["unnamed", pytest.approx(0.010)], ["admit", pytest.approx(0.002)]]
+    assert hostspans.idle_by_span(ir, {}) == [
+        ["unnamed", pytest.approx(0.022)]]
+
+
+def test_idle_unfed_leaves_out_the_idle_time_spent_waiting_for_the_chip():
+    # idle 35-45 ms (inside fetch_wait), 80-95 (10 ms of it inside), 120-125
+    ir = _device(("decode_multi", 0.0, 0.035), ("decode_multi", 0.045, 0.08),
+                 ("decode_multi", 0.095, 0.12), ("decode_multi", 0.125, 0.2))
+    unfed, window = hostspans.idle_unfed(ir, PUMP)
+    assert window == pytest.approx(0.2)
+    assert unfed == pytest.approx(0.005 + 0.005)
+    assert hostspans.idle_unfed(ir, {})[0] == pytest.approx(0.030)
+
+
+def test_find_trace_looks_only_into_the_cells_own_directory(tmp_path,
+                                                           monkeypatch):
+    """run.py's work directory is `.chipbench_work/<cell>` exactly; a
+    sweep's, or a cell's whose name extends this one's, is another run."""
+    monkeypatch.setattr(hostspans, "ROOT", tmp_path)
+    assert hostspans.find_trace("some.cell") is None
+    for name in ("some.cell-sweep", "some.cell-long", "other.cell"):
+        d = tmp_path / ".chipbench_work" / name / "trace" / "p"
+        d.mkdir(parents=True)
+        (d / "host.xplane.pb").write_bytes(b"")
+    (tmp_path / ".chipbench_work" / "some.cell").mkdir()
+    assert hostspans.find_trace("some.cell") is None
+    assert hostspans.find_trace("other.cell").parts[-4] == "other.cell"
+    own = tmp_path / ".chipbench_work" / "some.cell" / "trace" / "p"
+    own.mkdir(parents=True)
+    (own / "host.xplane.pb").write_bytes(b"")
+    assert hostspans.find_trace("some.cell").parts[-4] == "some.cell"
+
+
+# ------------------------------------------------------------- the readers
+RECENT = {
+    "seconds": 29.6, "admissions": 140, "prompt_tokens": 238000,
+    "prefix_hit_tokens": 215040, "decode_steps": 800,
+    "live_slot_steps": 17600, "context_token_steps": 1760000,
+    "pages_reserved_steps": 160000,
+    "host_s": {"admit": 0.5, "prefill_dispatch": 1.0, "decode_dispatch": 0.7,
+               "fetch_wait": 24.0, "emit": 2.8, "idle": 0.6}}
+
+
+def _ctx():
+    hf = json.loads((ROOT / "chipbench/configs/qwen25-3b-bf16/config.json")
+                    .read_text())
+    trace = json.loads((ROOT / "tests/chipbench/data/trace_head_tpu_v5e.json")
+                       .read_text())
+    return {"trace": trace, "agent_stats": {"engine_trace": {"recent": RECENT}},
+            "hotpath": {}, "hf": hf, "cell": CELL2,
+            "engine": {"decode_horizon": 8, "page_size": 16,
+                       "weights": "bfloat16"},
+            "device": {"kind": "TPU v5 lite"}}
+
+
+# 2200 context tokens a step x 36864 bytes of K and V a token, over the
+# kernel's 1.008347 ms / 8 steps of the recorded trace (its head holds 6 of
+# a call's 288 kernel launches, so a whole batch's context would not fit
+# the time), of 819 GB/s
+KV_BW = 100 * (2200 * 2 * 36 * 2 * 128 * 2 / 819e9) / (1.008347e-3 / 8)
+
+EXPECTED = {
+    "engine.prefix_hit_pct": 100 * 215040 / 238000,
+    "engine.batch_live_mean": 22.0,
+    "engine.kv_used_of_reserved_pct": 100 * 1760000 / (160000 * 16),
+    "engine.host_busy_pct": 100 * (1 - 24.6 / 29.6),
+    "kernel.paged_attn_kv_bw_pct": KV_BW,
+    # the recorded trace is busy 317.294 ms of 320.514; the synthetic pump
+    # waits through the first 2 ms of the gap between its two programs
+    "device.idle_unfed_pct": 100 * (0.320513533 - 0.317294335 - 0.002)
+    / 0.320513533,
+}
+
+
+@pytest.fixture()
+def pump_in_the_gap(monkeypatch):
+    """The pump waits for the chip through the first 2 ms of the recorded
+    trace's one gap between programs (50.227-53.431 ms)."""
+    spans = {"python#1": [_span("fetch_wait", 0.050226763, 0.052226763)]}
+    monkeypatch.setattr(hostspans, "find_trace", lambda cell: Path("x.pb"))
+    monkeypatch.setattr(hostspans, "load_spans", lambda path: spans)
+
+
+@pytest.mark.parametrize("name", sorted(EXPECTED))
+def test_reader_on_a_fixture_ctx(name, pump_in_the_gap):
+    value = harness.load_reader(SEARCH, name)(_ctx())
+    assert value == pytest.approx(EXPECTED[name], rel=1e-6)
+    if name.endswith("_pct"):
+        assert 0 <= value <= 100
+
+
+@pytest.mark.parametrize("name", sorted(EXPECTED))
+def test_reader_on_a_program_without_the_record(name, monkeypatch):
+    """The parent commit: `/stats` has no `engine_trace`, the trace no
+    `engine.*` span. Nothing to read, nothing raised."""
+    monkeypatch.setattr(hostspans, "find_trace", lambda cell: Path("x.pb"))
+    monkeypatch.setattr(hostspans, "load_spans", lambda path: {})
+    read = harness.load_reader(SEARCH, name)
+    ctx = _ctx()
+    ctx["agent_stats"] = {"ttft_spans": {"n": 3}, "cached_blocks": 224}
+    assert read(ctx) is None
+    assert read({"trace": None, "agent_stats": {}, "hotpath": {}}) is None
+    ctx["agent_stats"] = {"engine_trace": {"recent": {"seconds": 0.0}}}
+    assert read(ctx) is None
+
+
+def test_kv_bandwidth_share_of_a_pool_type_it_does_not_know(pump_in_the_gap):
+    ctx = _ctx()
+    ctx["hf"] = dict(ctx["hf"], torch_dtype="float8_e4m3fn")
+    read = harness.load_reader(SEARCH, "kernel.paged_attn_kv_bw_pct")
+    assert read(ctx) is None
+    del ctx["hf"]["torch_dtype"]
+    assert read(ctx) is None
+
+
+def test_the_six_follow_the_thirteen_and_are_reported_where_they_read():
+    names = [m["name"] for m in BENCH["per_layer"]]
+    assert names[13:19] == [       # after the thirteen PR 23 brought
+        "engine.prefix_hit_pct", "engine.batch_live_mean",
+        "engine.kv_used_of_reserved_pct", "engine.host_busy_pct",
+        "kernel.paged_attn_kv_bw_pct", "device.idle_unfed_pct"]
+    for wl in BENCH["workloads"]:
+        got = {m["name"] for m in harness.metrics_for(BENCH, "per_layer",
+                                                      wl["name"])}
+        want = set(EXPECTED) - ({"engine.prefix_hit_pct"}
+                                if wl["name"] != CELL2 else set())
+        assert got & set(EXPECTED) == want

@@ -173,7 +173,7 @@ class TestLiveProfilingTables:
                 "max_tokens": 6, "temperature": 0, "ignore_eos": True},
                 timeout=120)
             assert r.status_code == 200, r.text
-        assert len(agent.engine.ttft_samples) >= 3
+        assert len(agent.engine.telemetry.admissions) >= 3
         ttft_table, tpot_table = agent.profiling_tables()
         assert ttft_table != agent.DEFAULT_TTFT_TABLE
         assert len(ttft_table) >= 3

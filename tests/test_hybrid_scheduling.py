@@ -87,5 +87,5 @@ class TestHybridScheduling:
         assert off_col.finish_reason == "length"
         # Engine drained cleanly, and the offline victim really was
         # preempted (not just co-scheduled).
-        assert engine.preemption_count >= 1
+        assert engine.telemetry.counters["preemptions"] >= 1
         assert engine.stats()["running"] == 0

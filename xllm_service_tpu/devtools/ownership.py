@@ -343,8 +343,14 @@ STATE_DISCIPLINES: dict[str, str] = {
     # window race was this registry's first runtime catch).
     "InferenceEngine.recent_max_ttft_ms": "lock:_telemetry_lock",
     "InferenceEngine.recent_max_tbt_ms": "lock:_telemetry_lock",
-    "InferenceEngine.preemption_count": "confined:engine-pump",
-    "InferenceEngine.sarathi_rides": "confined:engine-pump",
+    # The step loop's telemetry record (engine/telemetry.py): one object
+    # per engine, every counter and sample ring in it written by
+    # the pump alone (no lock on the write path); other threads copy and
+    # compute their views on read.
+    "InferenceEngine.telemetry": "init-only",
+    "EngineTelemetry._phase": "confined:engine-pump",
+    "EngineTelemetry._t_phase": "confined:engine-pump",
+    "EngineTelemetry._t_snapshot": "confined:engine-pump",
     # ---------------------------------------------------- SamplingProfiler
     # Continuous profiler (profiling/sampler.py): refcounted lifecycle +
     # window aggregates behind one leaf lock (order 824); the sampler
@@ -439,6 +445,10 @@ THREAD_ROLES: dict[str, dict] = {
         "entries": (
             "InferenceEngine._loop",
             "InferenceEngine.step",
+            # Reached from step() through `self.telemetry`, an attribute
+            # the static call graph does not follow.
+            "EngineTelemetry.switch",
+            "EngineTelemetry.tick",
         ),
     },
     "profiler": {

@@ -56,6 +56,7 @@ from ..tokenizer import TokenizerFactory
 from ..utils import get_local_ip, get_logger, pick_free_port
 from .config import EngineConfig, prefill_bucket_ladder
 from .engine import EngineRequest, InferenceEngine, PrefillHandoff
+from .telemetry import summarize as summarize_telemetry
 
 logger = get_logger(__name__)
 
@@ -633,11 +634,12 @@ class EngineAgent:
         ttft: dict[int, list[float]] = {}
         tpot: dict[int, list[tuple[int, float]]] = {}
         for eng in self.engines:
-            for plen, ms in list(eng.ttft_samples):
-                bucket = 1 << max(5, (plen - 1).bit_length())
-                ttft.setdefault(bucket, []).append(ms)
-            for batch, toks, ms in list(eng.tpot_samples):
-                tpot.setdefault(batch, []).append((toks, ms))
+            for a in list(eng.telemetry.admissions):
+                bucket = 1 << max(5, (a.prompt_len - 1).bit_length())
+                ttft.setdefault(bucket, []).append(a.prefill_ms)
+            for d in list(eng.telemetry.decodes):
+                tpot.setdefault(d.live, []).append(
+                    (d.context_tokens, d.ms_per_tok))
         ttft_table = [[b, statistics.median(v)]
                       for b, v in sorted(ttft.items())]
         tpot_table = [
@@ -978,7 +980,7 @@ class EngineAgent:
             "cached_blocks": sum(s["cached_blocks"] for s in per),
             "total_generated": sum(s["total_generated"] for s in per),
             "dp_size": len(self.engines),
-            "sarathi_rides": sum(getattr(e, "sarathi_rides", 0)
+            "sarathi_rides": sum(e.telemetry.counters["sarathi_rides"]
                                  for e in self.engines),
             "attention_paths": [s.get("attention_paths", {}) for s in per],
         }
@@ -1016,6 +1018,7 @@ class EngineAgent:
             },
             "kv_tier": self._tier_stats(),
             "ttft_spans": self._span_summary(),
+            "engine_trace": self.engine_trace(),
         })
 
     def _tier_stats(self) -> dict[str, Any]:
@@ -1038,14 +1041,19 @@ class EngineAgent:
             xs = sorted(xs)
             return round(xs[len(xs) // 2], 1) if xs else 0.0
 
-        eng = [s for e in self.engines
-               for s in getattr(e, "span_samples", ())]
+        eng = [a for e in self.engines for a in list(e.telemetry.admissions)
+               if a.queue_ms is not None]
         return {
             "n": len(self.ttft_spans),
             "agent_accept_to_first_delta_ms": p50(list(self.ttft_spans)),
-            "engine_queue_ms": p50([s["queue_ms"] for s in eng]),
-            "engine_prefill_ms": p50([s["prefill_ms"] for s in eng]),
+            "engine_queue_ms": p50([a.queue_ms for a in eng]),
+            "engine_prefill_ms": p50([a.prefill_ms for a in eng]),
         }
+
+    def engine_trace(self) -> dict[str, Any]:
+        """The engines' step-loop telemetry (engine/telemetry.py), summed
+        over replicas: totals since boot, and the last 30 s."""
+        return summarize_telemetry(e.telemetry for e in self.engines)
 
     async def _h_metrics(self, req: web.Request) -> web.Response:
         """Prometheus text exposition of engine state (the service's
@@ -1062,9 +1070,6 @@ class EngineAgent:
             f"engine_cached_prefix_blocks {st['cached_blocks']}",
             "# TYPE engine_generated_tokens_total counter",
             f"engine_generated_tokens_total {st['total_generated']}",
-            "# TYPE engine_preemptions_total counter",
-            f"engine_preemptions_total "
-            f"{sum(e.preemption_count for e in self.engines)}",
             "# TYPE engine_recent_max_ttft_milliseconds gauge",
             f"engine_recent_max_ttft_milliseconds "
             f"{max(e.recent_max_ttft_ms for e in self.engines):.3f}",
@@ -1073,9 +1078,20 @@ class EngineAgent:
             f"{max(e.recent_max_tbt_ms for e in self.engines):.3f}",
             "# TYPE engine_dp_size gauge",
             f"engine_dp_size {len(self.engines)}",
-            "# TYPE engine_sarathi_rides_total counter",
-            f"engine_sarathi_rides_total {st['sarathi_rides']}",
         ]
+        # The step loop's counters (engine_preemptions_total and
+        # engine_sarathi_rides_total among them), by-key families labeled.
+        labels = {"prefill_calls": "bucket", "decode_calls": "horizon",
+                  "admissions_blocked": "reason", "host_s": "phase"}
+        for name, v in self.engine_trace()["total"].items():
+            metric = ("engine_host_seconds_total" if name == "host_s"
+                      else f"engine_{name}_total")
+            lines.append(f"# TYPE {metric} counter")
+            if isinstance(v, dict):
+                lines += [f'{metric}{{{labels[name]}="{k}"}} {x}'
+                          for k, x in sorted(v.items())]
+            else:
+                lines.append(f"{metric} {v}")
         tel = self.telemetry_stats()
         lines += [
             "# TYPE engine_telemetry_session_hosts gauge",
@@ -1334,6 +1350,17 @@ class EngineAgent:
         stage = {"span": self._stage_span("engine.prefill", ctx, sid,
                                           prompt_tokens=len(token_ids))}
 
+        def end_prefill_span(err: Optional[str] = None) -> None:
+            # What the engine's admission found (prefix_hit_tokens, bucket,
+            # queue_ms): /admin/trace says whether the cache served THIS
+            # request.
+            a = getattr(stage.get("req"), "admission", None)
+            if a is not None:
+                stage["span"].set(prompt_tokens=a.prompt_len,
+                                  prefix_hit_tokens=a.matched,
+                                  bucket=a.bucket, queue_ms=a.queue_ms)
+            stage["span"].end(err)
+
         def on_output(out: RequestOutput) -> None:
             # Agent-side TTFT span: HTTP accept -> first delta pushed to
             # the streamer. Client TTFT minus this is master+wire cost.
@@ -1350,7 +1377,7 @@ class EngineAgent:
                 first_delta[0] = False
                 self.ttft_spans.append(
                     (time.monotonic() - t_recv) * 1000)
-                stage["span"].end(err)
+                end_prefill_span(err)
                 # A failed prefill (error surfaced before any token) has
                 # no decode stage — don't fabricate one.
                 stage["span"] = NOOP_SPAN if err else \
@@ -1369,19 +1396,20 @@ class EngineAgent:
             def on_prefill_done(h: PrefillHandoff,
                                 _peer: str = decode_name,
                                 _dest: str = dest) -> None:
-                stage["span"].end()
+                end_prefill_span()
                 threading.Thread(
                     target=self._transfer_to_peer, daemon=True,
                     args=(h, _peer, _dest, ctx),
                     name=f"kv-transfer-{h.service_request_id}").start()
 
-            self._pick_engine(token_ids).submit(EngineRequest(
+            stage["req"] = EngineRequest(
                 service_request_id=sid,
                 request_id=body.get("request_id", sid),
                 token_ids=token_ids, sampling=sampling,
                 mm_embeds=mm_embeds,
                 prefill_only=True, on_prefill_done=on_prefill_done,
-                on_output=on_output))   # surfaces prefill-side errors
+                on_output=on_output)   # surfaces prefill-side errors
+            self._pick_engine(token_ids).submit(stage["req"])
             return web.json_response({"ok": True,
                                       "service_request_id": sid})
 
@@ -1392,13 +1420,14 @@ class EngineAgent:
         n = max(1, sampling.n)
         engine = self._pick_engine(token_ids)
         if n == 1:
-            engine.submit(EngineRequest(
+            stage["req"] = EngineRequest(
                 service_request_id=sid,
                 request_id=body.get("request_id", sid),
                 token_ids=token_ids, sampling=sampling, on_output=on_output,
                 mm_embeds=mm_embeds,
                 offline=bool(body.get("offline", False)),
-                priority=int(body.get("priority") or 0)))
+                priority=int(body.get("priority") or 0))
+            engine.submit(stage["req"])
             return web.json_response({"ok": True, "service_request_id": sid})
 
         # All n choices go to ONE replica so its prefix cache dedupes the

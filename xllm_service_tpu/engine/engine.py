@@ -68,6 +68,7 @@ from ..utils import get_logger
 from .config import EngineConfig
 from .kv_cache import GARBAGE_PAGE, KVPageManager, SequencePages
 from .sampling import NUM_BIAS, SamplingState, record_tokens, sample_tokens
+from .telemetry import AdmissionSample, EngineTelemetry
 
 logger = get_logger(__name__)
 
@@ -104,6 +105,10 @@ class EngineRequest:
     # Set by submit(); lets the admission path split TTFT into queue wait
     # vs prefill execution (span profiling).
     t_submit: float = 0.0
+    # Set by the engine when the first token is out: what admission found
+    # (prompt length, prefix-cache match, bucket, queue wait), for the
+    # caller's own span of the request.
+    admission: Optional[AdmissionSample] = None
     # Multimodal (qwen2_vl family): visual embeddings [n_mm_tokens, D]
     # spliced into image-placeholder token positions during prefill.
     mm_embeds: Optional[np.ndarray] = None
@@ -365,18 +370,10 @@ class InferenceEngine:
         self.recent_max_ttft_ms = 0.0
         self.recent_max_tbt_ms = 0.0
         self.total_generated = 0
-        self.preemption_count = 0
-        # Mixed decode+chunk calls actually dispatched — the proof a
-        # Sarathi A/B arm exercised the path (surfaced via agent /stats).
-        self.sarathi_rides = 0
-        # Live latency samples the agent fits SLO profiling tables from
-        # (replacing offline tables, reference `common/types.h:207-210`):
-        # ttft: (prompt_len, ms); tpot: (batch, total_ctx_tokens, ms/tok).
-        self.ttft_samples: deque[tuple[int, float]] = deque(maxlen=512)
-        self.tpot_samples: deque[tuple[int, int, float]] = deque(maxlen=512)
-        # Per-admission span samples: where engine-side TTFT goes
-        # (queue wait vs prefill execution). serve_bench reports the p50s.
-        self.span_samples: deque[dict[str, float]] = deque(maxlen=512)
+        # Everything else the loop counts, and its per-admission and
+        # per-decode-call samples (the agent fits its SLO profiling tables
+        # and `/stats`.ttft_spans from them): engine/telemetry.py.
+        self.telemetry = EngineTelemetry()
         # Async decode pipeline: the last dispatched decode whose results
         # have not been fetched yet — (packed, t_dispatch, horizon,
         # {slot: seq} snapshot). Host-side output processing of step N
@@ -1323,7 +1320,7 @@ class InferenceEngine:
                 self._fail_all(str(e))
                 did_work = True
             if not did_work:
-                with self._lock:
+                with self.telemetry.phase("idle"), self._lock:
                     if not self._waiting and not self._running:
                         self._lock.wait(timeout=0.05)
 
@@ -1513,17 +1510,22 @@ class InferenceEngine:
         one chunk of one in-flight chunked prefill (round-robin), decode
         one horizon. Chunked prefill keeps long-prompt admission from
         stalling running decodes."""
-        self._process_cancellations()
-        worked = self._admit()
+        tel = self.telemetry
+        tel.tick()
+        with tel.phase("admit"):
+            self._process_cancellations()
+            worked = self._admit()
         # Sarathi mixed steps: the plain decode path consumes the front
         # prefilling sequence's next sub-chunks INSIDE the decode program
         # (_ride_chunk_args); only when nothing rode — spec path, no
         # running batch, final chunk, unsupported family — does the
         # standalone chunk program run.
         self._rode_chunk = False
-        decoded = self._decode()
+        with tel.phase("decode_dispatch"):
+            decoded = self._decode()
         if self._prefillings and not self._rode_chunk:
-            worked = self._advance_prefill() or worked
+            with tel.phase("prefill_dispatch"):
+                worked = self._advance_prefill() or worked
         return worked or decoded
 
     def _process_cancellations(self) -> None:
@@ -1552,7 +1554,9 @@ class InferenceEngine:
         for slot, seq in list(self._running.items()):
             if seq.req.service_request_id in cancelled:
                 seq.cancelled = True
+                victims.append(seq.req)
                 self._finish_sequence(seq, "abort", emit=True)
+        self.telemetry.counters["cancelled"] += len(victims)
 
     def _emit_cancelled(self, req: EngineRequest) -> None:
         req.on_output(RequestOutput(
@@ -1610,6 +1614,9 @@ class InferenceEngine:
             while True:
                 with self._lock:
                     if not self._free_slots:
+                        if self._waiting:
+                            self.telemetry.count_by("admissions_blocked",
+                                                    "no_slot")
                         _requeue_deferred()
                         return admitted
                     req = self._pop_next_waiting()
@@ -1658,6 +1665,7 @@ class InferenceEngine:
                         if self._start_sequence(req, batch=batch):
                             admitted = True
                             continue
+                    self.telemetry.count_by("admissions_blocked", "no_pages")
                     with self._lock:
                         self._waiting.appendleft(req)
                     _requeue_deferred()
@@ -1688,7 +1696,7 @@ class InferenceEngine:
             resume_logprobs=list(victim.logprobs))
         logger.info("preempting offline request %s after %d tokens",
                     req.service_request_id, len(victim.output_ids))
-        self.preemption_count += 1
+        self.telemetry.counters["preemptions"] += 1
         self._release_slot_and_pages(victim)
         victim.finished = True
         with self._lock:
@@ -1793,6 +1801,7 @@ class InferenceEngine:
             cached_hashes.append(hx)
             cached_pages.extend(pages)
             matched += hbs
+            self.telemetry.counters["prefix_onload_tokens"] += hbs
             i += 1
         return matched
 
@@ -1981,6 +1990,7 @@ class InferenceEngine:
         except Exception as e:  # noqa: BLE001
             self._fail_admission(seq, req, e)
             raise
+        self.telemetry.count_by("prefill_calls", "chunk")
         st["written"] += C
         self._prefillings.append(st)   # back of the round-robin
         return True
@@ -2013,8 +2023,9 @@ class InferenceEngine:
         completes the batch with _complete_admission once every waiting
         request's install is in the device queue."""
         try:
-            packed = self._dispatch_prefill_install(seq, prompt,
-                                                    prefix_written)
+            with self.telemetry.phase("prefill_dispatch"):
+                packed = self._dispatch_prefill_install(seq, prompt,
+                                                        prefix_written)
         except Exception as e:  # noqa: BLE001 — e.g. compile error on device
             # Fail THIS request visibly and return its resources, then
             # re-raise so the loop's _fail_all can deal with potentially
@@ -2042,16 +2053,14 @@ class InferenceEngine:
         ttft_ms = (now - t0) * 1000
         with self._telemetry_lock:
             self.recent_max_ttft_ms = max(self.recent_max_ttft_ms, ttft_ms)
-        self.ttft_samples.append((len(prompt), ttft_ms))
         # Engine-side TTFT span: how long the request queued before
         # admission vs how long the prefill program itself took. The
         # difference between a client-observed TTFT and these two is
         # service-plane overhead (HTTP hops, streamer flush, SSE).
-        if req.t_submit:
-            self.span_samples.append({
-                "queue_ms": (t0 - req.t_submit) * 1000,
-                "prefill_ms": ttft_ms,
-                "prompt_len": float(len(prompt))})
+        req.admission = self.telemetry.admitted(
+            len(prompt), cache_matched,
+            self._bucket_for(len(prompt) - prefix_written),
+            (t0 - req.t_submit) * 1000 if req.t_submit else None, ttft_ms)
 
         # Donate completed prompt blocks to the prefix cache (skip only the
         # blocks matched FROM the cache; self-written chunks are donated).
@@ -2306,6 +2315,8 @@ class InferenceEngine:
         P = cfg.pages_per_seq
         suffix = prompt[matched:]
         S = self._bucket_for(len(suffix))
+        self.telemetry.count_by("prefill_calls", S)
+        self.telemetry.counters["prefill_padded_tokens"] += S - len(suffix)
         toks = np.zeros((1, S), np.int32)
         toks[0, :len(suffix)] = suffix
 
@@ -2350,6 +2361,10 @@ class InferenceEngine:
         self._rng, slot_key = jax.random.split(self._rng)
         if sp.seed is not None:
             slot_key = jax.random.PRNGKey(sp.seed)
+        # The split runs on the device, behind whatever is in flight there:
+        # reading the key back is a wait for the chip, not host work.
+        with self.telemetry.phase("fetch_wait"):
+            key_bits = np.asarray(slot_key).view(np.int32).reshape(-1)[:2]
 
         # Visual embeddings for THIS suffix only (earlier chunks consumed
         # their own slices); padded to a bucket (4 images' worth) so a new
@@ -2364,8 +2379,7 @@ class InferenceEngine:
                                             matched + len(suffix), S)
             head += [pos3.reshape(-1), np.asarray([delta], np.int32)]
         packed_in = np.concatenate([
-            *head, ints, floats.view(np.int32), counts_row,
-            np.asarray(slot_key).view(np.int32).reshape(-1)[:2]])
+            *head, ints, floats.view(np.int32), counts_row, key_bits])
         if self._sp_applicable(len(suffix), matched, seq.req):
             prog = (self._prefill_install_sp if needs_counts
                     else self._prefill_install_sp_nc)
@@ -2379,7 +2393,8 @@ class InferenceEngine:
     def _complete_prefill_install(
             self, seq: _Sequence,
             packed: jax.Array) -> tuple[int, Optional[LogProb]]:
-        packed_np = self._fetch(packed)
+        with self.telemetry.phase("fetch_wait"):
+            packed_np = self._fetch(packed)
         K = self.cfg.max_top_logprobs
         token = int(packed_np[0])
         lp = self._make_logprob(token, float(packed_np[1]),
@@ -2428,7 +2443,7 @@ class InferenceEngine:
             self._dstate, packed = self._decode_chunk_multi(
                 self.params, self._dstate, *ride)
             self._rode_chunk = True
-            self.sarathi_rides += 1
+            self.telemetry.counters["sarathi_rides"] += 1
         else:
             self._dstate, packed = self._decode_multi(
                 self.params, self._dstate, horizon)
@@ -2438,31 +2453,54 @@ class InferenceEngine:
         # hidden behind device compute instead of serializing with it.
         snapshot = {slot: seq for slot, seq in self._running.items()
                     if not seq.finished}
+        self._count_decode_call(horizon, horizon, snapshot)
         prev, self._pending_decode = (self._pending_decode,
                                       (packed, t0, horizon, snapshot))
         if prev is not None:
-            self._drain_one_decode(prev)
+            with self.telemetry.phase("emit"):
+                self._drain_one_decode(prev)
         return True
+
+    def _count_decode_call(self, key, steps: int, snapshot: dict) -> None:
+        """Telemetry of one dispatched decode call (O(batch)): the
+        sequences it serves, their context and the pages they hold."""
+        context = pages = 0
+        for seq in snapshot.values():
+            context += seq.context_len
+            pages += len(seq.pages.cached_pages) + len(seq.pages.own_pages)
+        self.telemetry.decode_dispatched(key, steps, len(snapshot), context,
+                                         pages)
+
+    def _sample_decode_call(self, horizon: int, snapshot: dict,
+                            ms_per_tok: float) -> None:
+        """The fetched call's sample for the heartbeat's TPOT table: its
+        sequences still live now and their context (the rule the table was
+        always fitted from; what the call was *dispatched* with is in the
+        counters)."""
+        live = [s for s in snapshot.values() if not s.finished]
+        if live:
+            self.telemetry.decode_fetched(
+                horizon, len(live), sum(s.context_len for s in live),
+                ms_per_tok)
 
     def _drain_pending_decode(self) -> bool:
         pend, self._pending_decode = self._pending_decode, None
         if pend is None:
             return False
-        self._drain_one_decode(pend)
+        with self.telemetry.phase("emit"):
+            self._drain_one_decode(pend)
         return True
 
     def _drain_one_decode(self, pend: tuple) -> None:
         packed, t0, horizon, snapshot = pend
         K = self.cfg.max_top_logprobs
-        packed_np = self._fetch(packed)   # [H, B, 2+2K]
+        with self.telemetry.phase("fetch_wait"):
+            packed_np = self._fetch(packed)   # [H, B, 2+2K]
         elapsed = time.monotonic() - t0
         ms_per_tok = elapsed * 1000 / max(1, horizon)
         with self._telemetry_lock:
             self.recent_max_tbt_ms = max(self.recent_max_tbt_ms, ms_per_tok)
-        live = [s for s in snapshot.values() if not s.finished]
-        if live:
-            self.tpot_samples.append(
-                (len(live), sum(s.context_len for s in live), ms_per_tok))
+        self._sample_decode_call(horizon, snapshot, ms_per_tok)
 
         H = packed_np.shape[0]
         for slot, seq in snapshot.items():
@@ -2533,24 +2571,28 @@ class InferenceEngine:
             self.params, self._dstate, jnp.asarray(room), C)
         snapshot = {slot: seq for slot, seq in self._running.items()
                     if not seq.finished}
+        self._count_decode_call("spec", C, snapshot)
         prev, self._pending_spec = (self._pending_spec,
                                     (packed, t0, C, snapshot, n_seqs))
         if prev is not None:
-            self._drain_one_spec(prev)
+            with self.telemetry.phase("emit"):
+                self._drain_one_spec(prev)
         return True
 
     def _drain_pending_spec(self) -> bool:
         pend, self._pending_spec = self._pending_spec, None
         if pend is None:
             return False
-        self._drain_one_spec(pend)
+        with self.telemetry.phase("emit"):
+            self._drain_one_spec(pend)
         return True
 
     def _drain_one_spec(self, pend: tuple) -> None:
         packed, t0, C, snapshot, n_seqs = pend
         K = self.cfg.speculate_k
         Klp = self.cfg.max_top_logprobs
-        out = self._fetch(packed)            # [C, B, 1 + (K+1) + 1 + 2Klp]
+        with self.telemetry.phase("fetch_wait"):
+            out = self._fetch(packed)        # [C, B, 1 + (K+1) + 1 + 2Klp]
         elapsed = time.monotonic() - t0
 
         emitted = 0
@@ -2586,10 +2628,7 @@ class InferenceEngine:
         ms_per_tok = elapsed * 1000 / max(1.0, per_seq)
         with self._telemetry_lock:
             self.recent_max_tbt_ms = max(self.recent_max_tbt_ms, ms_per_tok)
-        live = [s for s in snapshot.values() if not s.finished]
-        if live:
-            self.tpot_samples.append(
-                (len(live), sum(s.context_len for s in live), ms_per_tok))
+        self._sample_decode_call(C, snapshot, ms_per_tok)
 
     # ----------------------------------------------------------- emission
     # Finalized-context window for the incremental diff: the tail is
@@ -2719,6 +2758,7 @@ class InferenceEngine:
                               num_generated_tokens=len(seq.output_ids))
             out.finished_on_prefill = len(seq.output_ids) == 1
             seq.finished = True
+            self.telemetry.counters["finished"] += 1
         try:
             seq.req.on_output(out)
         except Exception:  # noqa: BLE001
