@@ -49,9 +49,6 @@ def _bench_variant() -> str:
     """Non-default kernel/route knobs that change what is measured, kept
     in the record so an A/B arm is never read as the default config."""
     parts = []
-    wb = os.environ.get("XLLM_KV_WRITEBACK", "")
-    if wb:
-        parts.append(f"wb={wb}")
     if os.environ.get("XLLM_PREFILL_PALLAS", ""):
         parts.append("prefill_pallas")
     if os.environ.get("XLLM_MQ_PALLAS", ""):

@@ -43,6 +43,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 TOPOLOGY = "v5e:2x2"
+POOL_LAYERS = 2     # the kernel arms' pool: [L, 2, pages, n_kv, ps, hd]
 HBM_BYTES = int(15.75 * 2 ** 30)      # what the compiler allows one v5e chip
 
 
@@ -192,11 +193,11 @@ def kernel_arms(devices):
             paged_attention_pallas)
 
         def thunk():
-            fn = jax.jit(lambda q, k, v, pt, cl: paged_attention_pallas(
-                q, k, v, pt, cl, **kw))
+            fn = jax.jit(lambda q, kv, ly, pt, cl: paged_attention_pallas(
+                q, kv, ly, pt, cl, **kw))
             return fn.lower(f((B, n_q, hd), bf16),
-                            f((pool, n_kv, ps, hd), bf16),
-                            f((pool, n_kv, ps, hd), bf16),
+                            f((POOL_LAYERS, 2, pool, n_kv, ps, hd), bf16),
+                            f((1,), i32),
                             f((B, max_pages), i32), f((B,), i32)).compile()
         return thunk
 
@@ -226,17 +227,6 @@ def kernel_arms(devices):
 
     yield "page_gather_l32", mover("gather")
     yield "page_scatter_l32", mover("scatter")
-
-    def fused():
-        from xllm_service_tpu.ops.pallas_fused_decode_attention import (
-            fused_decode_attention_pallas)
-        new = f((16, n_kv, hd), bf16)
-        pool = f((2048, n_kv, ps, hd), bf16)
-        return jax.jit(fused_decode_attention_pallas).lower(
-            f((16, n_q, hd), bf16), new, new, pool, pool,
-            f((16, 128), i32), f((16,), i32)).compile()
-
-    yield "fused_append_attend", fused
 
     def mq(s_q):
         from xllm_service_tpu.ops.pallas_mq_paged_attention import (
@@ -269,18 +259,18 @@ def kernel_arms(devices):
 
         mesh = model_mesh(devices, 4)
         heads = NamedSharding(mesh, P(None, "model", None))
-        pool = NamedSharding(mesh, P(None, "model", None, None))
+        pool = NamedSharding(mesh, P(None, None, None, "model", None, None))
         rep = NamedSharding(mesh, P())
 
-        def fn(q, k, v, pt, cl):
+        def fn(q, kv, pt, cl):
             with attention.trace_program("paged_tp4", {}, mesh):
-                return attention.paged_attention(q, k, v, pt, cl)
+                return attention.paged_attention(q, kv, POOL_LAYERS - 1,
+                                                 pt, cl)
 
         with steer_to_tpu():
             return jax.jit(fn).lower(
                 f((16, n_q, hd), bf16, heads),
-                f((2048, n_kv, ps, hd), bf16, pool),
-                f((2048, n_kv, ps, hd), bf16, pool),
+                f((POOL_LAYERS, 2, 2048, n_kv, ps, hd), bf16, pool),
                 f((16, 128), i32, rep), f((16,), i32, rep)).compile()
 
     yield "paged_shard_map_tp4", paged_tp4

@@ -84,8 +84,10 @@ def main() -> None:
 
     # 1. single-device decode kernel (reference point).
     single = jax.jit(paged_attention_pallas)
+    pool = jnp.stack([k_pages, v_pages])[None]     # one layer's pool
     result["single_device_kernel_ms"] = round(
-        _time(single, q, k_pages, v_pages, page_table, clens), 4)
+        _time(single, q, pool, jnp.zeros((1,), jnp.int32), page_table,
+              clens), 4)
 
     # 2. CP Pallas partial kernel (Mosaic on accel; the validation target).
     def cp(qq, kk, vv, tt, cc):

@@ -436,16 +436,18 @@ def kernel_parity() -> dict:
     B, pages, max_pages = 16, 2048, 64
     ks = jax.random.split(jax.random.PRNGKey(SEED), 4)
     q = jax.random.normal(ks[0], (B, n_q, hd), jnp.bfloat16)
-    k_pages = jax.random.normal(ks[1], (pages, n_kv, ps, hd), jnp.bfloat16)
-    v_pages = jax.random.normal(ks[2], (pages, n_kv, ps, hd), jnp.bfloat16)
+    # Two layers, read at the second: the kernel indexes the whole pool.
+    pool = jax.random.normal(ks[1], (2, 2, pages, n_kv, ps, hd),
+                             jnp.bfloat16)
     host = np.random.default_rng(SEED)
     pt = jnp.asarray(host.permutation(pages - 1)[:B * max_pages]
                      .reshape(B, max_pages) + 1, jnp.int32)
     lens = jnp.asarray(host.integers(1, max_pages * ps + 1, size=B),
                        jnp.int32)
-    got = jax.jit(paged_attention_pallas)(q, k_pages, v_pages, pt, lens)
-    want = jax.jit(attention.paged_attention_xla)(q, k_pages, v_pages, pt,
-                                                  lens)
+    got = jax.jit(paged_attention_pallas)(
+        q, pool, jnp.ones((1,), jnp.int32), pt, lens)
+    want = jax.jit(attention.paged_attention_xla, static_argnums=2)(
+        q, pool, 1, pt, lens)
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     err = float(np.max(np.abs(got - want)))
     if got.shape != (B, n_q, hd) or not np.isfinite(got).all():

@@ -39,7 +39,7 @@ CHUNK2_PATTERNS=(
     test_chaos_failover test_kv_tiering test_fleet_observability
     test_hybrid_scheduling test_mixed_decode_chunk
     test_chunked_multimodal test_dp_replicas test_northstar_topology
-    test_pallas_engine_routing
+    test_pallas_engine_routing test_kv_pool_in_place
 )
 
 in_list() {

@@ -93,12 +93,12 @@ def test_master_and_actuator_stay_off_jax():
 def _paged_case(seed=0, B=2, n_q=4, n_kv=2, hd=128, ps=16, pages=12, mp=4):
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(B, n_q, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(pages, n_kv, ps, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(pages, n_kv, ps, hd)), jnp.float32)
+    pool = jnp.asarray(rng.normal(size=(2, 2, pages, n_kv, ps, hd)),
+                       jnp.float32)
     pt = jnp.asarray(rng.permutation(pages - 1)[:B * mp].reshape(B, mp) + 1,
                      jnp.int32)
     lens = jnp.asarray([ps * mp - 3, ps + 5], jnp.int32)
-    return q, k, v, pt, lens
+    return q, pool, 1, pt, lens       # read at the pool's second layer
 
 
 class TestAttentionPathRecord:
@@ -127,21 +127,19 @@ class TestAttentionPathRecord:
 
         monkeypatch.setenv("XLLM_PALLAS_INTERPRET", "1")
         mesh = build_mesh(MeshConfig(model=2), devices=jax.devices()[:2])
-        q, k, v, pt, lens = _paged_case(seed=1)
-        pool_spec = jax.sharding.PartitionSpec(*KV_PAGES_SPEC[2:])
-        k_s, v_s = (jax.device_put(a, NamedSharding(mesh, pool_spec))
-                    for a in (k, v))
+        q, pool, layer, pt, lens = _paged_case(seed=1)
+        pool_s = jax.device_put(pool, NamedSharding(mesh, KV_PAGES_SPEC))
         rec = {}
 
-        def step(q, k, v, pt, lens):
+        def step(q, pool, pt, lens):
             with attention.trace_program("prog", rec, mesh):
-                return attention.paged_attention(q, k, v, pt, lens)
+                return attention.paged_attention(q, pool, layer, pt, lens)
 
-        got = jax.jit(step)(q, k_s, v_s, pt, lens)
+        got = jax.jit(step)(q, pool_s, pt, lens)
         assert rec == {"prog": {
             "paged_attention": "pallas (shard_map model=2)"}}
         np.testing.assert_allclose(
-            got, attention.paged_attention_xla(q, k, v, pt, lens),
+            got, attention.paged_attention_xla(q, pool, layer, pt, lens),
             rtol=2e-5, atol=2e-5)
 
     def test_engine_stats_carry_the_record(self):

@@ -85,6 +85,27 @@ def test_decode_step_full_width_one_chip(chip):
         "paged_attention": "pallas"}
 
 
+def test_decode_step_holds_no_second_pool(chip):
+    """`qwen25-7b-int8` as the benchmark serves it (full depth, 2048
+    pages, B 32, horizon 8): the decode program's temporaries are a small
+    part of the 1.75 GiB pool. They were 1.84 GiB, the pool over again,
+    while each layer was sliced out for the kernel and stacked back."""
+    from chipbench.engine_setup import build_engine_config
+
+    name = "qwen25-7b-int8"
+    ecfg, _ = build_engine_config(REPO / "chipbench" / "configs" / name, 0,
+                                  name)
+    out = gate.compile_engine_programs(ecfg, device=chip[0],
+                                       horizons=(ecfg.decode_horizon,),
+                                       buckets=())
+    prog = out[f"decode_multi_h{ecfg.decode_horizon}"]
+    m = ecfg.model
+    pool_gib = (m.num_layers * 2 * ecfg.num_pages * m.num_kv_heads
+                * ecfg.page_size * m.head_dim * 2) / 2 ** 30
+    assert prog["tpu_custom_calls"] == m.num_layers
+    assert prog["temp_gib"] < 0.25 * pool_gib
+
+
 def test_decode_step_full_width_tp4(chip):
     """The same program partitioned over the 4-device model mesh: GSPMD
     cannot partition a Mosaic kernel, so it sits under shard_map on a

@@ -30,7 +30,7 @@ class TestCpPagedAttention:
     @pytest.mark.parametrize("sp", [2, 4])
     def test_matches_single_device(self, sp):
         q, kp, vp, pt, clens = make_case()
-        want = paged_attention_xla(q, kp, vp, pt, clens)
+        want = paged_attention_xla(q, jnp.stack([kp, vp])[None], 0, pt, clens)
         mesh = build_mesh(MeshConfig(seq=sp), devices=jax.devices()[:sp])
         with mesh:
             got = jax.jit(lambda *a: cp_paged_attention(
@@ -55,7 +55,7 @@ class TestCpPagedAttention:
 
         monkeypatch.setattr(cpmod, "_paged_partial_pallas", spy)
         q, kp, vp, pt, clens = make_case(hd=128, H=H, n_kv=n_kv, seed=5)
-        want = paged_attention_xla(q, kp, vp, pt, clens)
+        want = paged_attention_xla(q, jnp.stack([kp, vp])[None], 0, pt, clens)
         mesh = build_mesh(MeshConfig(seq=4), devices=jax.devices()[:4])
         with mesh:
             got = cp_paged_attention(q, kp, vp, pt, clens, mesh=mesh)
@@ -69,7 +69,7 @@ class TestCpPagedAttention:
         q, kp, vp, pt, clens = make_case(H=8, n_kv=2, seed=3)
         pt = pt.at[0].set(jnp.array([0, 0, 0, 0], jnp.int32))
         clens = clens.at[0].set(1)
-        want = paged_attention_xla(q, kp, vp, pt, clens)
+        want = paged_attention_xla(q, jnp.stack([kp, vp])[None], 0, pt, clens)
         mesh = build_mesh(MeshConfig(seq=4), devices=jax.devices()[:4])
         with mesh:
             got = cp_paged_attention(q, kp, vp, pt, clens, mesh=mesh)

@@ -431,9 +431,9 @@ class TestKVPageManager:
         assert mgr.cached_block_count() == 1
 
     def test_tail_page_never_donated(self):
-        """The fused decode kernel's whole-page RMW append is safe only
+        """The KV writer's whole-page read-modify-write is safe only
         because a partially-filled tail page stays PRIVATE to its
-        sequence (ops/pallas_fused_decode_attention.py). Donation must
+        sequence (ops/attention.write_kv). Donation must
         stay full-hash-block granular: a prompt whose tail doesn't fill a
         block leaves the tail page out of the donated set, and
         page-misaligned block sizes are rejected at construction."""

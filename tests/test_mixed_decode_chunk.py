@@ -10,7 +10,7 @@ import pytest
 
 from xllm_service_tpu.models.base import get_model_family, tiny_config
 from xllm_service_tpu.models.gemma import gemma2_tiny_config
-from xllm_service_tpu.ops.attention import prefill_attention, write_prefill_kv
+from xllm_service_tpu.ops.attention import prefill_attention, write_kv
 
 
 def _setup(cfg, family):
@@ -56,18 +56,17 @@ def test_mixed_step_matches_separate_programs(family, cfg):
             lp = jax.tree.map(lambda a, _l=l: a[_l], params["layers"])
             h = _norm(x, lp["input_norm"]["scale"], cfg)
             q, k, v = _project_qkv(lp, h, cfg, chunk_pos[None])
-            kp, vp = write_prefill_kv(
-                pool[l, 0], pool[l, 1], k, v, chunk_pt,
+            pool = write_kv(
+                pool, l, k, v, chunk_pt,
                 jnp.asarray([start], jnp.int32),
                 jnp.asarray([valid], jnp.int32))
             attn = prefill_attention(
-                q, k, v, kp, vp, chunk_pt,
+                q, k, v, pool, l, chunk_pt,
                 jnp.asarray([start], jnp.int32),
                 jnp.asarray([valid], jnp.int32), **_attn_opts(cfg, l))
             from xllm_service_tpu.models.llama import _attn_mlp_residual
             x = _attn_mlp_residual(lp, x,
                                    attn.reshape(1, c, cfg.q_size), cfg)
-            pool = pool.at[l, 0].set(kp).at[l, 1].set(vp)
         return pool
 
     ref_pool = jax.jit(ref_chunk)(ref_pool)

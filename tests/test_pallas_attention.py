@@ -1,22 +1,74 @@
-"""Pallas paged-attention kernel vs XLA reference (interpret mode on CPU)."""
+"""Pallas paged-attention kernel vs XLA reference (interpret mode on CPU),
+and both against a plain per-layer form: the kernel and the gather read
+`(pool, layer)` where the pool lies, `write_kv` appends into it in place."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from xllm_service_tpu.ops.attention import paged_attention_xla, write_decode_kv
+from xllm_service_tpu.ops.attention import (
+    decode_attention_step,
+    paged_attention_xla,
+    write_kv,
+)
 from xllm_service_tpu.ops.pallas_paged_attention import paged_attention_pallas
+
+LAYERS = 3          # every case reads/writes one layer of a 3-layer pool
 
 
 def _setup(B=4, n_q=8, n_kv=4, hd=128, pages=32, ps=16, max_pages=6, seed=0):
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
-    k_pages = jax.random.normal(k1, (pages, n_kv, ps, hd), jnp.float32)
-    v_pages = jax.random.normal(k2, (pages, n_kv, ps, hd), jnp.float32)
-    q = jax.random.normal(k3, (B, n_q, hd), jnp.float32)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(seed), 2)
+    pool = jax.random.normal(k1, (LAYERS, 2, pages, n_kv, ps, hd),
+                             jnp.float32)
+    q = jax.random.normal(k2, (B, n_q, hd), jnp.float32)
     # Distinct pages per row, nonzero ids (page 0 = garbage).
     pt = (jnp.arange(B * max_pages, dtype=jnp.int32).reshape(B, max_pages) + 1)
-    return q, k_pages, v_pages, pt
+    return q, pool, pt
+
+
+def _kernel(q, pool, layer, pt, cl, **kw):
+    return paged_attention_pallas(q, pool, jnp.full((1,), layer, jnp.int32),
+                                  pt, cl, interpret=True, **kw)
+
+
+def _per_layer_attention(q, k_pages, v_pages, pt, cl, scale=None,
+                         softcap=0.0, window=0):
+    """The plain per-layer form: one layer's K and V pages as arrays of
+    their own, dense softmax per row in numpy."""
+    q, k_pages, v_pages = (np.asarray(a, np.float64)
+                           for a in (q, k_pages, v_pages))
+    pt, cl = np.asarray(pt), np.asarray(cl)
+    B, n_q, hd = q.shape
+    n_kv, ps = k_pages.shape[1], k_pages.shape[2]
+    scale = hd ** -0.5 if scale is None else scale
+    out = np.zeros((B, n_q, hd))
+    for b in range(B):
+        c = int(cl[b])
+        if c == 0:
+            continue
+        k = k_pages[pt[b]].transpose(0, 2, 1, 3).reshape(-1, n_kv, hd)[:c]
+        v = v_pages[pt[b]].transpose(0, 2, 1, 3).reshape(-1, n_kv, hd)[:c]
+        lo = max(c - window, 0) if window else 0
+        for h in range(n_q):
+            s = k[lo:, h // (n_q // n_kv)] @ (q[b, h] * scale)
+            if softcap:
+                s = softcap * np.tanh(s / softcap)
+            p = np.exp(s - s.max())
+            out[b, h] = (p / p.sum()) @ v[lo:, h // (n_q // n_kv)]
+    return out
+
+
+def _per_layer_append(pool, layer, k_new, v_new, pt, pos):
+    """The plain per-layer write: token rows at (page, :, slot, :) of the
+    layer's own K and V arrays; every other byte of the pool as it was."""
+    want = np.array(pool)
+    ps = want.shape[4]
+    for b, p in enumerate(np.asarray(pos)):
+        page = int(np.asarray(pt)[b, p // ps])
+        want[layer, 0, page, :, p % ps, :] = np.asarray(k_new)[b]
+        want[layer, 1, page, :, p % ps, :] = np.asarray(v_new)[b]
+    return want
 
 
 class TestPallasPagedAttention:
@@ -26,11 +78,10 @@ class TestPallasPagedAttention:
         [5, 96, 0, 50],            # includes an inactive row (ctx 0)
     ])
     def test_matches_xla(self, context_lens):
-        q, k_pages, v_pages, pt = _setup()
+        q, pool, pt = _setup()
         cl = jnp.asarray(context_lens, jnp.int32)
-        ref = paged_attention_xla(q, k_pages, v_pages, pt, cl)
-        got = paged_attention_pallas(q, k_pages, v_pages, pt, cl,
-                                     interpret=True)
+        ref = paged_attention_xla(q, pool, 1, pt, cl)
+        got = _kernel(q, pool, 1, pt, cl)
         # Rows with ctx 0 are undefined in both paths; compare active rows.
         for b, c in enumerate(context_lens):
             if c > 0:
@@ -52,11 +103,10 @@ class TestPallasPagedAttention:
         chunk counts, and row boundaries."""
         monkeypatch.setenv("XLLM_PAGE_PIPELINE", "row")
         monkeypatch.setenv("XLLM_PAGE_CHUNK", "1")   # maximize row turns
-        q, k_pages, v_pages, pt = _setup()
+        q, pool, pt = _setup()
         cl = jnp.asarray(context_lens, jnp.int32)
-        ref = paged_attention_xla(q, k_pages, v_pages, pt, cl)
-        got = paged_attention_pallas(q, k_pages, v_pages, pt, cl,
-                                     interpret=True)
+        ref = paged_attention_xla(q, pool, 2, pt, cl)
+        got = _kernel(q, pool, 2, pt, cl)
         for b, c in enumerate(context_lens):
             if c > 0:
                 np.testing.assert_allclose(np.asarray(got[b]),
@@ -69,15 +119,15 @@ class TestPallasPagedAttention:
         full-span branch for compile time): every ladder rung must match
         the full-span gather, including at occupancies that select the
         shortest span."""
-        q, k_pages, v_pages, pt = _setup()
+        q, pool, pt = _setup()
         for cls in ([8, 12, 4, 16],              # shortest span
                     [40, 41, 33, 50],            # middle rung
                     [96, 96, 96, 96]):           # full span
             cl = jnp.asarray(cls, jnp.int32)
             monkeypatch.setenv("XLLM_XLA_SPAN_BUCKETS", "0")
-            ref = paged_attention_xla(q, k_pages, v_pages, pt, cl)
+            ref = paged_attention_xla(q, pool, 0, pt, cl)
             monkeypatch.setenv("XLLM_XLA_SPAN_BUCKETS", "1")
-            got = paged_attention_xla(q, k_pages, v_pages, pt, cl)
+            got = paged_attention_xla(q, pool, 0, pt, cl)
             np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                        rtol=2e-6, atol=2e-6)
 
@@ -91,116 +141,124 @@ class TestPallasPagedAttention:
         """softcap / sliding window / explicit query scale are static
         kernel params now — gemma-2 decode must route through the kernel
         with XLA-exact numerics."""
-        q, k_pages, v_pages, pt = _setup()
+        q, pool, pt = _setup()
         cl = jnp.asarray([96, 41, 8, 64], jnp.int32)
-        ref = paged_attention_xla(q, k_pages, v_pages, pt, cl, **opts)
-        got = paged_attention_pallas(q, k_pages, v_pages, pt, cl,
-                                     interpret=True, **opts)
+        ref = paged_attention_xla(q, pool, 1, pt, cl, **opts)
+        got = _kernel(q, pool, 1, pt, cl, **opts)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
 
     def test_gqa_grouping(self):
-        q, k_pages, v_pages, pt = _setup(n_q=16, n_kv=2)
+        q, pool, pt = _setup(n_q=16, n_kv=2)
         cl = jnp.asarray([40, 96, 8, 64], jnp.int32)
-        ref = paged_attention_xla(q, k_pages, v_pages, pt, cl)
-        got = paged_attention_pallas(q, k_pages, v_pages, pt, cl,
-                                     interpret=True)
+        ref = paged_attention_xla(q, pool, 1, pt, cl)
+        got = _kernel(q, pool, 1, pt, cl)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
 
-    def test_fused_decode_step_parity(self):
-        """Fused append+attend kernel == scatter-then-attend: attention
-        output AND resulting pool contents must both match."""
-        from xllm_service_tpu.ops.pallas_fused_decode_attention import (
-            fused_decode_attention_pallas,
-        )
+    @pytest.mark.parametrize("layer", [0, 1, LAYERS - 1])
+    @pytest.mark.parametrize("heads", [(8, 8), (16, 2)],
+                             ids=["mha", "gqa"])
+    @pytest.mark.parametrize("opts", [
+        {}, {"softcap": 50.0, "window": 33}], ids=["plain", "softcap-window"])
+    def test_pool_layer_reads_match_per_layer_form(self, layer, heads, opts):
+        """The kernel on (pool, layer) and the XLA gather on (pool, layer)
+        both equal the per-layer form run on that layer's own K and V
+        arrays — first, middle and last layer, MHA and GQA, with and
+        without the gemma-2 statics."""
+        q, pool, pt = _setup(n_q=heads[0], n_kv=heads[1])
+        cl = jnp.asarray([96, 41, 8, 64], jnp.int32)
+        want = _per_layer_attention(q, pool[layer, 0], pool[layer, 1], pt,
+                                    cl, **opts)
+        for got in (_kernel(q, pool, layer, pt, cl, **opts),
+                    paged_attention_xla(q, pool, layer, pt, cl, **opts)):
+            np.testing.assert_allclose(np.asarray(got), want,
+                                       rtol=2e-4, atol=2e-4)
 
-        q, k_pages, v_pages, pt = _setup()
-        B, n_kv, hd = 4, 4, 128
+    @pytest.mark.parametrize("layer", [0, 1, LAYERS - 1])
+    @pytest.mark.parametrize("heads", [(8, 8), (16, 2)],
+                             ids=["mha", "gqa"])
+    @pytest.mark.parametrize("opts", [
+        {}, {"softcap": 50.0, "window": 33}], ids=["plain", "softcap-window"])
+    def test_decode_step_appends_in_place(self, layer, heads, opts,
+                                          monkeypatch):
+        """The one decode append+attend path, routed through the kernel:
+        attention output equals the per-layer form after a per-layer row
+        write, and the pool holds the same bytes — the written layer's
+        token rows and nothing else, in any layer."""
+        monkeypatch.setenv("XLLM_PALLAS_INTERPRET", "1")
+        q, pool, pt = _setup(n_q=heads[0], n_kv=heads[1])
+        B, n_kv, hd = 4, heads[1], 128
         for prev in ([10, 20, 30, 40],   # mid-page appends
-                     [0, 16, 31, 95],    # page starts/edges + pool-full row
-                     [0, 0, 0, 0]):      # empty contexts: first token ever
-            cl_prev = jnp.asarray(prev, jnp.int32)
+                     [0, 16, 31, 95]):   # first token, page edges, last slot
+            cl = jnp.asarray(prev, jnp.int32) + 1
             k_new = jax.random.normal(jax.random.PRNGKey(9), (B, n_kv, hd))
             v_new = jax.random.normal(jax.random.PRNGKey(10), (B, n_kv, hd))
-            kp_ref, vp_ref = write_decode_kv(k_pages, v_pages, k_new, v_new,
-                                             pt, cl_prev)
-            cl = cl_prev + 1
-            ref = paged_attention_xla(q, kp_ref, vp_ref, pt, cl)
-            got, kp_got, vp_got = fused_decode_attention_pallas(
-                q, k_new, v_new, k_pages, v_pages, pt, cl, interpret=True)
-            np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                       rtol=2e-5, atol=2e-5)
-            np.testing.assert_array_equal(np.asarray(kp_got),
-                                          np.asarray(kp_ref))
-            np.testing.assert_array_equal(np.asarray(vp_got),
-                                          np.asarray(vp_ref))
+            want_pool = _per_layer_append(pool, layer, k_new, v_new, pt, prev)
+            want = _per_layer_attention(q, want_pool[layer, 0],
+                                        want_pool[layer, 1], pt, cl, **opts)
+            got, got_pool = decode_attention_step(q, k_new, v_new, pool,
+                                                  layer, pt, cl, **opts)
+            np.testing.assert_allclose(np.asarray(got), want,
+                                       rtol=2e-4, atol=2e-4)
+            np.testing.assert_array_equal(np.asarray(got_pool), want_pool)
 
-    def test_fused_decode_step_parity_rowpipe(self, monkeypatch):
-        """Fused kernel with cross-row pipelining: same parity contract
-        as the default walk across empty contexts, page edges, and odd
-        chunk counts."""
-        from xllm_service_tpu.ops.pallas_fused_decode_attention import (
-            fused_decode_attention_pallas,
-        )
-
+    def test_decode_step_rowpipe(self, monkeypatch):
+        """The append + the kernel's cross-row pipelining: same parity
+        contract across empty contexts, page edges, and odd chunk counts
+        (rows that hold nothing but the token just written)."""
+        monkeypatch.setenv("XLLM_PALLAS_INTERPRET", "1")
         monkeypatch.setenv("XLLM_PAGE_PIPELINE", "row")
         monkeypatch.setenv("XLLM_PAGE_CHUNK", "1")   # maximize row turns
-        q, k_pages, v_pages, pt = _setup()
+        q, pool, pt = _setup()
         B, n_kv, hd = 4, 4, 128
-        for prev in ([10, 20, 30, 40],
-                     [0, 16, 31, 95],
-                     [0, 0, 0, 0],
-                     [50, 0, 0, 12]):    # empty rows between active ones
-            cl_prev = jnp.asarray(prev, jnp.int32)
+        for prev in ([10, 20, 30, 40], [0, 16, 31, 95], [0, 0, 0, 0],
+                     [50, 0, 0, 12]):
+            cl = jnp.asarray(prev, jnp.int32) + 1
             k_new = jax.random.normal(jax.random.PRNGKey(9), (B, n_kv, hd))
             v_new = jax.random.normal(jax.random.PRNGKey(10), (B, n_kv, hd))
-            kp_ref, vp_ref = write_decode_kv(k_pages, v_pages, k_new, v_new,
-                                             pt, cl_prev)
-            cl = cl_prev + 1
-            ref = paged_attention_xla(q, kp_ref, vp_ref, pt, cl)
-            got, kp_got, vp_got = fused_decode_attention_pallas(
-                q, k_new, v_new, k_pages, v_pages, pt, cl, interpret=True)
+            want_pool = _per_layer_append(pool, 1, k_new, v_new, pt, prev)
+            ref = paged_attention_xla(q, jnp.asarray(want_pool), 1, pt, cl)
+            got, got_pool = decode_attention_step(q, k_new, v_new, pool, 1,
+                                                  pt, cl)
             np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                        rtol=2e-5, atol=2e-5)
-            np.testing.assert_array_equal(np.asarray(kp_got),
-                                          np.asarray(kp_ref))
-            np.testing.assert_array_equal(np.asarray(vp_got),
-                                          np.asarray(vp_ref))
+            np.testing.assert_array_equal(np.asarray(got_pool), want_pool)
 
-    def test_fused_decode_step_gqa(self):
-        from xllm_service_tpu.ops.pallas_fused_decode_attention import (
-            fused_decode_attention_pallas,
-        )
-
-        q, k_pages, v_pages, pt = _setup(n_q=16, n_kv=2)
-        B, n_kv, hd = 4, 2, 128
-        cl_prev = jnp.asarray([3, 40, 64, 95], jnp.int32)
-        k_new = jax.random.normal(jax.random.PRNGKey(4), (B, n_kv, hd))
-        v_new = jax.random.normal(jax.random.PRNGKey(5), (B, n_kv, hd))
-        kp_ref, vp_ref = write_decode_kv(k_pages, v_pages, k_new, v_new,
-                                         pt, cl_prev)
-        cl = cl_prev + 1
-        ref = paged_attention_xla(q, kp_ref, vp_ref, pt, cl)
-        got, kp_got, vp_got = fused_decode_attention_pallas(
-            q, k_new, v_new, k_pages, v_pages, pt, cl, interpret=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-        np.testing.assert_array_equal(np.asarray(kp_got), np.asarray(kp_ref))
-        np.testing.assert_array_equal(np.asarray(vp_got), np.asarray(vp_ref))
+    @pytest.mark.parametrize("B,S", [(1, 40), (2, 16), (3, 7), (2, 1)])
+    def test_write_kv_runs_match_per_token_writes(self, B, S):
+        """Prefill suffixes, verify blocks and chunks through the one
+        writer: runs starting mid-page, ending mid-page, padded
+        (lens < S) or empty land exactly where per-token row writes put
+        them; page 0 and every other layer keep their bytes."""
+        rng = np.random.default_rng(S)
+        _, pool, pt = _setup(B=B)
+        n_kv, hd = pool.shape[3], pool.shape[5]
+        k = jnp.asarray(rng.normal(size=(B, S, n_kv, hd)), jnp.float32)
+        v = jnp.asarray(rng.normal(size=(B, S, n_kv, hd)), jnp.float32)
+        start = jnp.asarray(rng.integers(0, 96 - S + 1, size=B), jnp.int32)
+        lens = jnp.asarray(rng.integers(0, S + 1, size=B), jnp.int32)
+        want = np.array(pool)
+        for b in range(B):
+            for j in range(int(lens[b])):
+                p = int(start[b]) + j
+                want[2, 0, pt[b, p // 16], :, p % 16, :] = k[b, j]
+                want[2, 1, pt[b, p // 16], :, p % 16, :] = v[b, j]
+        got = jax.jit(write_kv, static_argnums=1)(pool, 2, k, v, pt, start,
+                                                  lens)
+        np.testing.assert_array_equal(np.asarray(got), want)
 
     def test_after_decode_write(self):
         """End-to-end shape: write one token then attend, both paths."""
-        q, k_pages, v_pages, pt = _setup()
+        q, pool, pt = _setup()
         B, n_kv, hd = 4, 4, 128
         cl_prev = jnp.asarray([10, 20, 30, 40], jnp.int32)
         k_new = jax.random.normal(jax.random.PRNGKey(9), (B, n_kv, hd))
         v_new = jax.random.normal(jax.random.PRNGKey(10), (B, n_kv, hd))
-        k_pages, v_pages = write_decode_kv(k_pages, v_pages, k_new, v_new,
-                                           pt, cl_prev)
+        pool = write_kv(pool, 1, k_new[:, None], v_new[:, None], pt,
+                        cl_prev, jnp.ones_like(cl_prev))
         cl = cl_prev + 1
-        ref = paged_attention_xla(q, k_pages, v_pages, pt, cl)
-        got = paged_attention_pallas(q, k_pages, v_pages, pt, cl,
-                                     interpret=True)
+        ref = paged_attention_xla(q, pool, 1, pt, cl)
+        got = _kernel(q, pool, 1, pt, cl)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)

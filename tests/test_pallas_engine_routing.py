@@ -2,9 +2,10 @@
 
 XLLM_PALLAS_INTERPRET=1 makes the dispatch gates treat the CPU backend as
 kernel-capable and run every Pallas kernel in interpret mode, so these
-tests drive the REAL trace-time routing (fused decode writeback, Pallas
-chunked-prefill attention) end-to-end through the engine and compare
-greedy outputs against the default XLA paths. Tiny 1-layer config with
+tests drive the REAL trace-time routing (the (pool, layer) decode kernel
+behind the in-place append, Pallas chunked-prefill attention) end-to-end
+through the engine and compare greedy outputs against the default XLA
+paths. Tiny 1-layer config with
 head_dim=128 (the Mosaic lane-width requirement the gates check).
 """
 
@@ -44,13 +45,16 @@ PROMPT = [7, 11, 13, 17, 19, 23, 29, 31, 37, 41]
 
 
 class TestPallasEngineRouting:
-    def test_fused_decode_writeback_matches_default(self, monkeypatch):
+    def test_kernel_decode_matches_default(self, monkeypatch):
+        """decode_multi through the kernel: it reads the pool the append
+        just wrote in place, layer by scalar-prefetch index."""
         baseline = _greedy(_pallas_capable_engine(), PROMPT)
         assert len(baseline) == 6
         monkeypatch.setenv("XLLM_PALLAS_INTERPRET", "1")
-        monkeypatch.setenv("XLLM_KV_WRITEBACK", "fused")
-        fused = _greedy(_pallas_capable_engine(), PROMPT)
-        assert fused == baseline
+        engine = _pallas_capable_engine()
+        assert _greedy(engine, PROMPT) == baseline
+        assert engine.stats()["attention_paths"]["decode_multi"] == {
+            "paged_attention": "pallas"}
 
     def test_pallas_prefill_matches_default(self, monkeypatch):
         baseline = _greedy(_pallas_capable_engine(), PROMPT)
@@ -62,7 +66,6 @@ class TestPallasEngineRouting:
     def test_all_pallas_paths_together(self, monkeypatch):
         baseline = _greedy(_pallas_capable_engine(), PROMPT)
         monkeypatch.setenv("XLLM_PALLAS_INTERPRET", "1")
-        monkeypatch.setenv("XLLM_KV_WRITEBACK", "fused")
         monkeypatch.setenv("XLLM_PREFILL_PALLAS", "1")
         routed = _greedy(_pallas_capable_engine(), PROMPT)
         assert routed == baseline

@@ -458,7 +458,7 @@ class InferenceEngine:
             plain decode scan and the Sarathi mixed decode+chunk scan."""
             toks, logprobs = sample_tokens(
                 logits, sampling_state(d), d["keys"], d["clens"],
-                want_logprobs=d["want_lp"])
+                want_logprobs=d["want_lp"], live=d["active"])
             d["counts"] = record_tokens(d["counts"], toks, d["active"])
 
             # Full-vocab log_softmax + top-k cost real bandwidth; only
@@ -476,7 +476,8 @@ class InferenceEngine:
                         jnp.zeros((B_, K), jnp.int32))
 
             chosen, tv, ti = jax.lax.cond(
-                jnp.any(d["want_lp"]), _with_lp, _no_lp, operand=None)
+                jnp.any(d["want_lp"] & d["active"]), _with_lp, _no_lp,
+                operand=None)
             if spec_on:
                 # Append to the device history (speculation draws
                 # drafts from it; the emitted token lands at position
@@ -833,7 +834,7 @@ class InferenceEngine:
                     # the forward of `last`, exactly the decode step).
                     toks0, logprobs0 = sample_tokens(
                         logits[:, 0, :], sampling_state(d), d["keys"],
-                        d["clens"], want_logprobs=d["want_lp"])
+                        d["clens"], want_logprobs=d["want_lp"], live=live)
                     d["counts"] = record_tokens(d["counts"], toks0,
                                                 live & ~spec_ok)
                     emit0 = jnp.where(spec_ok, preds[:, 0], toks0)
@@ -851,7 +852,7 @@ class InferenceEngine:
                                 jnp.zeros((B, K), jnp.int32))
 
                     chosen, tv, ti = jax.lax.cond(
-                        jnp.any(d["want_lp"]), _with_lp, _no_lp,
+                        jnp.any(d["want_lp"] & live), _with_lp, _no_lp,
                         operand=None)
                     match = (drafts == preds[:, :Kd]).astype(jnp.int32)
                     acc = jnp.cumprod(match, axis=1).sum(axis=1)   # [B]

@@ -41,15 +41,15 @@ class KVPageManager:
                  hash_block_size: int):
         # Donation granularity is FULL hash blocks of whole pages: a
         # partially-filled (tail) page is never donated, so it stays
-        # private to its sequence. The fused decode kernel
-        # (ops/pallas_fused_decode_attention.py) relies on exactly this to
-        # make its whole-page read-modify-write append safe — if donation
-        # ever becomes page- or token-granular, that kernel would silently
-        # clobber shared KV. Fail loudly here instead.
+        # private to its sequence. The pool's one writer
+        # (ops/attention.write_kv) relies on exactly this to make its
+        # whole-page read-modify-write safe — if donation ever becomes
+        # page- or token-granular, it would silently clobber shared KV.
+        # Fail loudly here instead.
         if hash_block_size % page_size != 0:
             raise ValueError(
                 "hash_block_size must be a whole number of pages: the "
-                "fused decode kernel's tail-page-privacy invariant "
+                "KV writer's tail-page-privacy invariant "
                 "depends on full-page donation granularity")
         self.page_size = page_size
         self.hash_block_size = hash_block_size

@@ -94,9 +94,16 @@ def _mask_top_p(logits: jax.Array, top_p: jax.Array) -> jax.Array:
 
 def sample_tokens(logits: jax.Array, st: SamplingState,
                   keys: jax.Array, steps: jax.Array,
-                  want_logprobs=None) -> tuple[jax.Array, jax.Array]:
+                  want_logprobs=None, live=None,
+                  ) -> tuple[jax.Array, jax.Array]:
     """logits [B, V] f32, keys [B] per-slot PRNG keys, steps [B] i32 ->
     (tokens [B] i32, logprobs_full [B, V] f32).
+
+    `live` [B] bool: the rows whose token anyone reads (the decode batch's
+    active slots; None = all). Only they decide whether the sampling and
+    log-probability branches run: a slot that holds no request keeps the
+    temperature and `want_logprobs` of its last occupant (1.0 if it never
+    had one), and must not buy the batch two full-vocabulary sorts a step.
 
     Each row samples with fold_in(keys[b], steps[b]) — deterministic per
     request (and per `seed`) regardless of batch composition. Greedy where
@@ -118,7 +125,12 @@ def sample_tokens(logits: jax.Array, st: SamplingState,
 
     # The top-k/top-p masks cost full-vocab sorts; skip the whole branch at
     # runtime when every slot is greedy (the common serving case).
-    tokens = jax.lax.cond(jnp.any(st.temperature > 0.0), _sample,
+    sampled_rows = st.temperature > 0.0
+    if live is not None:
+        sampled_rows &= live
+        if want_logprobs is not None:
+            want_logprobs = want_logprobs & live
+    tokens = jax.lax.cond(jnp.any(sampled_rows), _sample,
                           lambda _: greedy_tokens, operand=None)
     if want_logprobs is None:
         logprobs = jax.nn.log_softmax(logits, axis=-1)

@@ -33,8 +33,7 @@ from ..ops.attention import (
     decode_attention_step,
     prefill_attention,
     rms_norm,
-    write_decode_kv,
-    write_prefill_kv,
+    write_kv,
 )
 from ..parallel.mesh import AXIS_EXPERT, AXIS_MODEL
 from ..parallel.sharding import ShardingRules
@@ -238,7 +237,7 @@ def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     return routed.reshape(orig_shape)
 
 
-def _mla_attention(lp, cfg, h, mode, k_pages, v_pages, page_table,
+def _mla_attention(lp, cfg, h, mode, kv_pages, layer, page_table,
                    prefix_lens, seq_lens, positions, context_lens):
     """mode: "prefill" | "decode" | "dense" (dense = no paged cache at
     all — the embeddings path; nothing is written)."""
@@ -247,7 +246,7 @@ def _mla_attention(lp, cfg, h, mode, k_pages, v_pages, page_table,
     V up-projection applied after attention — so the existing paged
     attention ops run unchanged over latents (n_kv=1).
 
-    Returns (attn_out flattened [..., H*dv], k_pages, v_pages)."""
+    Returns (attn_out flattened [..., H*dv], kv_pages)."""
     from ..ops.attention import apply_rope, paged_attention_xla
 
     H, dn = cfg.num_heads, cfg.qk_nope_head_dim
@@ -276,22 +275,22 @@ def _mla_attention(lp, cfg, h, mode, k_pages, v_pages, page_table,
                                  jnp.zeros(h.shape[:1], jnp.int32),
                                  seq_lens, scale=scale)
     elif mode == "prefill":
-        k_pages, v_pages = write_prefill_kv(k_pages, v_pages, entry, entry,
-                                            page_table, prefix_lens, seq_lens)
-        attn = prefill_attention(q_lat, entry, entry, k_pages, v_pages,
+        kv_pages = write_kv(kv_pages, layer, entry, entry, page_table,
+                            prefix_lens, seq_lens)
+        attn = prefill_attention(q_lat, entry, entry, kv_pages, layer,
                                  page_table, prefix_lens, seq_lens,
                                  scale=scale)
     else:
-        k_pages, v_pages = write_decode_kv(k_pages, v_pages, entry, entry,
-                                           page_table, positions)
-        attn = paged_attention_xla(q_lat, k_pages, v_pages, page_table,
+        kv_pages = write_kv(kv_pages, layer, entry[:, None], entry[:, None],
+                            page_table, positions, jnp.ones_like(positions))
+        attn = paged_attention_xla(q_lat, kv_pages, layer, page_table,
                                    context_lens, scale=scale)
     # The weighted sum over [c ‖ k_rope] entries: keep the latent part,
     # apply the absorbed V up-projection per head.
     ctx = attn[..., :dc]                              # [..., H, dc]
     out = quantized_einsum("...hc,hcv->...hv", ctx,
                            lp["v_up"]["kernel"])
-    return out.reshape(*out.shape[:-2], H * dv), k_pages, v_pages
+    return out.reshape(*out.shape[:-2], H * dv), kv_pages
 
 
 def _dense_mlp(mp: Params, x: jax.Array) -> jax.Array:
@@ -303,18 +302,17 @@ def _dense_mlp(mp: Params, x: jax.Array) -> jax.Array:
 
 def _run_layers(params, cfg, x, kv_pages, mode, page_table, prefix_lens,
                 seq_lens, positions, context_lens):
-    """Unrolled layer loop with in-place KV writebacks (see
-    models/llama.py for why not `lax.scan`)."""
+    """Unrolled layer loop over the one donated pool, written in place and
+    read as `(pool, layer)` (see models/llama.py)."""
     use_mla = cfg.kv_lora_rank > 0
     Ld = cfg.first_dense_layers
     dense = kv_pages is None            # embeddings: no cache at all
     for l in range(cfg.num_layers):
         lp = jax.tree.map(lambda a, _l=l: a[_l], params["layers"])
         h = rms_norm(x, lp["input_norm"]["scale"], cfg.rms_eps)
-        k_pages, v_pages = (None, None) if dense else             (kv_pages[l, 0], kv_pages[l, 1])
         if use_mla:
-            attn, k_pages, v_pages = _mla_attention(
-                lp, cfg, h, "dense" if dense else mode, k_pages, v_pages,
+            attn, kv_pages = _mla_attention(
+                lp, cfg, h, "dense" if dense else mode, kv_pages, l,
                 page_table, prefix_lens, seq_lens, positions, context_lens)
         else:
             q, k, v = _project_qkv(lp, h, cfg, positions)
@@ -323,14 +321,13 @@ def _run_layers(params, cfg, x, kv_pages, mode, page_table, prefix_lens,
                     q, k, v, None, None, None,
                     jnp.zeros(x.shape[:1], jnp.int32), seq_lens)
             elif mode == "prefill":
-                k_pages, v_pages = write_prefill_kv(
-                    k_pages, v_pages, k, v, page_table, prefix_lens,
-                    seq_lens)
-                attn = prefill_attention(q, k, v, k_pages, v_pages,
+                kv_pages = write_kv(kv_pages, l, k, v, page_table,
+                                    prefix_lens, seq_lens)
+                attn = prefill_attention(q, k, v, kv_pages, l,
                                          page_table, prefix_lens, seq_lens)
             else:
-                attn, k_pages, v_pages = decode_attention_step(
-                    q, k, v, k_pages, v_pages, page_table, context_lens)
+                attn, kv_pages = decode_attention_step(
+                    q, k, v, kv_pages, l, page_table, context_lens)
             attn = attn.reshape(*attn.shape[:-2], cfg.q_size)
         x = x + quantized_einsum("...f,fd->...d", attn,
                                  lp["o_proj"]["kernel"])
@@ -343,9 +340,6 @@ def _run_layers(params, cfg, x, kv_pages, mode, page_table, prefix_lens,
             x = x + _moe_mlp(
                 jax.tree.map(lambda a, _l=l - Ld: a[_l], params["moe"]),
                 h2, cfg)
-        if not dense:
-            kv_pages = jax.lax.dynamic_update_index_in_dim(
-                kv_pages, jnp.stack([k_pages, v_pages]), l, 0)
     return x, kv_pages
 
 

@@ -16,10 +16,9 @@ import jax.numpy as jnp
 from ..ops.attention import (
     apply_rope,
     decode_attention_step,
-    paged_attention,
     prefill_attention,
     rms_norm,
-    write_prefill_kv,
+    write_kv,
 )
 from ..parallel.sharding import ShardingRules
 from jax.sharding import PartitionSpec as P
@@ -203,33 +202,29 @@ def prefill_from_embeddings(params: Params, cfg: ModelConfig,
     """Prefill body over precomputed input embeddings (multimodal families
     splice visual tokens before calling this).
 
-    Layers run as an unrolled Python loop with per-layer
-    `dynamic_update_index_in_dim` KV writebacks — with the KV pool donated,
-    XLA updates it in place. (A `lax.scan` whose ys re-stack the pool
-    copies the entire KV cache every call — measured ~2x decode cost.)
+    Layers run as an unrolled Python loop over the ONE donated pool: each
+    layer's K/V is written into it in place (`write_kv`) and read where it
+    lies (`(pool, layer)`); no layer is sliced out or stacked back. (A
+    `lax.scan` whose ys re-stack the pool copies the entire KV cache
+    every call, and so did a per-layer slice + `dynamic_update_index_in_dim`:
+    a third of a decode step on the chip, PERF.md §6 PR 25.)
 
     all_logits=True returns logits for EVERY position [B, S, V] (the
     speculative-decoding verify path needs per-position predictions);
     default returns only the last valid token's [B, V].
     """
 
-    def layer_body(l, x, k_pages, v_pages):
-        lp = jax.tree.map(lambda a: a[l], params["layers"])
+    for l in range(cfg.num_layers):
+        lp = jax.tree.map(lambda a, _l=l: a[_l], params["layers"])
         h = _norm(x, lp["input_norm"]["scale"], cfg)
         q, k, v = _project_qkv(lp, h, cfg, positions)
-        k_pages, v_pages = write_prefill_kv(k_pages, v_pages, k, v,
-                                            page_table, prefix_lens, seq_lens)
-        attn = prefill_attention(q, k, v, k_pages, v_pages,
+        kv_pages = write_kv(kv_pages, l, k, v, page_table, prefix_lens,
+                            seq_lens)
+        attn = prefill_attention(q, k, v, kv_pages, l,
                                  page_table, prefix_lens, seq_lens,
                                  **_attn_opts(cfg, l))
         attn = attn.reshape(*attn.shape[:-2], cfg.q_size)
         x = _attn_mlp_residual(lp, x, attn, cfg)
-        return x, k_pages, v_pages
-
-    for l in range(cfg.num_layers):
-        x, k_pages, v_pages = layer_body(l, x, kv_pages[l, 0], kv_pages[l, 1])
-        kv_pages = jax.lax.dynamic_update_index_in_dim(
-            kv_pages, jnp.stack([k_pages, v_pages]), l, 0)
     if all_logits:
         return _unembed(params, cfg, x), kv_pages
     # Last valid token's hidden state per row.
@@ -296,21 +291,9 @@ def decode_forward(params: Params, cfg: ModelConfig,
                    ) -> tuple[jax.Array, jax.Array]:
     """One decode step. Returns (logits [B, V], updated kv_pages).
 
-    Unrolled layer loop + in-place KV writebacks (see
-    prefill_from_embeddings for why not `lax.scan`). XLLM_KV_WRITEBACK
-    selects the write strategy — numerically identical (parity-tested),
-    perf A/B'd per backend:
-    - "" (default): per-layer slice/stack/update pattern (round-1
-      measured fastest on TPU among the XLA variants);
-    - "scatter": write the token's K/V directly into the full [L, 2, ...]
-      pool;
-    - "fused": single Pallas kernel doing append + paged attention
-      (ops/pallas_fused_decode_attention.py) — no separate scatter op,
-      the HBM append DMA overlaps the page walk."""
-    from ..ops.attention import kv_writeback_mode
-    wb = kv_writeback_mode()
-    scatter = wb == "scatter"
-    page_size = kv_pages.shape[4]
+    Unrolled layer loop over the one donated pool (see
+    prefill_from_embeddings): the token's K/V is appended in place and the
+    kernel reads `(pool, layer)`."""
     x = _embed(params, cfg, tokens)                            # [B, D]
     # M-RoPE (qwen2_vl): rope rotates by the multimodal position id
     # (sequence index + per-slot delta after image grids), while KV
@@ -322,32 +305,11 @@ def decode_forward(params: Params, cfg: ModelConfig,
         lp = jax.tree.map(lambda a, _l=l: a[_l], params["layers"])
         h = _norm(x, lp["input_norm"]["scale"], cfg)
         q, k, v = _project_qkv(lp, h, cfg, rope_positions)        # [B, H, hd]
-        if scatter:
-            page_idx = jnp.take_along_axis(
-                page_table, (positions // page_size)[:, None], axis=1)[:, 0]
-            slot = positions % page_size
-            kv_pages = kv_pages.at[l, 0, page_idx, :, slot, :].set(
-                k, mode="drop")
-            kv_pages = kv_pages.at[l, 1, page_idx, :, slot, :].set(
-                v, mode="drop")
-            k_pages, v_pages = kv_pages[l, 0], kv_pages[l, 1]
-            attn = paged_attention(q, k_pages, v_pages, page_table,
-                                   context_lens, **_attn_opts(cfg, l))
-        else:
-            attn, k_pages, v_pages = decode_attention_step(
-                q, k, v, kv_pages[l, 0], kv_pages[l, 1],
-                page_table, context_lens, **_attn_opts(cfg, l))
+        attn, kv_pages = decode_attention_step(
+            q, k, v, kv_pages, l, page_table, context_lens,
+            **_attn_opts(cfg, l))
         attn = attn.reshape(*attn.shape[:-2], cfg.q_size)
         x = _attn_mlp_residual(lp, x, attn, cfg)
-        if wb == "slice":
-            # Two static index updates: no [2, P, n_kv, ps, hd] stack
-            # temp (l is a Python int — XLA sees static update-slices on
-            # the donated pool).
-            kv_pages = kv_pages.at[l, 0].set(k_pages)
-            kv_pages = kv_pages.at[l, 1].set(v_pages)
-        elif not scatter:
-            kv_pages = jax.lax.dynamic_update_index_in_dim(
-                kv_pages, jnp.stack([k_pages, v_pages]), l, 0)
     return _unembed(params, cfg, x), kv_pages
 
 
@@ -391,20 +353,17 @@ def mixed_decode_chunk_forward(
         q, k, v = _project_qkv(lp, h, cfg, rope_pos)          # [B+c, H, hd]
         # Chunk KV lands in the pool FIRST (its own pages; decode rows
         # belong to different sequences, so order is immaterial there).
-        k_pages, v_pages = write_prefill_kv(
-            kv_pages[l, 0], kv_pages[l, 1], k[None, B:], v[None, B:],
-            chunk_pt, chunk_prefix, chunk_lens)
-        attn_d, k_pages, v_pages = decode_attention_step(
-            q[:B], k[:B], v[:B], k_pages, v_pages, dec_pt, dec_clens,
+        kv_pages = write_kv(kv_pages, l, k[None, B:], v[None, B:],
+                            chunk_pt, chunk_prefix, chunk_lens)
+        attn_d, kv_pages = decode_attention_step(
+            q[:B], k[:B], v[:B], kv_pages, l, dec_pt, dec_clens,
             **_attn_opts(cfg, l))
         attn_c = prefill_attention(
-            q[None, B:], k[None, B:], v[None, B:], k_pages, v_pages,
+            q[None, B:], k[None, B:], v[None, B:], kv_pages, l,
             chunk_pt, chunk_prefix, chunk_lens, **_attn_opts(cfg, l))
         attn = jnp.concatenate([attn_d, attn_c[0]])
         attn = attn.reshape(B + c, cfg.q_size)
         x = _attn_mlp_residual(lp, x, attn, cfg)
-        kv_pages = kv_pages.at[l, 0].set(k_pages)
-        kv_pages = kv_pages.at[l, 1].set(v_pages)
     return _unembed(params, cfg, x[:B]), kv_pages
 
 

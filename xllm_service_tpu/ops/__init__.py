@@ -11,12 +11,11 @@ from .attention import (
     apply_rope,
     prefill_attention,
     paged_attention_xla,
-    write_prefill_kv,
-    write_decode_kv,
+    write_kv,
     decode_attention_step,
 )
 
 __all__ = [
     "rms_norm", "apply_rope", "prefill_attention", "paged_attention_xla",
-    "write_prefill_kv", "write_decode_kv", "decode_attention_step",
+    "write_kv", "decode_attention_step",
 ]

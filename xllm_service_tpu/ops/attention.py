@@ -1,10 +1,16 @@
 """Attention primitives for the paged-KV engine.
 
 Layouts:
-- KV pool (per layer): ``k_pages/v_pages: [num_pages, n_kv, page_size, hd]``
-  (stacked over layers by the engine: leading ``L`` dim). The
-  (page_size, head_dim) minor dims match the bf16 (16, 128) TPU tile so the
-  Pallas decode kernel reads whole pages as aligned blocks.
+- KV pool: ``pool: [L, 2, num_pages, n_kv, page_size, hd]`` (K at index 0
+  of the second dim, V at 1): ONE donated buffer. Writers scatter into it
+  in place and readers take ``(pool, layer)``; no forward slices a layer
+  out of it into a temporary or stacks one back (a Pallas operand needs a
+  buffer of its own, so a sliced layer is a copy of that layer — once per
+  layer that is the whole pool, every step). ``k_pages/v_pages:
+  [num_pages, n_kv, page_size, hd]`` name one layer's views where an XLA
+  gather reads them. The (page_size, head_dim) minor dims match the bf16
+  (16, 128) TPU tile so the Pallas decode kernel reads whole pages as
+  aligned blocks.
 - ``page_tables: [B, max_pages]`` int32 — page ids per sequence, in order.
 - ``context_lens: [B]`` int32 — tokens currently in cache per sequence.
 
@@ -206,71 +212,124 @@ def _repeat_kv(kv: jax.Array, n_rep: int) -> jax.Array:
     return jnp.repeat(kv, n_rep, axis=-2)
 
 
-# --------------------------------------------------------------- KV writes
-def write_prefill_kv(k_pages: jax.Array, v_pages: jax.Array,
-                     k: jax.Array, v: jax.Array,
-                     page_table: jax.Array, prefix_lens: jax.Array,
-                     seq_lens: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Scatter a prefill suffix's K/V into the paged pool.
+# ------------------------------------------------------ pool reads / writes
+# One gather and one scatter per layer move K and V pages together: their
+# rows are pool coordinates (layer, k|v, page), their window one page
+# [n_kv, page_size, hd], the pool's contiguous trailing block. `lax`
+# directly: a forward traces these once per layer, and jnp's index
+# normalisation costs more to trace than the rest of `write_kv`.
+_PAGE_GATHER = jax.lax.GatherDimensionNumbers(
+    offset_dims=(1, 2, 3), collapsed_slice_dims=(0, 1, 2),
+    start_index_map=(0, 1, 2))
+_PAGE_SCATTER = jax.lax.ScatterDimensionNumbers(
+    update_window_dims=(1, 2, 3), inserted_window_dims=(0, 1, 2),
+    scatter_dims_to_operand_dims=(0, 1, 2))
+
+
+def _page_rows(layer: int, page_ids: jax.Array) -> jax.Array:
+    """[n] page ids -> [2n, 3] pool coordinates, K rows then V rows."""
+    n = page_ids.shape[0]
+    side = jnp.repeat(jnp.arange(2, dtype=jnp.int32), n)
+    return jnp.stack([jnp.full((2 * n,), layer, jnp.int32), side,
+                      jnp.tile(page_ids.astype(jnp.int32), 2)], axis=1)
+
+
+def _read_pages(pool: jax.Array, layer: int,
+                page_ids: jax.Array) -> jax.Array:
+    """K and V pages of `layer`: [n] ids -> [2, n, n_kv, ps, hd], in ONE
+    gather on the pool (`pool[layer, side][ids]` would slice the layer
+    into a temporary first)."""
+    pages = jax.lax.gather(pool, _page_rows(layer, page_ids), _PAGE_GATHER,
+                           (1, 1, 1) + pool.shape[3:], mode="clip")
+    return pages.reshape(2, -1, *pool.shape[3:])
+
+
+def write_kv(pool: jax.Array, layer: int, k: jax.Array, v: jax.Array,
+             page_table: jax.Array, start: jax.Array,
+             lens: jax.Array) -> jax.Array:
+    """Write a run of tokens' K/V per sequence into layer `layer` of the
+    pool, in place (the pool is donated through every program): the one
+    writer of prefill suffixes, verify blocks, prefill chunks and the
+    decode token (S = 1).
 
     k/v: [B, S, n_kv, hd] — token j of row b lands at absolute position
-    prefix_lens[b] + j (prefix blocks already cached are skipped). Padding
-    positions (j >= seq_lens[b]) are redirected to the reserved garbage
-    page 0 so bucket padding never overwrites live cache lines.
+    start[b] + j for j < lens[b]; the rest is bucket padding and is not
+    written.
+
+    Whole pages move, not token rows: the run's pages are gathered, the new
+    tokens spliced in and the pages scattered back. A page is the pool's
+    contiguous [n_kv, page_size, hd] block, so the scatter's window is the
+    trailing dims and it updates the pool in its own layout; a scatter of
+    [n_kv, hd] token rows at (page, :, slot, :) makes the TPU compiler
+    rewrite the whole pool into a slot-major layout and back around it.
+    Safe because a partially filled page is private to its sequence
+    (`KVPageManager` donates whole hash blocks of whole pages only) and
+    the cells outside the run are written back as read. Pages with
+    nothing to write (padding, rows past their table) get an index past
+    the pool, which the scatter drops.
     """
-    B, S = k.shape[0], k.shape[1]
-    page_size = k_pages.shape[2]
-    pos = prefix_lens[:, None] + jnp.arange(S)[None, :]          # [B, S]
-    valid = jnp.arange(S)[None, :] < seq_lens[:, None]
+    B, S, n_kv, hd = k.shape
+    ps = pool.shape[4]
     max_pages = page_table.shape[1]
-    page_idx = jnp.take_along_axis(
-        page_table, jnp.clip(pos // page_size, 0, max_pages - 1), axis=1)
-    page_idx = jnp.where(valid, page_idx, 0)
-    slot = pos % page_size
-    p_flat = page_idx.reshape(-1)
-    s_flat = slot.reshape(-1)
-    # [N, n_kv, hd] scattered at (page, :, slot, :).
-    k_pages = k_pages.at[p_flat, :, s_flat, :].set(
-        k.reshape(B * S, *k.shape[2:]), mode="drop")
-    v_pages = v_pages.at[p_flat, :, s_flat, :].set(
-        v.reshape(B * S, *v.shape[2:]), mode="drop")
-    return k_pages, v_pages
+    n_pg = (S + ps - 2) // ps + 1       # pages S consecutive tokens touch
+    first = start // ps
+    off = start - first * ps                                  # [B], < ps
+    slot_ids = first[:, None] + jnp.arange(n_pg)[None, :]     # [B, n_pg]
+    # Token index of every (page, slot) cell of the run's pages.
+    t = jnp.arange(n_pg * ps)[None, :] - off[:, None]         # [B, n_pg*ps]
+    live = ((t >= 0) & (t < lens[:, None])).reshape(B, n_pg, ps)
+    page_ids = jnp.take_along_axis(
+        page_table, jnp.clip(slot_ids, 0, max_pages - 1), axis=1)
+    in_table = (slot_ids >= 0) & (slot_ids < max_pages)
+    page_ids = jnp.where(live.any(-1) & in_table, page_ids,
+                         pool.shape[2]).reshape(-1)
 
-
-def write_decode_kv(k_pages: jax.Array, v_pages: jax.Array,
-                    k: jax.Array, v: jax.Array,
-                    page_table: jax.Array, context_lens: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Append one token's K/V per sequence. k/v: [B, n_kv, hd]; the new token
-    occupies position context_lens[b]."""
-    page_size = k_pages.shape[2]
-    B = k.shape[0]
-    page_idx = jnp.take_along_axis(
-        page_table, (context_lens // page_size)[:, None], axis=1)[:, 0]
-    slot = context_lens % page_size
-    k_pages = k_pages.at[page_idx, :, slot, :].set(k, mode="drop")
-    v_pages = v_pages.at[page_idx, :, slot, :].set(v, mode="drop")
-    return k_pages, v_pages
+    kv = jnp.stack([k, v]).astype(pool.dtype)             # [2, B, S, n_kv, hd]
+    if S == 1:
+        cells = kv[:, :, :, :, None, :]         # the select keeps one slot
+    else:
+        # Token j of row b at cell off[b] + j of the row's pages.
+        kv = jnp.pad(kv, ((0, 0), (0, 0), (ps, n_pg * ps - S), (0, 0),
+                          (0, 0)))
+        kv = jax.vmap(lambda x, o: jax.lax.dynamic_slice_in_dim(
+            x, ps - o, n_pg * ps, axis=1), in_axes=(1, 0), out_axes=1)(
+                kv, off)
+        cells = kv.reshape(2, B, n_pg, ps, n_kv, hd).transpose(
+            0, 1, 2, 4, 3, 5)
+    old = _read_pages(pool, layer, page_ids).reshape(
+        2, B, n_pg, n_kv, ps, hd)
+    new = jnp.where(live[None, :, :, None, :, None], cells, old)
+    return jax.lax.scatter(pool, _page_rows(layer, page_ids),
+                           new.reshape(-1, n_kv, ps, hd), _PAGE_SCATTER,
+                           mode="drop")
 
 
 # ----------------------------------------------------------- prefill attn
-def gather_pages(pages: jax.Array, page_table: jax.Array) -> jax.Array:
-    """[num_pages, n_kv, ps, hd] x [B, max_pages] -> [B, max_pages*ps, n_kv, hd]."""
-    g = pages[page_table]                     # [B, max_pages, n_kv, ps, hd]
-    B, mp, n_kv, ps, hd = g.shape
-    return g.transpose(0, 1, 3, 2, 4).reshape(B, mp * ps, n_kv, hd)
+def gather_pages(pool: jax.Array, layer: int,
+                 page_table: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """K and V of `layer` under a page table: [L, 2, num_pages, n_kv, ps,
+    hd] x [B, max_pages] -> two [B, max_pages*ps, n_kv, hd]."""
+    B, mp = page_table.shape
+    n_kv, ps, hd = pool.shape[3:]
+    g = _read_pages(pool, layer, page_table.reshape(-1))
+    g = g.reshape(2, B, mp, n_kv, ps, hd).transpose(0, 1, 2, 4, 3, 5)
+    g = g.reshape(2, B, mp * ps, n_kv, hd)
+    return g[0], g[1]
 
 
 def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                      k_pages: jax.Array, v_pages: jax.Array,
-                      page_table: jax.Array,
+                      pool: jax.Array | None, layer: int | None,
+                      page_table: jax.Array | None,
                       prefix_lens: jax.Array, seq_lens: jax.Array,
                       scale: float | None = None,
                       softcap: float = 0.0, window: int = 0) -> jax.Array:
     """Causal attention for a (possibly prefix-cached) prefill chunk.
 
     q/k/v: [B, S, n(_kv), hd] for the *suffix* being prefilled; queries also
-    attend to the cached prefix (first prefix_lens[b] tokens) read from the
-    paged pool. seq_lens[b] = valid suffix length (padding masked out).
+    attend to the cached prefix (first prefix_lens[b] tokens) read from
+    layer `layer` of the paged pool (`pool` None: no cache at all, the
+    embeddings path). seq_lens[b] = valid suffix length (padding masked
+    out).
     Returns [B, S, n_heads, hd].
 
     softcap > 0 tanh-caps the attention scores; window > 0 restricts each
@@ -291,11 +350,11 @@ def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     #   gathers every row's full page span dense — [B, H, S, prefix+S]
     #   scores in HBM, which at long contexts dwarfs the chunk itself.
     # Both share the kernel's invariant (block KV already written to the
-    # pages — write_prefill_kv runs first in prefill_from_embeddings) and
+    # pages — write_kv runs first in prefill_from_embeddings) and
     # both are excluded under the ring-attention (sp) trace context. The
     # rows cap keeps the kernel's [S*n_heads, hd] f32 accumulator and
     # m/l scratch inside VMEM; bigger chunks fall back to XLA.
-    if k_pages is not None and scale is None \
+    if pool is not None and scale is None \
             and softcap == 0.0 and window == 0 \
             and getattr(_sp_ctx, "cfg", None) is None:
         import os
@@ -308,11 +367,13 @@ def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                  and os.environ.get("XLLM_PREFILL_PALLAS", "") == "1"
                  and S * n_heads <= 4096)
         if (mq_on or pf_on) and _tp_mesh()[0] is None \
-                and _mosaic_kernel_ok(q, k_pages):
+                and _mosaic_kernel_ok(q, n_kv):
             from .pallas_mq_paged_attention import mq_paged_attention_pallas
 
+            # Opt-in kernel on one layer's views (a copy of the layer).
             note_path("prefill_attention", "pallas-mq")
-            return mq_paged_attention_pallas(q, k_pages, v_pages,
+            return mq_paged_attention_pallas(q, pool[layer, 0],
+                                             pool[layer, 1],
                                              page_table, prefix_lens,
                                              seq_lens,
                                              interpret=_pallas_interpret())
@@ -362,14 +423,12 @@ def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         probs = jax.nn.softmax(ss, axis=-1)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, vf)
 
-    if k_pages is None:
+    if pool is None:
         return _suffix_only(None).astype(q.dtype)
 
     def _attend_prefix(pt_prefix):
-        pk = _repeat_kv(gather_pages(k_pages, pt_prefix),
-                        n_rep).astype(jnp.float32)
-        pv = _repeat_kv(gather_pages(v_pages, pt_prefix),
-                        n_rep).astype(jnp.float32)
+        pk, pv = (_repeat_kv(g, n_rep).astype(jnp.float32)
+                  for g in gather_pages(pool, layer, pt_prefix))
         T = pk.shape[1]
         ps_scores = cap(jnp.einsum("bqhd,bkhd->bhqk", qf, pk))
         pmask = (jnp.arange(T)[None, :] < prefix_lens[:, None])  # [B, T]
@@ -394,7 +453,7 @@ def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         # so a chunked long prefill stops re-gathering its table's FULL
         # span on every chunk. Accelerator-gated like the decode ladder —
         # each span is a compiled variant, noise the CPU suite can't pay.
-        page_size = k_pages.shape[2]
+        page_size = pool.shape[4]
         max_pages = page_table.shape[1]
         spans = []
         if _span_buckets_on():
@@ -421,34 +480,6 @@ def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.astype(q.dtype)
 
 
-_warned_writeback_modes: set[str] = set()
-
-
-def kv_writeback_mode() -> str:
-    """The single reader for the XLLM_KV_WRITEBACK decode A/B switch.
-
-    Valid values: "" (per-layer slice/stack/update), "slice" (two static
-    .at[l, 0/1].set updates — skips materializing the [2, P, n_kv, ps,
-    hd] stack temp), "scatter" (direct write into the full stacked pool —
-    handled at the model layer, which owns the [L, 2, ...] array),
-    "fused" (single Pallas append+attend kernel,
-    `decode_attention_step`). An unrecognized value falls back to the
-    default with a one-time warning instead of silently acting like an
-    unset flag."""
-    import logging
-    import os
-
-    mode = os.environ.get("XLLM_KV_WRITEBACK", "")
-    if mode not in ("", "slice", "scatter", "fused"):
-        if mode not in _warned_writeback_modes:
-            _warned_writeback_modes.add(mode)
-            logging.getLogger(__name__).warning(
-                "XLLM_KV_WRITEBACK=%r is not one of '', 'slice', "
-                "'scatter', 'fused'; using the default writeback", mode)
-        return ""
-    return mode
-
-
 def _pallas_interpret() -> bool:
     """XLLM_PALLAS_INTERPRET=1 runs the Pallas kernels in interpret mode
     and lets the dispatch gates treat the CPU backend as kernel-capable —
@@ -459,7 +490,7 @@ def _pallas_interpret() -> bool:
     return os.environ.get("XLLM_PALLAS_INTERPRET", "") == "1"
 
 
-def _mosaic_kernel_ok(q: jax.Array, k_pages: jax.Array) -> bool:
+def _mosaic_kernel_ok(q: jax.Array, n_kv: int) -> bool:
     """Shared eligibility gate for the hand-written attention kernels:
     Mosaic tiling needs the head dim to be a lane-width multiple and GQA
     an integer group size; the kill switch and CPU backend exclude all
@@ -467,7 +498,6 @@ def _mosaic_kernel_ok(q: jax.Array, k_pages: jax.Array) -> bool:
     import os
 
     n_heads, hd = q.shape[-2], q.shape[-1]
-    n_kv = k_pages.shape[1]
     return (hd % 128 == 0 and n_heads % n_kv == 0
             and q.dtype in (jnp.bfloat16, jnp.float32)
             and (_backend() != "cpu" or _pallas_interpret())
@@ -476,45 +506,25 @@ def _mosaic_kernel_ok(q: jax.Array, k_pages: jax.Array) -> bool:
 
 
 def decode_attention_step(q: jax.Array, k: jax.Array, v: jax.Array,
-                          k_pages: jax.Array, v_pages: jax.Array,
+                          pool: jax.Array, layer: int,
                           page_table: jax.Array, context_lens: jax.Array,
                           scale: float | None = None,
                           softcap: float = 0.0, window: int = 0,
-                          ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Append one token's K/V and attend, as one step.
+                          ) -> tuple[jax.Array, jax.Array]:
+    """Append one token's K/V to layer `layer` of the pool and attend, as
+    one step: the one decode append+attend path of every family.
 
     q: [B, n_heads, hd]; k/v: [B, n_kv, hd] — the new token, written at
     position ``context_lens[b] - 1`` (context_lens INCLUDE it, matching
     the engine decode path's ``positions = clens - 1``); attention covers
     positions < ``context_lens[b]``. Returns (attn [B, n_heads, hd],
-    k_pages, v_pages).
-
-    Under ``XLLM_KV_WRITEBACK=fused`` on an accelerator this routes
-    through the single fused Pallas kernel (one HBM append DMA overlapped
-    with the page walk, no separate scatter); otherwise scatter-then-
-    attend with identical numerics (parity-tested). The CP-decode context
-    keeps the unfused path — the pool is sharded there and the write must
-    land on the owning shard via the XLA scatter.
+    pool).
     """
-    if (kv_writeback_mode() == "fused"
-            and softcap == 0.0 and window == 0 and scale is None
-            and getattr(_cp_ctx, "cfg", None) is None
-            and _tp_mesh()[0] is None
-            and _mosaic_kernel_ok(q, k_pages)):
-        note_path("paged_attention", "pallas-fused")
-        from .pallas_fused_decode_attention import (
-            fused_decode_attention_pallas,
-        )
-
-        return fused_decode_attention_pallas(
-            q, k, v, k_pages, v_pages, page_table, context_lens,
-            interpret=_pallas_interpret())
-    positions = context_lens - 1
-    k_pages, v_pages = write_decode_kv(k_pages, v_pages, k, v,
-                                       page_table, positions)
-    attn = paged_attention(q, k_pages, v_pages, page_table, context_lens,
+    pool = write_kv(pool, layer, k[:, None], v[:, None], page_table,
+                    context_lens - 1, jnp.ones_like(context_lens))
+    attn = paged_attention(q, pool, layer, page_table, context_lens,
                            scale=scale, softcap=softcap, window=window)
-    return attn, k_pages, v_pages
+    return attn, pool
 
 
 # ------------------------------------------------------------ decode attn
@@ -531,14 +541,15 @@ def _span_buckets_on() -> bool:
     return _backend() != "cpu"
 
 
-def paged_attention_xla(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
+def paged_attention_xla(q: jax.Array, pool: jax.Array, layer: int,
                         page_table: jax.Array,
                         context_lens: jax.Array,
                         scale: float | None = None,
                         softcap: float = 0.0, window: int = 0) -> jax.Array:
     """One-token-per-sequence paged attention (XLA path).
 
-    q: [B, n_heads, hd]; returns [B, n_heads, hd]. Assumes the new token's
+    q: [B, n_heads, hd]; pool: [L, 2, num_pages, n_kv, ps, hd], read at
+    `layer`; returns [B, n_heads, hd]. Assumes the new token's
     K/V are already written (attends to positions < context_lens[b] + 1 ...
     callers pass context_lens *including* the new token). softcap/window:
     gemma-2 score capping and sliding-window (the query sits at position
@@ -552,16 +563,16 @@ def paged_attention_xla(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     the table is sized for contexts far beyond current occupancy.
     """
     B, n_heads, hd = q.shape
-    n_kv = k_pages.shape[1]
+    n_kv = pool.shape[3]
     n_rep = n_heads // n_kv
-    page_size = k_pages.shape[2]
+    page_size = pool.shape[4]
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
     qf = q.astype(jnp.float32) * scale
 
     def attend(pt_prefix):
-        k = _repeat_kv(gather_pages(k_pages, pt_prefix), n_rep)
-        v = _repeat_kv(gather_pages(v_pages, pt_prefix), n_rep)
+        k, v = (_repeat_kv(g, n_rep)
+                for g in gather_pages(pool, layer, pt_prefix))
         T = k.shape[1]
         scores = jnp.einsum("bhd,bkhd->bhk", qf, k.astype(jnp.float32))
         if softcap > 0:
@@ -595,20 +606,23 @@ def paged_attention_xla(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     return jax.lax.switch(idx, branches, operand=None)
 
 
-def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
+def paged_attention(q: jax.Array, pool: jax.Array, layer: int,
                     page_table: jax.Array,
                     context_lens: jax.Array,
                     scale: float | None = None,
                     softcap: float = 0.0, window: int = 0) -> jax.Array:
-    """Backend dispatcher: context-parallel op when the engine traced
-    under `decode_context_parallel` (pool sharded over the seq axis),
-    hand-written Pallas kernel on TPU, XLA gather fallback elsewhere (CPU
-    test meshes) and for shapes outside the kernel's tiling constraints.
-    Selection happens at trace time — all paths are numerically
-    equivalent (tested). softcap/window (gemma-2) ride the Pallas
-    kernel as static params when the shape qualifies, falling back to
-    XLA otherwise; CP meshes refuse such models (the partial-stats
-    merge has no softcap/window support)."""
+    """Backend dispatcher over layer `layer` of the pool
+    [L, 2, num_pages, n_kv, ps, hd]: context-parallel op when the engine
+    traced under `decode_context_parallel` (pool sharded over the seq
+    axis), hand-written Pallas kernel on TPU, XLA gather fallback
+    elsewhere (CPU test meshes) and for shapes outside the kernel's tiling
+    constraints. Selection happens at trace time — all paths are
+    numerically equivalent (tested). The kernel and the gather both read
+    the pool where it lies; only the CP op still takes one layer's views.
+    softcap/window (gemma-2) ride the Pallas kernel as static params
+    when the shape qualifies, falling back to XLA otherwise; CP meshes
+    refuse such models (the partial-stats merge has no softcap/window
+    support)."""
     cp = getattr(_cp_ctx, "cfg", None)
     if cp is not None:
         if softcap != 0.0 or window != 0:
@@ -618,13 +632,13 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         from .cp_paged_attention import cp_paged_attention
 
         mesh, seq_axis = cp
-        return cp_paged_attention(q, k_pages, v_pages, page_table,
-                                  context_lens, mesh, seq_axis=seq_axis,
-                                  scale=scale)
+        return cp_paged_attention(q, pool[layer, 0], pool[layer, 1],
+                                  page_table, context_lens, mesh,
+                                  seq_axis=seq_axis, scale=scale)
 
     mesh, tp = _tp_mesh()
-    n_heads, n_kv = q.shape[-2], k_pages.shape[1]
-    if not _mosaic_kernel_ok(q, k_pages):
+    n_heads, n_kv = q.shape[-2], pool.shape[3]
+    if not _mosaic_kernel_ok(q, n_kv):
         import os
 
         why = ("XLLM_DISABLE_PALLAS_ATTENTION"
@@ -647,22 +661,23 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                                    interpret=_pallas_interpret(),
                                    scale=scale, softcap=softcap,
                                    window=window)
+        layer_id = jnp.full((1,), layer, jnp.int32)
         if mesh is None:
             note_path("paged_attention", "pallas")
-            return kernel(q, k_pages, v_pages, page_table, context_lens)
+            return kernel(q, pool, layer_id, page_table, context_lens)
         # Tensor parallel: GSPMD cannot partition a Mosaic kernel, so
         # each device runs it on its own heads — q and the pool are
-        # head-sharded over `model` (KV_PAGES_SPEC), the page table and
-        # lengths replicated. GQA groups stay whole because both head
-        # counts divide by tp. pallas_call outputs carry no
-        # varying-axes metadata, hence check_vma=False.
+        # head-sharded over `model` (KV_PAGES_SPEC), the layer id, the
+        # page table and the lengths replicated. GQA groups stay whole
+        # because both head counts divide by tp. pallas_call outputs
+        # carry no varying-axes metadata, hence check_vma=False.
         note_path("paged_attention", f"pallas (shard_map model={tp})")
         heads = P(None, AXIS_MODEL, None)
-        pool = P(None, AXIS_MODEL, None, None)
+        pool_spec = P(None, None, None, AXIS_MODEL, None, None)
         return jax.shard_map(
             kernel, mesh=mesh,
-            in_specs=(heads, pool, pool, P(), P()),
+            in_specs=(heads, pool_spec, P(), P(), P()),
             out_specs=heads, check_vma=False,
-        )(q, k_pages, v_pages, page_table, context_lens)
-    return paged_attention_xla(q, k_pages, v_pages, page_table, context_lens,
+        )(q, pool, layer_id, page_table, context_lens)
+    return paged_attention_xla(q, pool, layer, page_table, context_lens,
                                scale=scale, softcap=softcap, window=window)

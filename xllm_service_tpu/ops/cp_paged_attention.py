@@ -305,7 +305,7 @@ def cp_paged_attention(q: jax.Array, k_pages: jax.Array,
     single-device paged attention (parity-tested)."""
     from .attention import _mosaic_kernel_ok, _pallas_interpret, note_path
 
-    kernel_ok = _mosaic_kernel_ok(q, k_pages)
+    kernel_ok = _mosaic_kernel_ok(q, k_pages.shape[1])
     note_path("paged_attention",
               f"cp-pallas ({seq_axis})" if kernel_ok
               else f"cp-xla-dense ({seq_axis})")

@@ -1,5 +1,5 @@
 """Shared scaffolding for the paged-attention Pallas kernels (decode,
-fused decode-append, multi-query verify):
+multi-query verify, context-parallel partial):
 
 - `make_chunk_dma`: a 2-slot VMEM ring of `chunk`-page blocks, one async
   copy per page (pages are non-contiguous in HBM), waits batched per

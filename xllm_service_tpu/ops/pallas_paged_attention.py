@@ -5,16 +5,22 @@ kernel quality drives the tok/s/chip north star"). One query token per
 sequence attends over that sequence's KV pages, located via its page table.
 
 Design (v2 — manual double-buffered DMA):
-- grid = (batch,). K/V pools stay in HBM (`memory_space=ANY`); the kernel
+- grid = (batch,). The whole KV pool `[L, 2, pages, n_kv, ps, hd]` stays in
+  HBM (`memory_space=ANY`) as ONE operand and the layer to read is a
+  scalar-prefetch operand: DMA sources are `pool.at[layer, 0|1, page]`,
+  so no caller ever slices a layer out for the kernel (an operand needs a
+  buffer of its own — a sliced layer is a copy of it, per layer per step).
+  A scalar and not a static int: every layer's call is then the same
+  traced kernel. The kernel
   walks only the pages the sequence actually occupies (`cdiv(ctx, ps)` —
   a *dynamic* trip count, unlike a grid dimension) and DMAs each page into
   a 2-slot VMEM scratch ring, prefetching page i+1 while computing page i.
-- page table + context lengths are scalar-prefetch operands (SMEM) so DMA
-  source addresses are computable before compute starts.
+- layer id, page table + context lengths are scalar-prefetch operands
+  (SMEM) so DMA source addresses are computable before compute starts.
 - online-softmax accumulation (flash-style m/l/acc) in VMEM scratch; GQA
   via a static loop over KV heads with G query rows each.
-- KV page layout ``[num_pages, n_kv, page_size, head_dim]``: one page is a
-  contiguous (n_kv, ps, hd) block whose minor dims match the bf16
+- KV page layout ``[..., num_pages, n_kv, page_size, head_dim]``: one page
+  is a contiguous (n_kv, ps, hd) block whose minor dims match the bf16
   (16, 128) tile.
 
 vs the v1 grid-over-pages version: no DMA for garbage pages past the
@@ -40,9 +46,9 @@ from .pallas_page_dma import (
 )
 
 
-def _kernel(page_table_ref, context_lens_ref,   # scalar prefetch (SMEM)
+def _kernel(layer_ref, page_table_ref, context_lens_ref,   # SMEM prefetch
             q_ref,                              # VMEM block [1, n_q, hd]
-            k_hbm, v_hbm,                       # full pools in HBM/ANY
+            pool_hbm,                           # the whole pool in HBM/ANY
             o_ref,                              # VMEM block [1, n_q, hd]
             k_buf, v_buf, sems,                 # scratch: 2-slot chunk ring
             m_scr, l_scr, acc_scr,
@@ -52,6 +58,8 @@ def _kernel(page_table_ref, context_lens_ref,   # scalar prefetch (SMEM)
     b = pl.program_id(0)
     nb = pl.num_programs(0)
     ctx = context_lens_ref[b]
+    k_hbm = pool_hbm.at[layer_ref[0], 0]        # [pages, n_kv, ps, hd] views
+    v_hbm = pool_hbm.at[layer_ref[0], 1]
 
     def n_pages_of(row):
         return jnp.minimum(pl.cdiv(context_lens_ref[row], page_size),
@@ -103,16 +111,17 @@ def _kernel(page_table_ref, context_lens_ref,   # scalar prefetch (SMEM)
     o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
-                           v_pages: jax.Array, page_table: jax.Array,
+def paged_attention_pallas(q: jax.Array, pool: jax.Array,
+                           layer: jax.Array, page_table: jax.Array,
                            context_lens: jax.Array,
                            interpret: bool = False,
                            scale: float | None = None,
                            softcap: float = 0.0,
                            window: int = 0) -> jax.Array:
-    """q: [B, n_q, hd]; k/v_pages: [pages, n_kv, ps, hd];
-    page_table: [B, max_pages] i32; context_lens: [B] i32 (incl. the new
-    token, whose K/V must already be written). Returns [B, n_q, hd].
+    """q: [B, n_q, hd]; pool: [L, 2, pages, n_kv, ps, hd]; layer: [1] i32,
+    the layer of the pool to read; page_table: [B, max_pages] i32;
+    context_lens: [B] i32 (incl. the new token, whose K/V must already be
+    written). Returns [B, n_q, hd].
 
     scale/softcap/window cover the gemma-2 extras (explicit query scale,
     score soft-capping, sliding window) so that family decodes through
@@ -128,7 +137,7 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
     # Cross-row DMA pipelining (see _kernel): XLLM_PAGE_PIPELINE=row
     # enables; default off until the on-chip A/B proves it.
     pipeline_rows = os.environ.get("XLLM_PAGE_PIPELINE", "") == "row"
-    return _paged_attention_impl(q, k_pages, v_pages, page_table,
+    return _paged_attention_impl(q, pool, layer, page_table,
                                  context_lens, chunk=chunk,
                                  pipeline_rows=pipeline_rows,
                                  scale=(float(scale)
@@ -141,15 +150,15 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
 @functools.partial(jax.jit, static_argnames=("chunk", "pipeline_rows",
                                              "scale", "softcap", "window",
                                              "interpret"))
-def _paged_attention_impl(q: jax.Array, k_pages: jax.Array,
-                          v_pages: jax.Array, page_table: jax.Array,
+def _paged_attention_impl(q: jax.Array, pool: jax.Array,
+                          layer: jax.Array, page_table: jax.Array,
                           context_lens: jax.Array, *, chunk: int,
                           pipeline_rows: bool,
                           scale: float | None = None,
                           softcap: float = 0.0, window: int = 0,
                           interpret: bool = False) -> jax.Array:
     B, n_q, hd = q.shape
-    _, n_kv, page_size, _ = k_pages.shape
+    _, _, _, n_kv, page_size, _ = pool.shape
     max_pages = page_table.shape[1]
     group = n_q // n_kv
     if scale is None:
@@ -161,17 +170,17 @@ def _paged_attention_impl(q: jax.Array, k_pages: jax.Array,
                                pipeline_rows=pipeline_rows,
                                softcap=softcap, window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, n_q, hd), lambda b, pt, cl: (b, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),   # k pool stays in HBM
-            pl.BlockSpec(memory_space=pl.ANY),   # v pool stays in HBM
+            pl.BlockSpec((1, n_q, hd), lambda b, ly, pt, cl: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),   # the pool stays in HBM
         ],
-        out_specs=pl.BlockSpec((1, n_q, hd), lambda b, pt, cl: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, n_q, hd),
+                               lambda b, ly, pt, cl: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, chunk, n_kv, page_size, hd), k_pages.dtype),
-            pltpu.VMEM((2, chunk, n_kv, page_size, hd), v_pages.dtype),
+            pltpu.VMEM((2, chunk, n_kv, page_size, hd), pool.dtype),
+            pltpu.VMEM((2, chunk, n_kv, page_size, hd), pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((n_q, 128), jnp.float32),   # m
             pltpu.VMEM((n_q, 128), jnp.float32),   # l
@@ -185,4 +194,4 @@ def _paged_attention_impl(q: jax.Array, k_pages: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(page_table, context_lens, q, k_pages, v_pages)
+    )(layer, page_table, context_lens, q, pool)
