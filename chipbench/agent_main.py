@@ -15,9 +15,10 @@ one line `CHIPBENCH {json}` on stdout:
   {"cmd": "report"}                   device line, memory peak, counters
   {"cmd": "exit"}                     stop the agent, leave
 
-Nothing here changes what the engine computes: weights come from
-weights.py (seeded, made on the device in one call, in the served type) and
-go in through `EngineAgent(params=...)`, the door a checkpoint loader uses.
+Nothing here changes what the engine computes: weights come from the
+configuration's family (harness.Family: seeded, made on the device in one
+call, in the served type) and go in through `EngineAgent(params=...)`, the
+door a checkpoint loader uses.
 """
 
 from __future__ import annotations
@@ -50,6 +51,8 @@ def main() -> int:
     ap.add_argument("--tokenizer-path", required=True)
     ap.add_argument("--platform", required=True,
                     help="what JAX must hold; anything else is a failure")
+    ap.add_argument("--search", action="append", required=True,
+                    help="BENCHMARK.json's paths: where a family is found")
     args = ap.parse_args()
 
     import jax
@@ -91,12 +94,14 @@ def main() -> int:
     from xllm_service_tpu.engine.agent import AgentConfig, EngineAgent
     from xllm_service_tpu.utils import enable_persistent_compile_cache
 
-    from chipbench import weights
+    from chipbench import harness
     from chipbench.engine_setup import build_engine_config
 
     cache_dir = enable_persistent_compile_cache()
     config_dir = Path(args.config_dir)
     hf = json.loads((config_dir / "config.json").read_text())
+    family = harness.family_of(args.search, hf)
+    weights = family.weights
     ecfg, eng = build_engine_config(config_dir, args.seed, args.model_id)
     if eng["replicas"] > len(devs) or eng["tp"] > len(devs):
         say(event="fatal", error=f"engine.json asks for tp {eng['tp']} x "
@@ -130,7 +135,9 @@ def main() -> int:
     del params
     agent.start()
     say(event="started", weights_s=t_weights,
-        engine_s=time.monotonic() - t0, compile_cache=cache_dir)
+        engine_s=time.monotonic() - t0, compile_cache=cache_dir,
+        family=family.name or "default", weights_file=weights.__file__,
+        engine_config=eng.get("engine_config", {}))
 
     def snapshot() -> dict:
         with lock:

@@ -1,9 +1,12 @@
 """Bytes a dense decoder must read from HBM for one decode step, from its
-config.json alone. The benchmark's own copy of the dense arithmetic of
+config.json alone: the default family's `bytes` (harness.Family). The
+benchmark's own copy of the dense arithmetic of
 `ModelConfig.decode_weight_stream_bytes` (the original is wrong for sparse
 experts at batch > 1 and is listed in PERF.md for deletion)."""
 
 from __future__ import annotations
+
+ITEMSIZE = {"bfloat16": 2, "float16": 2, "float32": 4}
 
 
 def decode_weight_stream_bytes(hf: dict, served: str) -> int:
@@ -34,3 +37,15 @@ def decode_weight_stream_bytes(hf: dict, served: str) -> int:
     # A tied head is the bfloat16 embedding matrix itself.
     head = kernel(D, V, quantised=not tied)
     return L * layer + D * 2 + head
+
+
+def kv_bytes_per_token(hf: dict) -> int | None:
+    """Bytes of keys and values one token of context holds in the pool,
+    which has the model's dtype: 2 x layers x kv heads x head dim x
+    itemsize. None for a pool type whose size is not known here."""
+    itemsize = ITEMSIZE.get(hf.get("torch_dtype"))
+    if itemsize is None:
+        return None
+    hd = hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"]
+    return (2 * hf["num_hidden_layers"] * hf["num_key_value_heads"] * hd
+            * itemsize)

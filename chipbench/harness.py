@@ -66,6 +66,8 @@ class Cell:
     limits: dict
     check_requests: int
     check_logprobs: int
+    family: "Family"
+    decode_paths: dict     # op of `decode_multi` -> the prefix its path must have
 
 
 def _find(search: list, *parts: str) -> Path:
@@ -113,7 +115,8 @@ def resolve_cell(bench: dict, search: list, name: str) -> Cell:
     return Cell(name, wl["chips"], config_file.parent, hf, engine, mix,
                 float(cf["rate_per_s"]), cf["limits"],
                 int(cf.get("check_requests", 6)),
-                int(cf.get("check_logprobs", 0)))
+                int(cf.get("check_logprobs", 0)),
+                family_of(search, hf), decode_paths_of(hf, config_file))
 
 
 def prepare(bench_file: str, workload: str, tag: str = ""):
@@ -148,13 +151,79 @@ def metrics_for(bench: dict, kind: str, cell: str) -> list[dict]:
         kind == "end_to_end" or "workloads" in m or m["moves"] in judged)]
 
 
+def load_file(path: Path):
+    """The module in the file `path`, which is on no import path (a reader,
+    a part of a family): run once a process and kept, so that everything
+    that asks for a family's weights gets the same module."""
+    path = Path(path).resolve()
+    name = "chipbench_file_" + "".join(
+        c if c.isalnum() else "_" for c in str(path.with_suffix("")))
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
+
+
 def load_reader(search: list, metric: str):
-    path = _find(search, "layers", metric + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_layer_" + metric.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_file(_find(search, "layers", metric + ".py")).read
+
+
+# ---------------------------------------------------------------- families
+DECODE_PATHS = {"paged_attention": "pallas"}   # where config.json names none
+FAMILY_PARTS = {"weights": "weights", "reference": "reference",
+                "bytes": "bytes_model"}       # part -> the default's module
+
+
+class Family:
+    """Everything of the harness that depends on a model's shape: its
+    seeded `weights`, its plain `reference` and its `bytes` counts (the
+    contract: README, "A family"). A configuration names its family in
+    config.json's `chipbench` group; `families/<name>/<part>.py` is then
+    found under BENCHMARK.json's `paths` as readers, mixes and cells are.
+    Without a name it is the default: the dense-GQA llama tree of
+    weights.py, reference.py and bytes_model.py beside this file.
+
+    A part is loaded when first asked for: weights and reference import
+    JAX, which the launcher may not before its children are gone."""
+
+    def __init__(self, search: list, name: str = ""):
+        self.search, self.name = [Path(s) for s in search], name
+
+    def __getattr__(self, part: str):
+        if part not in FAMILY_PARTS:
+            raise AttributeError(part)
+        if self.name:
+            mod = load_file(_find(self.search, "families", self.name,
+                                  part + ".py"))
+        else:
+            mod = importlib.import_module("chipbench." + FAMILY_PARTS[part])
+        setattr(self, part, mod)
+        return mod
+
+
+def family_of(search: list, hf: dict) -> Family:
+    """The family that config.json `hf` names, or the default."""
+    return Family(search, (hf.get("chipbench") or {}).get("family", ""))
+
+
+def decode_paths_of(hf: dict, config_file: Path) -> dict:
+    """op of `decode_multi` -> the prefix its path must have, as config.json
+    `hf` states it, or the default. A configuration states which kernels
+    its decode program must run; it cannot state that none must: an empty
+    object, or a prefix that every path has, is refused."""
+    paths = (hf.get("chipbench") or {}).get("decode_paths", DECODE_PATHS)
+    if not (isinstance(paths, dict) and paths and all(
+            isinstance(v, str) and v for v in paths.values())):
+        raise Failure(f"{config_file}: chipbench.decode_paths {paths!r} "
+                      "must name at least one op of decode_multi, each "
+                      "with the non-empty prefix its path must have")
+    return dict(paths)
 
 
 # -------------------------------------------------------------- tokenizer
@@ -256,7 +325,9 @@ class Cluster:
                       "--port", str(self.agent_port),
                       "--model-id", MODEL_ID,
                       "--tokenizer-path", str(tok),
-                      "--platform", self.platform],
+                      "--platform", self.platform,
+                      *(a for s in self.cell.family.search
+                        for a in ("--search", str(s)))],
             stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)
         threading.Thread(target=self._read_agent, daemon=True).start()
 

@@ -5,9 +5,9 @@ The engine's pump thread wraps the phases of one loop iteration in
 decode_dispatch, fetch_wait, emit, idle: xllm_service_tpu/engine/
 telemetry.py), so a profiler session writes them into the trace's host
 plane, on the clock of the `/device:TPU:<n>` planes. This file reads them
-from the raw `.xplane.pb` (run.py hands the readers the device planes only,
-so `find_trace` opens the file run.py read them from a second time) and
-lays them over the device's idle time:
+from the raw `.xplane.pb` (run.py hands them to the readers as
+`ctx["host_spans"]`, beside the device planes in `ctx["trace"]`) and lays
+them over the device's idle time:
 
   idle_by_span()  every gap between consecutive `XLA Modules` events,
                   charged to the engine phase that covers most of it
@@ -29,19 +29,6 @@ from chipbench import xplane
 
 HOST_PLANE = re.compile(r"^/host:CPU$")
 PREFIX = "engine."
-ROOT = Path(__file__).resolve().parent.parent
-
-
-def find_trace(cell: str) -> Path | None:
-    """The raw trace run.py left for `cell`: `.chipbench_work/<cell>/trace`
-    (harness.prepare; README "What a run does"), which run.py empties
-    before each traced run, so what is there is this run's. No other
-    directory is looked into: a sweep's, or another cell's whose name only
-    starts like this one's, holds another run."""
-    try:
-        return xplane.find_xplane(ROOT / ".chipbench_work" / cell / "trace")
-    except FileNotFoundError:
-        return None
 
 
 def load_spans(path: Path) -> dict[str, list[dict]]:
@@ -105,9 +92,10 @@ def _overlaps(pieces, a: float, b: float) -> dict[str, float]:
 
 
 def idle_by_span(ir: dict, spans: dict[str, list[dict]]) -> list[list]:
-    """[[phase, seconds]]: idle time between device programs (as
-    `xplane.idle_gaps` takes it), summed by the engine phase covering most
-    of each gap, averaged over the chips in the trace."""
+    """[[phase, seconds]]: idle time between consecutive device programs,
+    summed by the engine phase covering most of each gap (`unnamed` where
+    the trace holds no span there: a program without the annotations gives
+    one `unnamed` row), averaged over the chips in the trace."""
     pieces = _pieces(spans)
     tot: dict[str, float] = {}
     for plane in ir.values():

@@ -7,11 +7,8 @@ from chipbench import hostspans
 
 
 def read(ctx):
-    if not ctx.get("trace") or not ctx.get("cell"):
-        return None
-    path = hostspans.find_trace(ctx["cell"])
-    spans = hostspans.load_spans(path) if path else {}
-    if not spans:
+    spans = ctx.get("host_spans")
+    if not ctx.get("trace") or not spans:
         return None
     unfed, window = hostspans.idle_unfed(ctx["trace"], spans)
     if window <= 0:

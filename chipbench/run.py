@@ -9,7 +9,8 @@ chip), warms up every shape the cell's traffic uses, offers the cell's
 traffic open-loop at the cell's fixed rate over the master's HTTP port for
 `--seconds`, stops the processes, and only then takes the chip itself to
 compare a sample of what was served with the plain reference. The last line
-of stdout is the result. Without a TPU the run fails; `--rehearse` (the
+of stdout is the result; its last key, and the last lines of stderr, hold
+every number `correct` rests on beside its limit. Without a TPU the run fails; `--rehearse` (the
 builder's flag, never the driver's) runs the same code on the CPU and says
 `cpu` in its device line.
 
@@ -170,16 +171,18 @@ def measure(ref_logits, chosen, tops) -> dict:
 
 def compare(cell, sample, seed: int, control: bool) -> dict:
     """Takes the chip (the children are gone): teacher-forces prompt +
-    served tokens through the reference and reads the served tokens, and
-    their log-probabilities where the cell asked for them, against it."""
-    from chipbench import reference
+    served tokens through the reference of the configuration's family and
+    reads the served tokens, and their log-probabilities where the cell
+    asked for them, against it."""
+    from chipbench import reference as shared
 
+    reference = cell.family.reference
     prompts = [r.req.prompt for r in sample]
     outputs = [harness.text_tokens(r.text) for r in sample]
     k = cell.check_logprobs
     tops = [served_logprobs(r, k) for r in sample] if k else None
     served = cell.engine["weights"]
-    seqs, pos = reference.teacher_forced(prompts, outputs)
+    seqs, pos = shared.teacher_forced(prompts, outputs)
     # The mix's longest request and answer: the same shapes in every run.
     pad = dict(pad_len=loadgen.longest_total(cell.mix),
                pad_pos=cell.mix["output_tokens"]["max"])
@@ -191,7 +194,7 @@ def compare(cell, sample, seed: int, control: bool) -> dict:
         t = time.monotonic()
         low = reference.logits_at(seed, cell.hf, served, seqs, pos,
                                   LOWER[served], **pad)
-        ctops = [reference.top_logprobs(lg, k) for lg in low] if k else None
+        ctops = [shared.top_logprobs(lg, k) for lg in low] if k else None
         out["control"] = dict(
             measure(ref, [lg.argmax(-1) for lg in low], ctops),
             precision=LOWER[served], seconds=time.monotonic() - t)
@@ -199,19 +202,26 @@ def compare(cell, sample, seed: int, control: bool) -> dict:
 
 
 def decide(limits: dict, failed: int, compiled: int, loaded: int,
-           decode_path: str, blocks: list | None, cmp_) -> tuple[bool, dict]:
+           paths: dict, required: dict, blocks: list | None,
+           cmp_) -> tuple[bool, dict]:
     """`correct`, and every number it rests on beside its limit: no failed
-    request, nothing compiled or loaded inside the window, the Pallas kernel
-    in the served decode program, the mix's shared prefixes held by the
-    prefix cache from the window's start to its end (`blocks`: [held,
-    needed], None where nothing is shared), and each number of the
-    comparison with the reference that the cell's file gives a limit."""
+    request, nothing compiled or loaded inside the window, every op of the
+    served decode program that the configuration names on the path it names
+    (`paths`: what `decode_multi` took by op, `required`: op -> the prefix
+    its path must have; by default the Pallas paged-attention kernel; with
+    no op named, or an empty prefix, nothing is held and it is not correct),
+    the mix's shared prefixes held by the prefix cache from the window's
+    start to its end (`blocks`: [held, needed], None where nothing is
+    shared), and each number of the comparison with the reference that the
+    cell's file gives a limit."""
     checks = {"failed_requests": [failed, 0],
               "compilations_in_window": [compiled, 0],
-              "executables_loaded_in_window": [loaded, 0],
-              "decode_multi_path": [decode_path, "pallas*"]}
-    ok = (failed == 0 and compiled == 0 and loaded == 0
-          and decode_path.startswith("pallas") and cmp_ is not None)
+              "executables_loaded_in_window": [loaded, 0]}
+    ok = (failed == 0 and compiled == 0 and loaded == 0 and cmp_ is not None
+          and bool(required))
+    for op, prefix in required.items():
+        checks["decode_multi." + op] = [paths.get(op, ""), prefix + "*"]
+        ok = ok and bool(prefix) and paths.get(op, "").startswith(prefix)
     if blocks is not None:
         checks["prefix_blocks_held_min"] = blocks
         ok = ok and blocks[0] >= blocks[1]
@@ -268,6 +278,8 @@ def main(argv=None) -> int:
     harness.dump_records(
         recs, t0, outdir / f"records_s{args.seed}_t{args.trace}.json")
     say(phase="setup", setup_s=setup_s, **s["setup_parts"])
+    say(phase="family", **{k: s["started"].get(k) for k in (
+        "family", "weights_file", "engine_config")})
     say(phase="window", **{k: e2e[k] for k in (
         "attempted", "failed", "ttft_ms.mean", "ttft_ms.p50", "ttft_ms.p90",
         "tpot_ms.p50", "tpot_ms.p90", "gap_ms.p95", "gap_ms.p99",
@@ -281,8 +293,9 @@ def main(argv=None) -> int:
     compiled = (marks["end"]["compilations"] - marks["start"]["compilations"])
     loaded = marks["end"]["cache_loads"] - marks["start"]["cache_loads"]
     paths = (marks["stats"].get("attention_paths") or [{}])[0]
-    decode_path = paths.get("decode_multi", {}).get("paged_attention", "")
-    say(phase="paths", decode_multi=decode_path, attention_paths=paths)
+    decode_paths = paths.get("decode_multi", {})
+    say(phase="paths", decode_multi=decode_paths, required=cell.decode_paths,
+        attention_paths=paths)
     if compiled:
         say(phase="built_in_window", programs=[
             b for b in marks["end"]["built"]
@@ -292,11 +305,12 @@ def main(argv=None) -> int:
     device["memory_peak_bytes"] = report["memory_peak_bytes"]
     metrics: dict = {}
     breakdown = None
-    ctx = {"trace": None, "agent_stats": marks["stats"],
+    ctx = {"trace": None, "host_spans": None, "agent_stats": marks["stats"],
            "hotpath": marks["hotpath"], "hf": cell.hf, "engine": cell.engine,
-           "device": device, "cell": cell.name, "client": e2e}
+           "family": cell.family, "device": device, "cell": cell.name,
+           "client": e2e}
     if args.trace:
-        from chipbench import xplane
+        from chipbench import hostspans, xplane
 
         xp = xplane.find_xplane(s["trace_dir"])
         (outdir / "trace_inventory.json").write_text(
@@ -311,11 +325,17 @@ def main(argv=None) -> int:
                          (xplane.MODULE_LINE, xplane.OP_LINE))
         (outdir / "trace_head.json").write_text(
             json.dumps(xplane.trim(ir, 0.12)))
-        ctx["trace"] = ir
+        ctx["trace"], ctx["host_spans"] = ir, hostspans.load_spans(xp)
         busy, window = xplane.busy_and_window(ir)
         device["busy_s"], device["window_s"] = busy, window
-        breakdown = {"device_ops": xplane.top_ops(ir),
-                     "idle_gaps": xplane.idle_gaps(ir)}
+        # The result's line may hold ten rows of each; the line before it
+        # holds 25 operations, so that a family's kernels and whatever a
+        # PR removes can be read and not only bounded by the tenth row.
+        ops = xplane.top_ops(ir, 25)
+        say(phase="device_ops", rows=ops)
+        breakdown = {"device_ops": ops[:10],
+                     "idle_gaps": hostspans.idle_by_span(
+                         ir, ctx["host_spans"])[:10]}
         for m in harness.metrics_for(bench, "per_layer", cell.name):
             value = harness.load_reader(search, m["name"])(ctx)
             if value is not None:
@@ -332,14 +352,21 @@ def main(argv=None) -> int:
     cmp_ = compare(cell, sample, args.seed, args.control) if sample else None
     say(phase="reference", **(cmp_ or {"error": "no finished request"}))
     ok, checks = decide(cell.limits, e2e["failed"], compiled, loaded,
-                        decode_path, prefix_blocks(cell, marks), cmp_)
-    say(phase="checks", compared_beside_limit=checks, correct=ok)
+                        decode_paths, cell.decode_paths,
+                        prefix_blocks(cell, marks), cmp_)
 
     result = {"correct": bool(ok), "attempted": e2e["attempted"],
               "failed": e2e["failed"], "metrics": metrics, "device": device}
     if breakdown is not None:
         result["breakdown"] = breakdown
+    # Each number compared beside its limit, last in the result's line and
+    # last on stderr: of a run that is not correct the driver's record keeps
+    # the ends of those two and nothing else.
+    result["compared"] = checks
     say(**result)
+    for name, (got, limit) in checks.items():
+        print(f"chipbench: {name} {got} limit {limit}", file=sys.stderr)
+    print(f"chipbench: correct {ok}", file=sys.stderr, flush=True)
     return 0
 
 

@@ -176,23 +176,6 @@ def top_ops(ir: dict, n: int = 10) -> list[list]:
             sorted(tot.items(), key=lambda kv: -kv[1])[:n]]
 
 
-def idle_gaps(ir: dict, n: int = 10) -> list[list]:
-    """[[label, seconds]]: idle time between device programs, summed by
-    `program before -> program after` (the program writes no host spans into
-    the trace yet, so the neighbours are the only label there is)."""
-    tot: dict[str, float] = {}
-    for plane in ir.values():
-        mods = plane.get(MODULE_LINE, [])
-        for a, b in zip(mods, mods[1:]):
-            gap = b["start"] - (a["start"] + a["dur"])
-            if gap > 0:
-                key = f"{program_name(a['name'])}->{program_name(b['name'])}"
-                tot[key] = tot.get(key, 0.0) + gap
-    n_chips = max(1, len(ir))
-    return [[k, v / n_chips] for k, v in
-            sorted(tot.items(), key=lambda kv: -kv[1])[:n]]
-
-
 def trim(ir: dict, seconds: float) -> dict:
     """The first `seconds` of a trace, for a fixture small enough to commit."""
     out = {}

@@ -3,7 +3,6 @@ of its phases on the trace clock (chipbench/hostspans.py): attribution on a
 synthetic plane, each reader on a fixture `ctx` and on an empty one."""
 
 import json
-import os
 from pathlib import Path
 
 import pytest
@@ -82,23 +81,25 @@ def test_idle_unfed_leaves_out_the_idle_time_spent_waiting_for_the_chip():
     assert hostspans.idle_unfed(ir, {})[0] == pytest.approx(0.030)
 
 
-def test_find_trace_looks_only_into_the_cells_own_directory(tmp_path,
-                                                           monkeypatch):
-    """run.py's work directory is `.chipbench_work/<cell>` exactly; a
-    sweep's, or a cell's whose name extends this one's, is another run."""
-    monkeypatch.setattr(hostspans, "ROOT", tmp_path)
-    assert hostspans.find_trace("some.cell") is None
-    for name in ("some.cell-sweep", "some.cell-long", "other.cell"):
-        d = tmp_path / ".chipbench_work" / name / "trace" / "p"
-        d.mkdir(parents=True)
-        (d / "host.xplane.pb").write_bytes(b"")
-    (tmp_path / ".chipbench_work" / "some.cell").mkdir()
-    assert hostspans.find_trace("some.cell") is None
-    assert hostspans.find_trace("other.cell").parts[-4] == "other.cell"
-    own = tmp_path / ".chipbench_work" / "some.cell" / "trace" / "p"
-    own.mkdir(parents=True)
-    (own / "host.xplane.pb").write_bytes(b"")
-    assert hostspans.find_trace("some.cell").parts[-4] == "some.cell"
+def test_idle_unfed_reads_the_host_plane_run_py_hands_it(monkeypatch):
+    """`ctx["host_spans"]` is the trace's host plane as run.py loaded it,
+    beside the device planes in `ctx["trace"]`: the reader opens no file
+    (it used to look for `.chipbench_work/<cell>/trace` itself), so a
+    trace another run left there cannot be read for this one's."""
+    def opened(*a, **k):
+        raise AssertionError("a reader opened a trace")
+
+    monkeypatch.setattr(hostspans, "load_spans", opened)
+    monkeypatch.setattr("chipbench.xplane.find_xplane", opened)
+    read = harness.load_reader(SEARCH, "device.idle_unfed_pct")
+    ir = _device(("decode_multi", 0.0, 0.1), ("decode_multi", 0.12, 0.2))
+    ctx = {"trace": ir, "cell": CELL2,
+           "host_spans": {"python#1": [_span("fetch_wait", 0.1, 0.115)]}}
+    assert read(ctx) == pytest.approx(100 * 0.005 / 0.2)
+    assert read(dict(ctx, host_spans=None)) is None
+    assert read(dict(ctx, host_spans={})) is None
+    assert read(dict(ctx, trace=None)) is None
+    assert not hasattr(hostspans, "find_trace")
 
 
 # ------------------------------------------------------------- the readers
@@ -111,13 +112,14 @@ RECENT = {
                "fetch_wait": 24.0, "emit": 2.8, "idle": 0.6}}
 
 
-def _ctx():
+def _ctx(spans=None):
     hf = json.loads((ROOT / "chipbench/configs/qwen25-3b-bf16/config.json")
                     .read_text())
     trace = json.loads((ROOT / "tests/chipbench/data/trace_head_tpu_v5e.json")
                        .read_text())
     return {"trace": trace, "agent_stats": {"engine_trace": {"recent": RECENT}},
-            "hotpath": {}, "hf": hf, "cell": CELL2,
+            "hotpath": {}, "hf": hf, "cell": CELL2, "host_spans": spans,
+            "family": harness.Family(SEARCH),
             "engine": {"decode_horizon": 8, "page_size": 16,
                        "weights": "bfloat16"},
             "device": {"kind": "TPU v5 lite"}}
@@ -143,30 +145,26 @@ EXPECTED = {
 
 
 @pytest.fixture()
-def pump_in_the_gap(monkeypatch):
+def pump_in_the_gap():
     """The pump waits for the chip through the first 2 ms of the recorded
     trace's one gap between programs (50.227-53.431 ms)."""
-    spans = {"python#1": [_span("fetch_wait", 0.050226763, 0.052226763)]}
-    monkeypatch.setattr(hostspans, "find_trace", lambda cell: Path("x.pb"))
-    monkeypatch.setattr(hostspans, "load_spans", lambda path: spans)
+    return {"python#1": [_span("fetch_wait", 0.050226763, 0.052226763)]}
 
 
 @pytest.mark.parametrize("name", sorted(EXPECTED))
 def test_reader_on_a_fixture_ctx(name, pump_in_the_gap):
-    value = harness.load_reader(SEARCH, name)(_ctx())
+    value = harness.load_reader(SEARCH, name)(_ctx(pump_in_the_gap))
     assert value == pytest.approx(EXPECTED[name], rel=1e-6)
     if name.endswith("_pct"):
         assert 0 <= value <= 100
 
 
 @pytest.mark.parametrize("name", sorted(EXPECTED))
-def test_reader_on_a_program_without_the_record(name, monkeypatch):
+def test_reader_on_a_program_without_the_record(name):
     """The parent commit: `/stats` has no `engine_trace`, the trace no
     `engine.*` span. Nothing to read, nothing raised."""
-    monkeypatch.setattr(hostspans, "find_trace", lambda cell: Path("x.pb"))
-    monkeypatch.setattr(hostspans, "load_spans", lambda path: {})
     read = harness.load_reader(SEARCH, name)
-    ctx = _ctx()
+    ctx = _ctx({})
     ctx["agent_stats"] = {"ttft_spans": {"n": 3}, "cached_blocks": 224}
     assert read(ctx) is None
     assert read({"trace": None, "agent_stats": {}, "hotpath": {}}) is None
@@ -175,12 +173,31 @@ def test_reader_on_a_program_without_the_record(name, monkeypatch):
 
 
 def test_kv_bandwidth_share_of_a_pool_type_it_does_not_know(pump_in_the_gap):
-    ctx = _ctx()
-    ctx["hf"] = dict(ctx["hf"], torch_dtype="float8_e4m3fn")
+    ctx = _ctx(pump_in_the_gap)
     read = harness.load_reader(SEARCH, "kernel.paged_attn_kv_bw_pct")
+    # through the default family: the parent's own arithmetic, to the last
+    # digit (2200 context tokens a step, the kernel's 1.008347 ms / 8)
+    assert read(ctx) == 100.0 * (2200.0 * 36864 / 819e9) / (
+        harness.load_reader(SEARCH, "kernel.paged_attn_ms")(ctx) / 1000.0)
+    assert read(dict(ctx, family=None)) is None
+    ctx["hf"] = dict(ctx["hf"], torch_dtype="float8_e4m3fn")
     assert read(ctx) is None
     del ctx["hf"]["torch_dtype"]
     assert read(ctx) is None
+
+
+def test_kv_bandwidth_share_counts_what_the_family_counts(pump_in_the_gap):
+    """A family whose tokens hold keys and values in 9 of its 36 layers
+    says so in its `bytes`; the reader divides by nothing of its own."""
+    class Quarter:
+        class bytes:
+            @staticmethod
+            def kv_bytes_per_token(hf):
+                return 36864 // 4
+
+    ctx = _ctx(pump_in_the_gap)
+    read = harness.load_reader(SEARCH, "kernel.paged_attn_kv_bw_pct")
+    assert read(dict(ctx, family=Quarter)) == pytest.approx(read(ctx) / 4)
 
 
 def test_the_six_follow_the_thirteen_and_are_reported_where_they_read():

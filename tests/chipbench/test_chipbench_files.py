@@ -163,15 +163,24 @@ def _copy_benchmark(tmp: Path) -> None:
 
 
 def test_new_cell_config_mix_and_metric_are_files_and_one_entry_each(tmp_path):
-    """A later PR adds a configuration, a mix, a cell and a per-layer metric
-    by dropping files in and appending to BENCHMARK.json; no file that is
-    there is edited, and the harness resolves all four by name."""
+    """A later PR adds a configuration (its `engine.json` with
+    `engine_config`), its family under `chipbench/families/`, a mix, a cell
+    and a per-layer metric by dropping files in and appending to
+    BENCHMARK.json; no file that is there is edited, the harness resolves
+    all five by name, and the tests under `paths` that walk BENCHMARK.json,
+    which that PR may not edit either, pass in the copy."""
     _copy_benchmark(tmp_path)
     before = {p: p.read_bytes() for p in tmp_path.rglob("*")
               if p.is_file() and p.name != "BENCHMARK.json"}
     data = ROOT / "tests/chipbench/data"
-    shutil.copytree(data / "configs/tiny-qwen2",
+    shutil.copytree(data / "configs/tiny-moe",
                     tmp_path / "chipbench/configs/later-model")
+    shutil.copytree(data / "families/toy-moe",
+                    tmp_path / "chipbench/families/later-family")
+    cfg = tmp_path / "chipbench/configs/later-model/config.json"
+    hf = json.loads(cfg.read_text())
+    hf["chipbench"]["family"] = "later-family"
+    cfg.write_text(json.dumps(hf))
     shutil.copy(data / "traffic/tiny-chat.json",
                 tmp_path / "chipbench/traffic/later-mix.json")
     (tmp_path / "chipbench/cells/later-model.later-mix.json").write_text(
@@ -180,10 +189,12 @@ def test_new_cell_config_mix_and_metric_are_files_and_one_entry_each(tmp_path):
                     "limits": {"gap_max": 2.0, "gap_mean": 0.5}}))
     (tmp_path / "chipbench/layers/later.generated_total.py").write_text(
         "def read(ctx):\n"
-        "    return float(ctx['agent_stats']['total_generated'])\n")
+        "    n = ctx['agent_stats'].get('total_generated')\n"
+        "    return None if n is None else float(n)\n")
     bench = json.loads((tmp_path / "BENCHMARK.json").read_text())
     bench["configs"].append({
-        "name": "later-model", "source": "none", "reduced": [], "why": "x",
+        "name": "later-model", "source": hf["chipbench"]["source"],
+        "reduced": [], "why": "x",
         "file": "chipbench/configs/later-model/config.json"})
     bench["workloads"].append({
         "name": "later-model.later-mix", "config": "later-model",
@@ -203,17 +214,56 @@ def test_new_cell_config_mix_and_metric_are_files_and_one_entry_each(tmp_path):
         "names = [m['name'] for m in harness.metrics_for(b, 'per_layer', c.name)]\n"
         "old = [m['name'] for m in harness.metrics_for(b, 'per_layer', "
         "'qwen25-7b-int8.chat')]\n"
+        "import jax\n"
+        "tree = c.family.weights.param_shapes(c.hf, c.engine['weights'])\n"
         "print(json.dumps([str(harness.ROOT), c.rate, c.hf['hidden_size'], "
         "r({'agent_stats': {'total_generated': 5}}), "
-        "'later.generated_total' in names, 'later.generated_total' in old]))\n")
+        "'later.generated_total' in names, 'later.generated_total' in old, "
+        "c.family.name, c.family.weights.__file__, "
+        "list(tree['moe']['experts']['up_proj']['kernel'].shape), "
+        "c.family.bytes.kv_bytes_per_token(c.hf), "
+        "c.engine['engine_config'], c.decode_paths]))\n")
     out = subprocess.run([sys.executable, "-c", code], cwd=tmp_path,
                          capture_output=True, text=True, timeout=120,
-                         env={"PATH": "/usr/bin:/bin", "PYTHONPATH": str(ROOT)})
+                         env={"PATH": "/usr/bin:/bin", "PYTHONPATH": str(ROOT),
+                              "JAX_PLATFORMS": "cpu"})
     assert out.returncode == 0, out.stderr
-    root, rate, hidden, value, in_new, in_old = json.loads(
+    (root, rate, hidden, value, in_new, in_old, family, weights_file, experts,
+     kv_bytes, engine_config, decode_paths) = json.loads(
         out.stdout.strip().split("\n")[-1])
     assert Path(root) == tmp_path
     assert (rate, hidden, value, in_new, in_old) == (2.0, 256, 5.0, True, False)
+    assert family == "later-family" and Path(weights_file) == (
+        tmp_path / "chipbench/families/later-family/weights.py")
+    assert experts == [2, 4, 256, 128] and kv_bytes == 1024
+    assert engine_config == {"admission_horizon": 4}
+    assert decode_paths == {"paged_attention": "pallas"}
+    # The copy holds the tests too (`tests/chipbench` is under `paths`): the
+    # ones that walk BENCHMARK.json and the families, run there. Left out:
+    # this test, the one that asks git, the two that start run.py, and the
+    # toys' numerics, which the new files do not touch.
+    walk = subprocess.run(
+        [sys.executable, "-m", "pytest", "-v", "-m", "not slow",
+         "-p", "no:cacheprovider", "-p", "no:randomly", "-k",
+         "not (new_cell_config or files_under_paths or directory_with_only "
+         "or without_a_tpu or reference_agrees or pure_function "
+         "or parent_commits)",
+         *(f"tests/chipbench/test_chipbench_{f}.py"
+           for f in ("files", "units", "family"))],
+        cwd=tmp_path, capture_output=True, text=True, timeout=600,
+        env={"PATH": "/usr/bin:/bin", "PYTHONPATH": str(ROOT),
+             "JAX_PLATFORMS": "cpu", "PYTHONDONTWRITEBYTECODE": "1"})
+    assert walk.returncode == 0, walk.stdout[-4000:] + walk.stderr[-2000:]
+    for ran in ("test_config_entry[later-model]",
+                "test_workload_entry_and_its_files[later-model.later-mix]",
+                "test_per_layer_entry_and_its_reader[later.generated_total]",
+                "test_contract_signatures_and_independence[later-family]",
+                "test_contract_shapes_and_bytes[later-model]",
+                "test_the_two_engine_files_of_pr_23_hold_the_eleven_keys_"
+                "alone[qwen25-7b-int8]",
+                "test_a_family_is_found_under_the_second_search_path_and_"
+                "loaded_once"):
+        assert ran + " PASSED" in walk.stdout, ran
     after = {p: p.read_bytes() for p in before}
     assert after == before
 

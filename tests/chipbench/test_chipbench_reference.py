@@ -113,8 +113,9 @@ def test_control_reads_above_the_sound_path_and_a_broken_token_fails(served):
               "lp_rms": (sound["lp_rms"] * control["lp_rms"]) ** 0.5}
 
     def verdict(cmp_, **kw):
-        args = dict(failed=0, compiled=0, loaded=0, decode_path="pallas",
-                    blocks=None)
+        args = dict(failed=0, compiled=0, loaded=0,
+                    paths={"paged_attention": "pallas"},
+                    required={"paged_attention": "pallas"}, blocks=None)
         args.update(kw)
         return run.decide(limits, cmp_=cmp_, **args)[0]
 
@@ -132,7 +133,8 @@ def test_control_reads_above_the_sound_path_and_a_broken_token_fails(served):
     assert verdict(sound, failed=1) is False
     assert verdict(sound, compiled=1) is False
     assert verdict(sound, loaded=1) is False
-    assert verdict(sound, decode_path="xla (cpu backend)") is False
+    assert verdict(sound,
+                   paths={"paged_attention": "xla (cpu backend)"}) is False
     assert verdict(sound, blocks=[3, 4]) is False
     assert verdict(sound, blocks=[4, 4]) is True
     assert verdict(None) is False

@@ -3,12 +3,13 @@ function of the seed, percentiles and pooled gaps, the knee rule, the
 weight-bytes model against hand-worked numbers, the trace reduction."""
 
 import json
+import shutil
 import statistics
 from pathlib import Path
 
 import pytest
 
-from chipbench import bytes_model, loadgen, peaks, stats, xplane
+from chipbench import bytes_model, harness, loadgen, peaks, stats, xplane
 
 ROOT = Path(__file__).resolve().parents[2]
 CHAT = loadgen.read_mix(ROOT / "chipbench/traffic/chat.json")
@@ -192,9 +193,11 @@ def test_trace_reduction_on_a_hand_made_trace():
     assert ops["decode_multi/fusion"] == pytest.approx(0.030 + 0.045 + 0.020)
     assert ops["decode_multi/_paged_attention_impl"] == pytest.approx(0.030)
     assert ops["prefill_install/fusion"] == pytest.approx(0.050)
-    gaps = dict(xplane.idle_gaps(SYNTH))
-    assert gaps["decode_multi->prefill_install"] == pytest.approx(0.020)
-    assert gaps["prefill_install->decode_multi"] == pytest.approx(0.010)
+    from chipbench import hostspans
+
+    # a trace without host spans: one row, every gap between programs
+    assert hostspans.idle_by_span(SYNTH, {}) == [
+        ["unnamed", pytest.approx(0.020 + 0.010)]]
     assert xplane.program_name("jit_prefill_install_nc(123)") == \
         "prefill_install_nc"
     assert xplane.op_stem("%fusion.123") == "fusion"
@@ -220,16 +223,18 @@ def test_trace_reduction_on_a_recorded_tpu_trace():
     assert ops["prefill_install/fusion"] == pytest.approx(0.019618696, rel=1e-6)
     assert ops["prefill_install/copy"] == pytest.approx(0.018816489, rel=1e-6)
     assert not any(k.endswith("/while") for k in ops)
-    assert dict(xplane.idle_gaps(ir)) == {
-        "prefill_install->decode_multi": pytest.approx(0.003204217, rel=1e-6)}
+    from chipbench import hostspans
+
+    assert hostspans.idle_by_span(ir, {}) == [
+        ["unnamed", pytest.approx(0.003204217, rel=1e-6)]]
     kernel = xplane.ops_inside(
         ir, "decode_multi", lambda e: e["name"].startswith("_paged_attention"))
     assert kernel == [pytest.approx(0.001008347, rel=1e-6)]   # 6 calls
     # the per-layer readers on the same trace
-    from chipbench import harness
     _, search = harness.load_bench(ROOT / "BENCHMARK.json")
     ctx = {"trace": ir, "engine": {"decode_horizon": 8, "weights": "bfloat16"},
-           "hf": _hf("qwen25-3b-bf16"), "device": {"kind": "TPU v5 lite"}}
+           "hf": _hf("qwen25-3b-bf16"), "device": {"kind": "TPU v5 lite"},
+           "family": harness.Family(search)}
     step = harness.load_reader(search, "prog.decode_step_ms")(ctx)
     assert step == pytest.approx(274.422101 / 8)
     assert harness.load_reader(search, "prog.prefill_call_ms")(ctx) == \
@@ -238,8 +243,13 @@ def test_trace_reduction_on_a_recorded_tpu_trace():
         pytest.approx(1.008347 / 8)
     assert harness.load_reader(search, "device.idle_pct")(ctx) == \
         pytest.approx(1.0044, rel=1e-3)
+    # through the default family: the number the parent's arithmetic gave
+    # (bytes_model.py called by the reader itself), to the last digit
     assert harness.load_reader(search, "device.decode_weight_bw_pct")(ctx) == \
-        pytest.approx(100 * (6171877376 / 819e9) / (step / 1000))
+        100.0 * (6171877376 / 819e9) / (step / 1000.0)
+    # the 25 rows the line before the result holds, the ten in it
+    assert len(xplane.top_ops(ir, 25)) == min(25, len(xplane.top_ops(ir, 99)))
+    assert xplane.top_ops(ir, 25)[:10] == xplane.top_ops(ir)
 
 
 # ------------------------------------------- what `correct` compares (run.py)
@@ -357,7 +367,152 @@ def test_prefix_blocks_reads_the_fewest_held_against_the_mixes_prefixes(
 def test_decide_holds_every_number_the_cell_limits(limits, cmp_, blocks, want):
     from chipbench import run
 
-    ok, checks = run.decide(limits, 0, 0, 0, "pallas", blocks, cmp_)
+    ok, checks = run.decide(limits, 0, 0, 0, {"paged_attention": "pallas"},
+                            harness.DECODE_PATHS, blocks, cmp_)
     assert ok is want
     assert all(len(v) == 2 for v in checks.values())   # number beside limit
     assert set(limits) <= set(checks)
+    assert checks["decode_multi.paged_attention"] == ["pallas", "pallas*"]
+
+
+@pytest.mark.parametrize("paths,required,want", [
+    ({"paged_attention": "pallas (shard_map model=4)", "linear_scan": "pallas-chunked"},
+     {"paged_attention": "pallas", "linear_scan": "pallas"}, True),
+    ({"paged_attention": "pallas", "linear_scan": "xla (cpu backend)"},
+     {"paged_attention": "pallas", "linear_scan": "pallas"}, False),
+    ({"paged_attention": "pallas"},                     # an op never taken
+     {"paged_attention": "pallas", "linear_scan": "pallas"}, False),
+    ({"paged_attention": "xla (cpu backend)", "linear_scan": "pallas"},
+     {"linear_scan": "pallas"}, True),                  # only what is named
+    ({}, {}, False),                     # nothing named, nothing held
+    ({"paged_attention": "xla (cpu backend)"}, {"paged_attention": ""}, False),
+])
+def test_decide_holds_every_op_the_configuration_names(paths, required, want):
+    from chipbench import run
+
+    ok, checks = run.decide({"gap_max": 0.3}, 0, 0, 0, paths, required, None,
+                            {"gap_max": 0.1})
+    assert ok is want
+    for op, prefix in required.items():
+        assert checks["decode_multi." + op] == [paths.get(op, ""),
+                                                prefix + "*"]
+    assert not any(k.startswith("decode_multi.") and k[13:] not in required
+                   for k in checks)
+
+
+def test_resolve_cell_reads_the_decode_paths_and_the_family():
+    bench, search = harness.load_bench(
+        ROOT / "tests/chipbench/data/BENCHMARK.json")
+    toy = harness.resolve_cell(bench, search, "tiny-moe.tiny-chat")
+    assert toy.decode_paths == {"paged_attention": "pallas"}
+    assert toy.family.name == "toy-moe" and toy.family.search == search
+    old = harness.resolve_cell(bench, search, "tiny-qwen2.tiny-chat")
+    assert old.decode_paths == harness.DECODE_PATHS and old.family.name == ""
+    assert old.decode_paths is not harness.DECODE_PATHS
+
+
+@pytest.mark.parametrize("stated", [
+    {}, {"paged_attention": ""}, {"paged_attention": "pallas", "scan": ""},
+    {"paged_attention": None}, ["paged_attention"], "pallas", None])
+def test_decode_paths_that_would_hold_no_op_are_refused(tmp_path, stated):
+    """Data alone cannot take the kernel check away: a configuration whose
+    `decode_paths` names no op, or gives a prefix every path has, resolves
+    to no cell."""
+    data = ROOT / "tests/chipbench/data"
+    bench, search = harness.load_bench(data / "BENCHMARK.json")
+    shutil.copytree(data / "configs/tiny-moe", tmp_path / "cfg")
+    cfg = tmp_path / "cfg/config.json"
+    hf = json.loads(cfg.read_text())
+    hf["chipbench"]["decode_paths"] = stated
+    cfg.write_text(json.dumps(hf))
+    bench["configs"][1]["file"] = str(cfg)
+    with pytest.raises(harness.Failure,
+                       match=r"cfg/config.json.*decode_paths"):
+        harness.resolve_cell(bench, search, "tiny-moe.tiny-chat")
+    del hf["chipbench"]["decode_paths"]              # absent: the default
+    cfg.write_text(json.dumps(hf))
+    cell = harness.resolve_cell(bench, search, "tiny-moe.tiny-chat")
+    assert cell.decode_paths == harness.DECODE_PATHS
+
+
+# ------------------------------------------ engine.json beyond the eleven keys
+ELEVEN = {"weights": "int8", "num_pages": 64, "page_size": 16,
+          "hash_block_size": 128, "max_batch_size": 4, "max_seq_len": 256,
+          "prefill_buckets": [128, 256], "decode_horizon": 8,
+          "warmup_programs": False, "tp": 1, "replicas": 1}
+
+
+def _engine_dir(tmp_path, **more):
+    (tmp_path / "engine.json").write_text(json.dumps({**ELEVEN, **more}))
+    return tmp_path
+
+
+def test_engine_json_takes_engine_config_fields_by_name(tmp_path):
+    from chipbench import engine_setup
+
+    eng = engine_setup.read_engine_json(_engine_dir(tmp_path, engine_config={
+        "prefill_chunk_tokens": 64, "admission_horizon": 4}))
+    assert engine_setup.engine_config_kwargs(eng, ()) == {
+        "prefill_chunk_tokens": 64, "admission_horizon": 4}
+    assert engine_setup.engine_config_kwargs(ELEVEN, ()) == {}
+    shutil.copy(ROOT / "tests/chipbench/data/configs/tiny-qwen2/config.json",
+                tmp_path / "config.json")
+    ecfg, _ = engine_setup.build_engine_config(tmp_path, 5, "t")
+    assert (ecfg.prefill_chunk_tokens, ecfg.admission_horizon) == (64, 4)
+    assert ecfg.num_pages == 64 and ecfg.prefill_buckets == (128, 256)
+
+
+@pytest.mark.parametrize("extra,message", [
+    ({"engine_config": {"state_slots": 3}}, "state_slots"),     # no field
+    # what build_engine_config passes itself, whichever key it comes from
+    ({"engine_config": {"num_pages": 9}}, "decided elsewhere.*num_pages"),
+    ({"engine_config": {"seed": 1}}, "decided elsewhere.*seed"),
+    ({"engine_config": {"model_family": "x"}},
+     "decided elsewhere.*model_family"),
+    ({"engine_config": {"mesh": None}}, "decided elsewhere.*mesh"),
+    ({"engine_config": [1]}, "engine_config must be an object"),
+    ({"state_slots": 3}, "unknown keys"),
+])
+def test_engine_json_refuses_what_is_no_engine_config_field(tmp_path, extra,
+                                                            message):
+    """The documented ValueError, never a TypeError from EngineConfig for a
+    name given twice: the refused names are the call's own arguments."""
+    from chipbench import engine_setup
+
+    shutil.copy(ROOT / "tests/chipbench/data/configs/tiny-qwen2/config.json",
+                tmp_path / "config.json")
+    with pytest.raises(ValueError, match=message):
+        engine_setup.build_engine_config(_engine_dir(tmp_path, **extra), 5,
+                                         "t")
+
+
+@pytest.mark.parametrize("name", ["qwen25-7b-int8", "qwen25-3b-bf16"])
+def test_the_two_engine_files_of_pr_23_hold_the_eleven_keys_alone(name):
+    """ISSUE 26: the two files the benchmark had stay as they were. A
+    configuration a later PR adds may carry `engine_config`: this test
+    names the two, and the drop-in test of test_chipbench_files.py runs it
+    in a copy that holds such a configuration."""
+    eng = json.loads((ROOT / "chipbench/configs" / name / "engine.json")
+                     .read_text())
+    assert set(eng) == set(ELEVEN)
+
+
+def test_sizing_line_holds_the_arguments_against_the_chip_and_the_floors():
+    from chipbench import sizing
+
+    bench, search = harness.load_bench(
+        ROOT / "tests/chipbench/data/BENCHMARK.json")
+    cell = harness.resolve_cell(bench, search, "tiny-moe.tiny-chat")
+    line = sizing.size_line("tiny-moe", cell.family, cell.hf, cell.engine,
+                            {"argument_gib": 4.0}, 16e9)
+    # embedding and head 2 x 1024 x 256, per layer 2 x (256 x 256 + 256 x
+    # 128) attention + 256 x 4 float32 router + 3 x 4 x 256 x 128 experts +
+    # two norms, one final norm: bfloat16
+    want = 2 * (2 * 1024 * 256 + 2 * (2 * 256 * 256 + 2 * 256 * 128
+                                      + 12 * 256 * 128 + 2 * 256) + 256) \
+        + 2 * 256 * 4 * 4
+    assert line["family"] == "toy-moe"
+    assert line["family_weights_bytes"] == want
+    assert line["decode_arguments_bytes"] == 4 * 2 ** 30
+    assert line["arguments_pct_of_hbm"] == pytest.approx(26.84, abs=0.01)
+    assert (line["floor_pct"], line["floor_pct_where_busy_75"]) == (25.0, 12.5)
