@@ -820,3 +820,319 @@ class TestLogitBias:
                                     logit_bias={first: -100.0}),
             on_output=col)])
         assert first not in col.tokens
+
+
+# ----------------------------------------------------- the seam (PR 29)
+class Pinned(list):
+    """Measurements the pump cannot add to: the look-ahead rule then
+    decides on these numbers, not on the CPU's clock."""
+
+    def append(self, _):
+        pass
+
+
+#: (turn-around s, call s) as one v5e chip gives them: a horizon-8 call of
+#: 93 ms, a one-step call of 11.6 ms, beside a pump that needs 0.5 ms.
+LONG_CALL = (0.0005, 0.093)
+ONE_STEP_CALL = (0.0005, 0.0116)
+
+
+def pin_measurements(engine, turnaround_s, call_s):
+    engine._turnaround_s = Pinned([turnaround_s])
+    engine._call_s = Pinned([call_s])
+
+
+def greedy_req(rid, prompt, n, **kw):
+    return EngineRequest(rid, token_ids=list(prompt),
+                         sampling=SamplingParams(max_tokens=n,
+                                                 temperature=0.0,
+                                                 ignore_eos=True, **kw),
+                         on_output=Collector())
+
+
+def step_until_decoding(engine):
+    """Step until a decode call is on the device queue, unfetched."""
+    for _ in range(50):
+        engine.step()
+        call = engine._pending_decode
+        if call is not None and call.landed is None:
+            return
+    raise AssertionError("no decode call was dispatched")
+
+
+def finish(engine, reqs):
+    for _ in range(2000):
+        if all(r.on_output.done.is_set() for r in reqs):
+            return
+        engine.step()
+    raise AssertionError("requests did not finish")
+
+
+class TestLookAheadRule:
+    @pytest.mark.parametrize("turnaround_s,call_s,ahead", [
+        (*LONG_CALL, False),         # 0.5% of the call: not worth a call
+        (*ONE_STEP_CALL, True),      # 4.3% of it: a bubble worth hiding
+        (0.0, 0.0, False),           # before the first measurement
+        (0.0018, 0.093, False),      # just under the share
+        (0.0019, 0.093, True),       # just over it
+        (0.005, 0.0116, True),
+    ])
+    def test_the_decision_is_a_pure_function(self, turnaround_s, call_s,
+                                             ahead):
+        from xllm_service_tpu.engine.engine import look_ahead_pays
+        assert look_ahead_pays(turnaround_s, call_s) is ahead
+
+    def test_an_engine_without_measurements_does_not_look_ahead(self):
+        engine = make_engine(decode_horizon=8)
+        assert not engine._look_ahead()
+        engine._call_s.append(0.01)          # one of the two is not enough
+        assert not engine._look_ahead()
+
+    def test_stats_say_what_the_rule_measured_and_decided(self):
+        engine = make_engine(decode_horizon=8)
+        assert engine.stats()["look_ahead"] == {
+            "turnaround_ms": 0.0, "call_ms": 0.0, "ahead": False}
+        pin_measurements(engine, *ONE_STEP_CALL)
+        assert engine.stats()["look_ahead"] == {
+            "turnaround_ms": pytest.approx(0.5),
+            "call_ms": pytest.approx(11.6), "ahead": True}
+
+    def test_the_rule_reads_medians_so_one_slow_turnaround_is_not_a_flip(
+            self):
+        engine = make_engine(decode_horizon=8)
+        engine._call_s.extend([0.093] * 9)
+        engine._turnaround_s.extend([0.0005] * 8 + [0.050])
+        assert not engine._look_ahead()
+        engine._turnaround_s.extend([0.050] * 5)
+        assert engine._look_ahead()
+
+    @pytest.mark.parametrize("measured,overlaps", [
+        (ONE_STEP_CALL, True), (LONG_CALL, False)],
+        ids=["one-step-call", "long-call"])
+    def test_dispatch_overlaps_fetch_where_the_rule_says_so(self, measured,
+                                                            overlaps):
+        """decode_horizon=1: with a one-step call's measurements call k+1
+        is dispatched before call k is fetched, as it always was; with a
+        long call's, every call is fetched before the next is dispatched.
+        The tokens are the same."""
+        engine = make_engine(decode_horizon=1)
+        prompt = list(range(10, 30))
+        want = naive_greedy(engine, prompt, 8)
+        pin_measurements(engine, *measured)
+        events = []
+        real_decode, real_fetch = engine._decode_multi, engine._fetch
+
+        def decode_spy(params, d, horizon):
+            events.append("D")
+            return real_decode(params, d, horizon)
+
+        def fetch_spy(arr):
+            if arr.ndim == 3:              # a decode call's [H, B, ...]
+                events.append("F")
+            return real_fetch(arr)
+
+        engine._decode_multi, engine._fetch = decode_spy, fetch_spy
+        req = greedy_req("o", prompt, 8)
+        run_requests(engine, [req])
+        engine.step()
+        assert req.on_output.tokens == want
+        trace = "".join(events)
+        assert ("DD" in trace) is overlaps, trace
+        if not overlaps:
+            assert trace == "DF" * (len(trace) // 2)
+        assert engine._pending_decode is None
+
+
+class TestArrivalAtTheSeam:
+    A, B = list(range(10, 40)), list(range(100, 150))
+
+    def _arrival_during_decode(self, measured):
+        engine = make_engine(decode_horizon=8)
+        pin_measurements(engine, *measured)
+        a, b = greedy_req("a", self.A, 30), greedy_req("b", self.B, 20)
+        engine.submit(a)
+        step_until_decoding(engine)
+        engine.submit(b)                 # arrives while the batch decodes
+        finish(engine, [a, b])
+        return engine, a, b
+
+    @pytest.mark.parametrize("measured,behind", [
+        (LONG_CALL, 0), (ONE_STEP_CALL, 8)], ids=["seam", "look-ahead"])
+    def test_prefill_is_next_on_the_chip(self, measured, behind):
+        """A request that arrives while a batch decodes at horizon 8: with
+        a long call's measurements its prefill is dispatched with no decode
+        call pending; with the look-ahead, behind a whole call."""
+        engine, a, b = self._arrival_during_decode(measured)
+        c = engine.telemetry.counters
+        assert c["admissions"] == 2
+        assert c["prefill_behind_steps"] == behind
+        assert c["decode_calls/8"] >= 3      # whole-horizon calls only
+        # old and new sequence, interleaved: the cache-free reference
+        assert a.on_output.tokens == naive_greedy(engine, self.A, 30)
+        assert b.on_output.tokens == naive_greedy(engine, self.B, 20)
+
+    def test_streams_equal_a_run_that_admitted_both_together(self):
+        engine, a, b = self._arrival_during_decode(LONG_CALL)
+        together = make_engine(decode_horizon=8)
+        a2, b2 = greedy_req("a", self.A, 30), greedy_req("b", self.B, 20)
+        run_requests(together, [a2, b2])
+        assert a.on_output.tokens == a2.on_output.tokens
+        assert b.on_output.tokens == b2.on_output.tokens
+        # one delta per sequence per call, the first token on its own
+        assert [len(o.outputs[0].token_ids) for o in b.on_output.outputs] \
+            == [1, 8, 8, 3]
+
+    def test_landed_tokens_go_out_while_the_prefill_runs(self):
+        """With an admission at the seam the landed call's tokens are
+        emitted after the install is dispatched and before its result is
+        fetched; without one, after the next decode dispatch."""
+        engine = make_engine(decode_horizon=8)
+        pin_measurements(engine, *LONG_CALL)
+        a, b = greedy_req("a", self.A, 30), greedy_req("b", self.B, 4)
+        events = []
+        a_out, real_fetch = a.on_output, engine._fetch
+        real_install = engine._prefill_install_nc
+        real_decode = engine._decode_multi
+
+        def install_spy(*args):
+            events.append("install")
+            return real_install(*args)
+
+        def decode_spy(*args):
+            events.append("decode")
+            return real_decode(*args)
+
+        def fetch_spy(arr):
+            events.append("fetch")
+            return real_fetch(arr)
+
+        def a_spy(out):
+            events.append("emit-a")
+            a_out(out)
+
+        engine.submit(a)
+        step_until_decoding(engine)
+        engine._prefill_install_nc, engine._fetch = install_spy, fetch_spy
+        engine._decode_multi, a.on_output = decode_spy, a_spy
+        engine.submit(b)
+        engine.step()
+        assert events == ["fetch", "install", "emit-a", "fetch", "decode"]
+        del events[:]
+        engine.step()                    # nothing to admit
+        assert events[:3] == ["fetch", "decode", "emit-a"]
+
+    def test_a_full_batch_admits_at_the_seam_its_tokens_free(self):
+        """One slot: the landed call's tokens finish the running request,
+        and the waiting one is admitted at that same seam, not a call
+        later."""
+        engine = make_engine(decode_horizon=8, max_batch_size=1)
+        pin_measurements(engine, *LONG_CALL)
+        a, b = greedy_req("a", self.A, 9), greedy_req("b", self.B, 2)
+        engine.submit(a)
+        step_until_decoding(engine)      # call 1 of a: tokens 2..9
+        engine.submit(b)
+        calls = engine.telemetry.counters["decode_calls/8"]
+        engine.step()
+        assert a.on_output.done.is_set()
+        assert engine.telemetry.counters["admissions"] == 2
+        assert engine.telemetry.counters["prefill_behind_steps"] == 0
+        assert engine.telemetry.counters["decode_calls/8"] == calls
+        finish(engine, [b])
+        assert b.on_output.tokens == naive_greedy(engine, self.B, 2)
+
+    def test_no_device_read_between_picking_a_request_and_its_dispatch(
+            self, monkeypatch):
+        """Admission reads nothing back from the device before its program
+        is on the queue (the sampling key used to be: a round trip behind
+        the running call). Seeded and unseeded requests alike."""
+        from xllm_service_tpu.engine import engine as engine_mod
+
+        engine = make_engine(decode_horizon=8)
+        pin_measurements(engine, *LONG_CALL)
+        events = []
+
+        class NumpySpy:
+            def __getattr__(self, name):
+                return getattr(np, name)
+
+            @staticmethod
+            def asarray(a, *args, **kw):
+                if isinstance(a, jax.Array):
+                    events.append("read")
+                return np.asarray(a, *args, **kw)
+
+        a = greedy_req("a", self.A, 30)
+        engine.submit(a)
+        step_until_decoding(engine)
+        monkeypatch.setattr(engine_mod, "np", NumpySpy())
+        real_pop, real_install = (engine._pop_next_waiting,
+                                  engine._prefill_install_nc)
+
+        def pop_spy():
+            req = real_pop()
+            if req is not None:
+                events.append("pick")
+            return req
+
+        def install_spy(*args):
+            events.append("dispatch")
+            return real_install(*args)
+
+        engine._pop_next_waiting = pop_spy
+        engine._prefill_install_nc = install_spy
+        b = greedy_req("b", self.B, 3)
+        c = EngineRequest("c", token_ids=list(range(60, 90)),
+                          sampling=SamplingParams(max_tokens=3,
+                                                  temperature=0.9, seed=7,
+                                                  ignore_eos=True),
+                          on_output=Collector())
+        engine.submit(b)
+        engine.submit(c)
+        engine.step()
+        assert events.count("pick") == events.count("dispatch") == 2
+        trace = " ".join(events)
+        assert "pick read" not in trace
+        assert trace.startswith("read pick dispatch pick dispatch read")
+
+
+class TestSamplingKeys:
+    @pytest.mark.parametrize("seed", [0, 1, 42, 2**31 - 1, 2**31, 2**32 - 1,
+                                      2**32 + 5, -1, -2**31, 2**63 - 1])
+    def test_a_seed_gives_the_key_bits_of_prngkey(self, seed):
+        from xllm_service_tpu.engine.engine import seed_key_bits
+        got = seed_key_bits(seed)
+        want = np.asarray(jax.random.PRNGKey(seed))
+        assert got.dtype == want.dtype == np.uint32
+        assert got.tolist() == want.tolist()
+
+    def test_the_slot_holds_the_seeds_key_and_seeded_runs_repeat(self):
+        sp = SamplingParams(max_tokens=12, temperature=1.0, seed=1234,
+                            ignore_eos=True)
+        prompt = list(range(50, 80))
+        runs = []
+        for _ in range(2):
+            engine = make_engine(decode_horizon=4)
+            col = Collector()
+            engine.submit(EngineRequest("s", token_ids=prompt, sampling=sp,
+                                        on_output=col))
+            engine.step()
+            (slot,) = engine._running
+            assert np.asarray(engine._dstate["keys"])[slot].tolist() == \
+                np.asarray(jax.random.PRNGKey(1234)).tolist()
+            while not col.done.is_set():
+                engine.step()
+            runs.append(col.tokens)
+        assert runs[0] == runs[1] and len(runs[0]) == 12
+
+    def test_unseeded_keys_come_from_the_engines_own_host_chain(self):
+        """Two engines of one configuration draw the same chain (a
+        multi-host mesh's hosts must), successive requests different
+        keys, and no draw touches the device."""
+        sp = SamplingParams(max_tokens=2, temperature=1.0)
+        e1, e2 = make_engine(), make_engine()
+        k1 = [e1._slot_key_bits(sp) for _ in range(3)]
+        k2 = [e2._slot_key_bits(sp) for _ in range(3)]
+        assert [k.tolist() for k in k1] == [k.tolist() for k in k2]
+        assert len({tuple(k.tolist()) for k in k1}) == 3
+        assert all(type(k) is np.ndarray and k.dtype == np.uint32
+                   for k in k1)

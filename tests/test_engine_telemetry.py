@@ -17,7 +17,8 @@ from xllm_service_tpu.engine.agent import EngineAgent
 from xllm_service_tpu.engine.engine import EngineRequest
 
 from test_e2e_real_engine import _base, cluster  # noqa: F401 (fixture)
-from test_engine import Collector, make_engine, run_requests
+from test_engine import (LONG_CALL, ONE_STEP_CALL, Collector, make_engine,
+                         pin_measurements, run_requests, step_until_decoding)
 
 PROMPT = list(range(1, 71))     # 70 tokens: two whole hash blocks of 32
 
@@ -84,10 +85,11 @@ def test_a_full_match_keeps_one_suffix_token():
 def test_decode_counters_against_a_hand_count():
     """One request, 70-token prompt, 9 tokens out, horizon 4. The first
     token comes from the prefill; calls 1 and 2 are dispatched before call
-    1 is drained (the pipeline runs one ahead), so both see context 70;
-    call 3 sees 74 and is in flight when call 2's tokens finish the
-    request. The per-call samples (the heartbeat's TPOT table) keep the
-    rule they always had: the call's sequences still live when it is
+    1's tokens are emitted (emission runs behind the next dispatch, whether
+    or not the pump looks a call ahead), so both see context 70; call 3
+    sees 74 and is in flight when call 2's tokens finish the request. The
+    per-call samples (the heartbeat's TPOT table) keep the rule they always
+    had: the call's sequences still live when it is
     fetched, with their context then. Call 1 is fetched after the prefill's
     token only (70), call 2 after call 1's four (74), call 3 after the
     request has finished: no sample."""
@@ -134,6 +136,65 @@ def test_decode_counters_follow_the_dispatched_calls():
         == len(seen)
     # a call fetched after its last sequence finished leaves no sample
     assert 0 < len(e.telemetry.decodes) < len(seen)
+
+
+def test_prefill_behind_steps_is_what_was_in_flight_at_the_dispatch():
+    """Nothing runs when the first request is admitted: 0. The second is
+    dispatched behind whatever decode call is unfetched then: a whole call
+    of 4 steps where the pump looks ahead, nothing where it does not."""
+    for measured, behind in ((ONE_STEP_CALL, 4), (LONG_CALL, 0)):
+        e = make_engine(decode_horizon=4)
+        pin_measurements(e, *measured)
+        a, b = _req("a", max_tokens=20), _req("b", list(range(100, 140)))
+        e.submit(a)
+        step_until_decoding(e)
+        assert e.telemetry.counters["prefill_behind_steps"] == 0
+        e.submit(b)
+        e.step()
+        assert e.telemetry.counters["admissions"] == 2
+        assert e.telemetry.counters["prefill_behind_steps"] == behind
+        view = T.summarize([e.telemetry])
+        assert view["total"]["prefill_behind_steps"] == behind
+
+
+def test_turnaround_is_the_admit_and_decode_dispatch_phases():
+    clock = Clock()
+    tel = T.EngineTelemetry(clock)
+    assert tel.turnaround_s() == 0.0
+    with tel.phase("admit"):
+        clock.t += 0.25
+        with tel.phase("prefill_dispatch"):
+            clock.t += 4.0                  # not the pump's turn-around
+    with tel.phase("fetch_wait"):
+        clock.t += 2.0
+    with tel.phase("decode_dispatch"):
+        clock.t += 0.5
+        # read inside a phase: its time so far is brought up to date
+        assert tel.turnaround_s() == pytest.approx(0.75)
+        clock.t += 0.125
+    with tel.phase("emit"):
+        clock.t += 1.0
+    assert tel.turnaround_s() == pytest.approx(0.875)
+    assert sum(v for k, v in tel.counters.items()
+               if k.startswith("host_s/")) == pytest.approx(clock.t)
+
+
+@pytest.mark.parametrize("recent,want", [
+    ({"admissions": 96, "prefill_behind_steps": 768}, 8.0),   # looked ahead
+    ({"admissions": 96, "prefill_behind_steps": 0}, 0.0),     # at the seam
+    ({"admissions": 96, "prefill_behind_steps": 8}, 8 / 96),
+    ({"admissions": 96}, None),     # the parent's program: no such counter
+    ({"admissions": 0, "prefill_behind_steps": 0}, None),     # none admitted
+    (None, None),
+])
+def test_the_benchmarks_reader_of_prefill_behind_steps(recent, want):
+    from chipbench import harness
+
+    _, search = harness.load_bench(harness.ROOT / "BENCHMARK.json")
+    read = harness.load_reader(search, "engine.prefill_behind_steps")
+    stats = {} if recent is None else {"engine_trace": {"recent": recent}}
+    got = read({"agent_stats": stats})
+    assert got == want if want is None else got == pytest.approx(want)
 
 
 def test_blocked_admissions_and_cancellations_are_counted():
@@ -382,11 +443,17 @@ def test_stats_metrics_and_the_prefill_span_carry_the_record(cluster):  # noqa: 
     assert recent["admissions"] <= total["admissions"]
     assert {"queue_ms", "prefill_ms"} <= set(recent)
     assert stats["sarathi_rides"] == total["sarathi_rides"]
+    assert "prefill_behind_steps" in total and "prefill_behind_steps" in recent
+    # the look-ahead rule as it stands, one entry an engine
+    (rule,) = stats["look_ahead"]
+    assert set(rule) == {"turnaround_ms", "call_ms", "ahead"}
+    assert rule["call_ms"] > 0 and isinstance(rule["ahead"], bool)
 
     text = requests.get(f"http://{agent.name}/metrics", timeout=5).text
     for line in ("engine_admissions_total ", "engine_prefix_hit_tokens_total ",
                  "engine_preemptions_total 0", "engine_sarathi_rides_total ",
                  'engine_host_seconds_total{phase="fetch_wait"} ',
+                 "engine_prefill_behind_steps_total ",
                  'engine_decode_calls_total{horizon="',
                  'engine_prefill_calls_total{bucket="'):
         assert "\n" + line in text, line

@@ -983,6 +983,7 @@ class EngineAgent:
             "sarathi_rides": sum(e.telemetry.counters["sarathi_rides"]
                                  for e in self.engines),
             "attention_paths": [s.get("attention_paths", {}) for s in per],
+            "look_ahead": [s.get("look_ahead") for s in per],
         }
 
     async def _h_health(self, req: web.Request) -> web.Response:

@@ -60,13 +60,18 @@ class EngineConfig:
     # dispatch + transfer overhead. Tokens past a stop condition within a
     # horizon are discarded on the host.
     decode_horizon: int = 1
-    # TTFT guard: while requests are WAITING (or a chunked prefill is in
-    # flight), decode calls shrink to this many tokens so admission isn't
-    # blocked behind a long lax.scan — at horizon 32 a full call is
-    # ~0.5 s of device time a new arrival would queue behind. With an
-    # empty queue the full decode_horizon runs (pure-throughput regime,
-    # e.g. bench.py after admission). 0 disables; pow2 (compile variants
-    # already exist).
+    # While requests are WAITING (or a chunked prefill is in flight) when a
+    # decode call is dispatched, the call shrinks to this many tokens so
+    # the next admission is not a whole decode_horizon away. What it can
+    # do: bound the wait of a request that admission could NOT take this
+    # iteration (no free slot, no pages, chunk capacity) and pace a
+    # chunked prefill's rides. What it cannot do: shorten the wait of an
+    # arrival on an instance with room. Admission runs before decode and
+    # empties the queue, so the test finds nothing waiting, and the call
+    # such an arrival waits for was dispatched before it arrived. That
+    # wait is the rest of the running call (the pump no longer queues a
+    # second call behind it: `look_ahead_pays`, engine.py). 0 disables;
+    # pow2 (compile variants already exist).
     admission_horizon: int = 8
     # Pre-compile every power-of-two decode horizon (and the spec-verify
     # program) at engine start. The budget-bounded horizon's first use of

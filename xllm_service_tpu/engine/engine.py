@@ -18,14 +18,19 @@ become Pallas/XLA"). Design points for XLA:
 - **Prefix cache**: longest block-aligned cached prefix is reused (pages
   shared, suffix-only prefill); completed blocks are donated back and
   reported as KvCacheEvents (feeds cluster-wide cache-aware routing).
-- **Pipelined loop**: decode/spec round N+1 dispatches before round N's
-  results are fetched (host emit hides behind device compute; snapshot
+- **Pipelined loop**: decode round N's tokens are emitted behind round
+  N+1's dispatch (host emit hides behind device compute; snapshot
   ownership guards slot reuse), and a burst of arrivals dispatches every
   prefill install into the device queue before fetching any result.
+  Round N's result is *fetched* before round N+1 is dispatched wherever
+  a call is long against the pump's turn-around (`look_ahead_pays`): an
+  arrival's prefill is then the next thing the chip runs, not queued
+  behind a call dispatched before the request existed. One-step calls
+  and spec rounds dispatch N+1 before fetching N.
 - **Per-slot budgets on device**: a slot freezes at its max_total_len
   like a stop-token hit, so the batch horizon follows the LONGEST
-  remaining budget; while requests wait, calls shrink to
-  admission_horizon (TTFT guard), full decode_horizon when idle.
+  remaining budget; while requests stay queued past an admission pass,
+  calls shrink to admission_horizon, full decode_horizon otherwise.
 - Inactive batch slots write K/V to the reserved garbage page 0; a dead
   slot's device page-table row is cleared before its pages are recycled.
 """
@@ -34,6 +39,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import statistics
 import threading
 import time
 from collections import deque
@@ -76,6 +82,50 @@ logger = get_logger(__name__)
 # device for mid-horizon deactivation. Longer lists still work — the host
 # stop check covers the rest; the device just can't freeze the slot early.
 NUM_STOP_IDS = 4
+
+# The pump dispatches decode call k+1 before it fetches call k (one call of
+# look-ahead) only where its own turn-around between two calls (result
+# landed -> next decode program dispatched) is more than this share of a
+# call's time. The look-ahead hides the seam between two calls from the
+# chip, and costs every arrival one whole call: its prefill queues behind a
+# call that was dispatched before the request existed. Chosen on one v5e
+# chip (PERF.md section 6, PR 29): the seam the chip sees is ~1.3 ms a call,
+# of which the pump can measure only its own part, 0.46-0.56 ms (the rest
+# passes inside the runtime, between the program's end and the fetch's
+# return). At horizon 8 a call is 93 ms: 1.4% of the chip against 93 ms of
+# every first token, and the pump reads 0.5-0.6%: no look-ahead. At one
+# step a call, 11.6 ms, the same seam is 11% of the chip against 12 ms, and
+# the pump reads 4-5%: look ahead. 2% puts the change-over at calls of ~25
+# ms and leaves both sides a factor of two or more (at 1% a traced run's
+# slower pump crossed it on 5 of 89 admissions).
+LOOK_AHEAD_TURNAROUND_SHARE = 0.02
+# Medians of this many samples feed the rule: one slow turn-around (a GC
+# pause, a descheduled pump) does not flip it.
+_LOOK_AHEAD_SAMPLES = 9
+
+
+def look_ahead_pays(turnaround_s: float, call_s: float) -> bool:
+    """Whether to dispatch the next decode call before fetching the one
+    that is running. Before the first measurement (0, 0): no."""
+    return turnaround_s > LOOK_AHEAD_TURNAROUND_SHARE * call_s
+
+
+def seed_key_bits(seed: int) -> np.ndarray:
+    """The key data of `jax.random.PRNGKey(seed)` (threefry, uint32[2],
+    32-bit mode: the seed's low word under a zero), computed on the host:
+    making the key on the device and reading it back is a round trip
+    behind whatever the chip is running."""
+    return np.asarray([0, seed & 0xFFFFFFFF], np.uint32)
+
+
+@dataclass
+class _DecodeCall:
+    """A dispatched `decode_multi` call whose tokens are not emitted yet."""
+    packed: jax.Array             # [H, B, 2+2K], on the device
+    t0: float                     # time.monotonic() at dispatch
+    horizon: int
+    snapshot: dict                # {slot: _Sequence} it was dispatched for
+    landed: Optional[np.ndarray] = None   # `packed` on the host, once read
 
 
 @dataclass
@@ -346,7 +396,9 @@ class InferenceEngine:
         # Which attention path each traced program took (ops/attention.py
         # `note_path`): {program: {op: path}}, filled at trace time.
         self._paths: dict[str, dict[str, str]] = {}
-        self._rng = jax.random.PRNGKey(cfg.seed + 1)
+        # Sampling keys of requests that bring no seed: a host-side chain
+        # (no device work, so nothing to wait for at admission).
+        self._rng = np.random.default_rng(cfg.seed + 1)
 
         self._waiting: deque[EngineRequest] = deque()
         self._running: dict[int, _Sequence] = {}
@@ -374,13 +426,21 @@ class InferenceEngine:
         # per-decode-call samples (the agent fits its SLO profiling tables
         # and `/stats`.ttft_spans from them): engine/telemetry.py.
         self.telemetry = EngineTelemetry()
-        # Async decode pipeline: the last dispatched decode whose results
-        # have not been fetched yet — (packed, t_dispatch, horizon,
-        # {slot: seq} snapshot). Host-side output processing of step N
-        # overlaps the device executing step N+1. The speculative path
-        # keeps its own pending slot with the same discipline.
-        self._pending_decode: Optional[tuple] = None
+        # Decode pipeline: the last dispatched decode call whose tokens
+        # have not been emitted yet. Host-side output processing of call
+        # k overlaps the device executing call k+1 either way; whether
+        # call k+1 is dispatched before call k's result is *fetched* (one
+        # call of look-ahead) or right after it lands is `_look_ahead`'s
+        # decision. The speculative path keeps its own pending slot and
+        # always looks ahead: (packed, t_dispatch, cycles, snapshot, n).
+        self._pending_decode: Optional[_DecodeCall] = None
         self._pending_spec: Optional[tuple] = None
+        # The look-ahead rule's inputs, measured by the pump itself: its
+        # turn-around before a decode dispatch (steps that admit nothing)
+        # and the time each fetched call had the chip.
+        self._turnaround_s: deque[float] = deque(maxlen=_LOOK_AHEAD_SAMPLES)
+        self._call_s: deque[float] = deque(maxlen=_LOOK_AHEAD_SAMPLES)
+        self._t_landed = 0.0          # time.monotonic() of the last fetch
         # Sarathi mixed decode+chunk steps (XLLM_SARATHI=0 disables for
         # A/B; the path additionally requires prefill_chunk_tokens > 0
         # and a family mixed program — see _ride_chunk_args).
@@ -1157,8 +1217,6 @@ class InferenceEngine:
             self._fetch(packed)
             if clear:
                 self._dstate = self._clear_slot(self._dstate, 0)
-        # The admission path's host-side RNG split is its own compile.
-        self._rng, _ = jax.random.split(self._rng)
         logger.info("program warmup: %d programs (%d horizons, %d prefill "
                     "buckets) compiled in %.1fs by %d workers, run in %.1fs",
                     len(calls), self.cfg.decode_horizon.bit_length(),
@@ -1230,6 +1288,12 @@ class InferenceEngine:
                 "attention_paths": {k: dict(v)
                                     for k, v in self._paths.items()},
             }
+        # The look-ahead rule as it stands: its two measured inputs and
+        # what it decides on them.
+        turnaround_s, call_s = self._look_ahead_inputs()
+        out["look_ahead"] = {"turnaround_ms": turnaround_s * 1000,
+                             "call_ms": call_s * 1000,
+                             "ahead": self._look_ahead()}
         if self.tier_store is not None:
             out["kv_tier"] = self.tier_store.stats()
         return out
@@ -1506,13 +1570,25 @@ class InferenceEngine:
         return np.asarray(arr)
 
     def step(self) -> bool:
-        """One engine iteration: process cancellations, admit (short
-        prompts are never stuck behind an in-flight long prefill), advance
-        one chunk of one in-flight chunked prefill (round-robin), decode
-        one horizon. Chunked prefill keeps long-prompt admission from
-        stalling running decodes."""
+        """One engine iteration: land the running decode call's result
+        (unless the pump looks a call ahead), process cancellations, admit
+        (short prompts are never stuck behind an in-flight long prefill),
+        decode one horizon, advance one chunk of one in-flight chunked
+        prefill (round-robin). Chunked prefill keeps long-prompt admission
+        from stalling running decodes.
+
+        At the seam between two decode calls nothing is queued behind the
+        running one, so what admission dispatches is the next thing the
+        chip runs. A landed call's tokens are emitted after the next
+        program is on the device queue: after the next decode dispatch, or,
+        with admissions, while their prefills run (`_admit`)."""
         tel = self.telemetry
         tel.tick()
+        call = self._pending_decode
+        if (call is not None and call.landed is None
+                and not self._look_ahead()):
+            self._land_decode(call)
+        turnaround_from = tel.turnaround_s()
         with tel.phase("admit"):
             self._process_cancellations()
             worked = self._admit()
@@ -1523,11 +1599,31 @@ class InferenceEngine:
         # standalone chunk program run.
         self._rode_chunk = False
         with tel.phase("decode_dispatch"):
-            decoded = self._decode()
+            # A step that admits nothing spends its time up to the decode
+            # dispatch on the pump's turn-around alone: a sample for
+            # `_look_ahead`.
+            decoded = self._decode(
+                None if worked or self._prefillings else turnaround_from)
         if self._prefillings and not self._rode_chunk:
             with tel.phase("prefill_dispatch"):
                 worked = self._advance_prefill() or worked
         return worked or decoded
+
+    def _look_ahead(self) -> bool:
+        """Dispatch decode call k+1 before fetching call k? The pump's own
+        measurements decide (`look_ahead_pays`). A multi-host mesh always
+        does: its hosts run one program sequence in lockstep, which no
+        wall-clock decision may enter (multihost_driver.py)."""
+        return jax.process_count() > 1 or look_ahead_pays(
+            *self._look_ahead_inputs())
+
+    def _look_ahead_inputs(self) -> tuple[float, float]:
+        """(turn-around s, call s): the medians of the pump's last
+        measurements of each; (0, 0) until it has both."""
+        if not self._turnaround_s or not self._call_s:
+            return 0.0, 0.0
+        return (statistics.median(self._turnaround_s),
+                statistics.median(self._call_s))
 
     def _process_cancellations(self) -> None:
         with self._lock:
@@ -1598,6 +1694,10 @@ class InferenceEngine:
                         self._waiting.appendleft(r)
 
         def _complete_batch():
+            if batch:
+                # The installs are on the device queue: the landed decode
+                # call's tokens go out while they run.
+                self._emit_landed()
             while batch:
                 entry = batch.pop(0)
                 try:
@@ -1614,16 +1714,17 @@ class InferenceEngine:
         try:
             while True:
                 with self._lock:
-                    if not self._free_slots:
-                        if self._waiting:
-                            self.telemetry.count_by("admissions_blocked",
-                                                    "no_slot")
-                        _requeue_deferred()
-                        return admitted
-                    req = self._pop_next_waiting()
-                    if req is None:
-                        _requeue_deferred()
-                        return admitted
+                    req = (self._pop_next_waiting() if self._free_slots
+                           else None)
+                    blocked = req is None and bool(self._waiting)
+                if blocked and self._emit_landed():
+                    continue    # its tokens may have finished a sequence
+                if req is None:
+                    if blocked:
+                        self.telemetry.count_by("admissions_blocked",
+                                                "no_slot")
+                    _requeue_deferred()
+                    return admitted
                 # Chunk-capacity gate (conservative: ignores a possible
                 # prefix cache hit): a long prompt that would need chunking
                 # waits its turn — but SKIP it rather than stop, so short
@@ -1660,8 +1761,14 @@ class InferenceEngine:
                         _requeue_deferred()
                         raise
                 if not self._start_sequence(req, batch=batch):
-                    # Not enough KV pages. An online request may preempt a
-                    # running offline sequence to make room.
+                    # Not enough KV pages. A landed call's tokens may
+                    # finish a sequence and return its pages; an online
+                    # request may preempt a running offline sequence to
+                    # make room.
+                    if self._emit_landed() and self._start_sequence(
+                            req, batch=batch):
+                        admitted = True
+                        continue
                     if not req.offline and self._preempt_one_offline():
                         if self._start_sequence(req, batch=batch):
                             admitted = True
@@ -2199,12 +2306,10 @@ class InferenceEngine:
                 .astype(np.int32)
         else:
             counts_row = np.zeros((0,), np.int32)
-        self._rng, slot_key = jax.random.split(self._rng)
-        if sp.seed is not None:
-            slot_key = jax.random.PRNGKey(sp.seed)
         self._dstate = self._inject_install(
             self._dstate, jnp.asarray(blob), jnp.asarray(ints),
-            jnp.asarray(floats), jnp.asarray(counts_row), slot_key)
+            jnp.asarray(floats), jnp.asarray(counts_row),
+            jnp.asarray(self._slot_key_bits(sp)))
 
         # Donate the transferred prompt blocks to the local prefix cache.
         stored, donated = self.page_mgr.store_prefix(prompt,
@@ -2304,6 +2409,21 @@ class InferenceEngine:
         ids += [-1] * (NUM_STOP_IDS - len(ids))
         return np.asarray(ids, np.int32)
 
+    def _slot_key_bits(self, sp: SamplingParams) -> np.ndarray:
+        """The slot's sampling key (uint32[2]): the request's seed as
+        `jax.random.PRNGKey` would make it, else the engine's own chain.
+        Drawn on the host either way."""
+        if sp.seed is not None:
+            return seed_key_bits(sp.seed)
+        return self._rng.integers(0, 1 << 32, size=2, dtype=np.uint32)
+
+    def _steps_in_flight(self) -> int:
+        """Decode steps dispatched and not yet fetched: what the chip runs
+        before it reaches a program dispatched now."""
+        call, spec = self._pending_decode, self._pending_spec
+        return ((call.horizon if call and call.landed is None else 0)
+                + (spec[2] if spec else 0))
+
     def _dispatch_prefill_install(self, seq: _Sequence, prompt: list[int],
                                   matched: int) -> jax.Array:
         """Dispatch the prefill+install program WITHOUT fetching its
@@ -2359,13 +2479,9 @@ class InferenceEngine:
                 .astype(np.int32)
         else:
             counts_row = np.zeros((0,), np.int32)
-        self._rng, slot_key = jax.random.split(self._rng)
-        if sp.seed is not None:
-            slot_key = jax.random.PRNGKey(sp.seed)
-        # The split runs on the device, behind whatever is in flight there:
-        # reading the key back is a wait for the chip, not host work.
-        with self.telemetry.phase("fetch_wait"):
-            key_bits = np.asarray(slot_key).view(np.int32).reshape(-1)[:2]
+        key_bits = self._slot_key_bits(sp).view(np.int32)
+        self.telemetry.counters["prefill_behind_steps"] += \
+            self._steps_in_flight()
 
         # Visual embeddings for THIS suffix only (earlier chunks consumed
         # their own slices); padded to a bucket (4 images' worth) so a new
@@ -2405,7 +2521,10 @@ class InferenceEngine:
         return token, lp
 
     # -------------------------------------------------------------- decode
-    def _decode(self) -> bool:
+    def _decode(self, turnaround_from: Optional[float] = None) -> bool:
+        """Dispatch one decode call, then emit the previous call's tokens
+        behind it. `turnaround_from`: `telemetry.turnaround_s()` when the
+        step began, where the time since is to be sampled."""
         if not self._running:
             # No live batch: flush the tail of either pipeline.
             drained = self._drain_pending_decode()
@@ -2448,15 +2567,20 @@ class InferenceEngine:
         else:
             self._dstate, packed = self._decode_multi(
                 self.params, self._dstate, horizon)
+        if turnaround_from is not None:
+            self._turnaround_s.append(
+                self.telemetry.turnaround_s() - turnaround_from)
         # Pipeline: enqueue this step, then process the PREVIOUS step's
         # outputs while the device executes this one. Token emission (incl.
         # detokenize + callbacks, real host cost per horizon) is thereby
         # hidden behind device compute instead of serializing with it.
+        # (The previous call has landed already unless the pump looks a
+        # call ahead; then its fetch blocks here, behind this dispatch.)
         snapshot = {slot: seq for slot, seq in self._running.items()
                     if not seq.finished}
         self._count_decode_call(horizon, horizon, snapshot)
-        prev, self._pending_decode = (self._pending_decode,
-                                      (packed, t0, horizon, snapshot))
+        prev, self._pending_decode = (self._pending_decode, _DecodeCall(
+            packed, t0, horizon, snapshot))
         if prev is not None:
             with self.telemetry.phase("emit"):
                 self._drain_one_decode(prev)
@@ -2492,16 +2616,33 @@ class InferenceEngine:
             self._drain_one_decode(pend)
         return True
 
-    def _drain_one_decode(self, pend: tuple) -> None:
-        packed, t0, horizon, snapshot = pend
-        K = self.cfg.max_top_logprobs
+    def _emit_landed(self) -> bool:
+        """Emit the pending decode call's tokens if its result has landed
+        (a call the pump looked ahead of has not: it stays pending)."""
+        call = self._pending_decode
+        return (call is not None and call.landed is not None
+                and self._drain_pending_decode())
+
+    def _land_decode(self, call: _DecodeCall) -> None:
+        """Wait for the call's result and sample it; `_drain_one_decode`
+        emits its tokens."""
         with self.telemetry.phase("fetch_wait"):
-            packed_np = self._fetch(packed)   # [H, B, 2+2K]
-        elapsed = time.monotonic() - t0
-        ms_per_tok = elapsed * 1000 / max(1, horizon)
+            call.landed = self._fetch(call.packed)   # [H, B, 2+2K]
+        now = time.monotonic()
+        # The chip ran it from its dispatch, or from when the call before
+        # it landed if it was queued behind that one.
+        self._call_s.append(now - max(call.t0, self._t_landed))
+        self._t_landed = now
+        ms_per_tok = (now - call.t0) * 1000 / max(1, call.horizon)
         with self._telemetry_lock:
             self.recent_max_tbt_ms = max(self.recent_max_tbt_ms, ms_per_tok)
-        self._sample_decode_call(horizon, snapshot, ms_per_tok)
+        self._sample_decode_call(call.horizon, call.snapshot, ms_per_tok)
+
+    def _drain_one_decode(self, call: _DecodeCall) -> None:
+        if call.landed is None:
+            self._land_decode(call)
+        packed_np, snapshot = call.landed, call.snapshot
+        K = self.cfg.max_top_logprobs
 
         H = packed_np.shape[0]
         for slot, seq in snapshot.items():

@@ -21,7 +21,11 @@ horizon, reason or phase); `summarize` nests them for output:
               prefill_padded_tokens (bucket - suffix),
               admissions_blocked/<no_slot|no_pages> (loop iterations that
               left a waiting request unadmitted), preemptions, cancelled,
-              finished
+              finished, prefill_behind_steps (decode steps dispatched and
+              not yet fetched when an admission's install program was
+              dispatched, summed over admissions: what the chip runs
+              before it reaches the prefill; 0 where the pump does not
+              look a decode call ahead)
   decode      decode_calls/<horizon|spec>, decode_steps (sum of horizons),
               live_slot_steps (live x horizon), context_token_steps (sum of
               the live sequences' `context_len` at dispatch x horizon: the
@@ -67,8 +71,9 @@ RING = 512          # samples kept per ring
 _SCALARS = (
     "admissions", "prompt_tokens", "prefix_hit_tokens",
     "prefix_onload_tokens", "prefill_padded_tokens", "preemptions",
-    "cancelled", "finished", "decode_steps", "live_slot_steps",
-    "context_token_steps", "sarathi_rides", "pages_reserved_steps")
+    "cancelled", "finished", "prefill_behind_steps", "decode_steps",
+    "live_slot_steps", "context_token_steps", "sarathi_rides",
+    "pages_reserved_steps")
 
 
 class AdmissionSample(NamedTuple):
@@ -139,6 +144,15 @@ class EngineTelemetry:
         self.counters["host_s/" + prev] += now - self._t_phase
         self._phase, self._t_phase = name, now
         return prev
+
+    def turnaround_s(self) -> float:
+        """Seconds the pump has spent so far in the phases that stand
+        between a landed decode result and the next decode dispatch (admit,
+        decode_dispatch), the running phase brought up to date. The engine
+        differences it around a dispatch: its look-ahead rule's input."""
+        self.switch(self._phase)
+        c = self.counters
+        return c["host_s/admit"] + c["host_s/decode_dispatch"]
 
     def tick(self) -> None:
         """Once per loop iteration: keeps a copy of the counters every
