@@ -228,20 +228,6 @@ def kernel_arms(devices):
     yield "page_gather_l32", mover("gather")
     yield "page_scatter_l32", mover("scatter")
 
-    def mq(s_q):
-        from xllm_service_tpu.ops.pallas_mq_paged_attention import (
-            mq_paged_attention_pallas)
-
-        def thunk():
-            pool = f((2048, n_kv, ps, hd), bf16)
-            return jax.jit(mq_paged_attention_pallas).lower(
-                f((16, s_q, n_q, hd), bf16), pool, pool,
-                f((16, 128), i32), f((16,), i32), f((16,), i32)).compile()
-        return thunk
-
-    yield "mq_verify_k4", mq(5)
-    yield "mq_prefill_s128", mq(128)
-
     def cp_partial():
         from xllm_service_tpu.ops.cp_paged_attention import (
             _paged_partial_pallas)

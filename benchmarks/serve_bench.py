@@ -2,8 +2,8 @@
 real HTTP path (client → master → engine agent → TPU → SSE back).
 
 This measures the BASELINE.json north-star metrics ("req/s + p50/p99 TTFT")
-on whatever accelerator is attached; `bench.py` (repo root) remains the
-driver's single-line engine-throughput metric.
+on whatever accelerator is attached; the repo's benchmark is
+`chipbench/run.py` (BENCHMARK.json).
 
 Default is --stack multiproc: coordination server, master and engine
 agent each run as their OWN process, exactly like a real deployment.
@@ -145,7 +145,6 @@ def drive(base: str, stats_url: str, args, vocab: int) -> dict:
     }
     if getattr(args, "prefill_chunk", 0) > 0:
         report["prefill_chunk"] = args.prefill_chunk
-        report["sarathi"] = os.environ.get("XLLM_SARATHI", "1") != "0"
 
     # TTFT span breakdown (name where the time goes).
     # client TTFT = master+wire + agent span; agent span = engine queue +
@@ -360,8 +359,7 @@ def main() -> None:
                          "old single-interpreter stack")
     ap.add_argument("--prefill-chunk", type=int, default=0,
                     help="engine chunked-prefill tokens (0 = whole-suffix "
-                         "installs); chunks ride decode steps unless "
-                         "XLLM_SARATHI=0")
+                         "installs); chunks ride decode steps")
     args = ap.parse_args()
 
     # A CPU run is one that was asked for; without the request the bench

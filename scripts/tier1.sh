@@ -29,7 +29,7 @@ CHUNK1_PATTERNS=(
     test_hf_parity test_loader test_quant test_mrope test_speculative
     test_sarathi test_seq_parallel test_pipeline test_tp_serving
     test_moe_pd test_checkpoint_serving test_pallas_attention
-    test_mq_paged_attention test_cp_paged_attention test_chip_compile
+    test_cp_paged_attention test_chip_compile
     test_bringup
 )
 CHUNK2_PATTERNS=(

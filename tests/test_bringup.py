@@ -2,7 +2,7 @@
 
 - the compile cache is placed from outside (JAX_COMPILATION_CACHE_DIR) or
   at one fixed path in the checkout;
-- `chip_smoke.py` and `bench.py` refuse to run without the chip;
+- `chip_smoke.py` refuses to run without the chip;
 - the trace-time kernel-or-XLA choice is on record.
 """
 
@@ -66,17 +66,13 @@ class TestCompileCachePlacement:
         assert out.stdout.strip() == utils.DEFAULT_COMPILE_CACHE
 
 
-@pytest.mark.parametrize("script,forbidden", [
-    ("chip_smoke.py", '"ok": true'),
-    ("bench.py", "decode_tokens_per_sec_per_chip"),
-])
-def test_chip_scripts_refuse_the_cpu(script, forbidden):
-    """Under JAX_PLATFORMS=cpu they exit non-zero and print no result."""
-    r = subprocess.run([sys.executable, script], cwd=REPO,
+def test_chip_smoke_refuses_the_cpu():
+    """Under JAX_PLATFORMS=cpu it exits non-zero and prints no result."""
+    r = subprocess.run([sys.executable, "chip_smoke.py"], cwd=REPO,
                        env=dict(os.environ, JAX_PLATFORMS="cpu"),
                        capture_output=True, text=True, timeout=120)
     assert r.returncode != 0
-    assert forbidden not in r.stdout
+    assert '"ok": true' not in r.stdout
 
 
 def test_master_and_actuator_stay_off_jax():
@@ -153,3 +149,84 @@ class TestAttentionPathRecord:
             "paged_attention": "xla (cpu backend)"}
         assert paths["prefill_install"] == {
             "prefill_attention": "xla-dense"}
+
+    def test_one_context_routes_ring_and_context_parallel(self):
+        """`trace_program` alone carries what the dispatchers need: the
+        ring field sends `prefill_attention` round the seq axis, a mesh
+        with a seq axis sends `paged_attention` through the CP op."""
+        from xllm_service_tpu.parallel.mesh import MeshConfig, build_mesh
+
+        mesh = build_mesh(MeshConfig(seq=2), devices=jax.devices()[:2])
+        rng = np.random.default_rng(3)
+        q, k, v = (jnp.asarray(rng.normal(size=(1, 32, n, 128)), jnp.float32)
+                   for n in (4, 2, 2))
+        zero, full = jnp.zeros((1,), jnp.int32), jnp.full((1,), 32, jnp.int32)
+        want = attention.prefill_attention(q, k, v, None, None, None,
+                                           zero, full)
+        rec = {}
+
+        def prefill(q, k, v):
+            with attention.trace_program("prefill_sp", rec, mesh, ring=True):
+                return attention.prefill_attention(q, k, v, None, None, None,
+                                                   zero, full)
+
+        np.testing.assert_allclose(jax.jit(prefill)(q, k, v), want,
+                                   rtol=2e-5, atol=2e-5)
+        qd, pool, layer, pt, lens = _paged_case(seed=2)
+
+        def decode(q, pool, pt, lens):
+            with attention.trace_program("decode", rec, mesh):
+                return attention.paged_attention(q, pool, layer, pt, lens)
+
+        np.testing.assert_allclose(
+            jax.jit(decode)(qd, pool, pt, lens),
+            attention.paged_attention_xla(qd, pool, layer, pt, lens),
+            rtol=2e-5, atol=2e-5)
+        assert rec == {"prefill_sp": {"prefill_attention": "ring"},
+                       "decode": {"paged_attention": "cp-xla-dense (seq)"}}
+
+
+_TILING = "xla (shape outside the kernel's tiling: "
+
+
+@pytest.mark.parametrize("backend,interpret,hd,heads,kv,dtype,tp,cp,path", [
+    ("tpu", False, 128, 28, 4, "bfloat16", 1, False, "pallas"),
+    ("tpu", False, 128, 16, 2, "float32", 1, False, "pallas"),
+    ("tpu", False, 128, 28, 4, "bfloat16", 2, False,
+     "pallas (shard_map model=2)"),
+    ("tpu", False, 128, 28, 4, "bfloat16", 4, False,
+     "pallas (shard_map model=4)"),
+    ("tpu", False, 128, 16, 2, "bfloat16", 2, False,
+     "pallas (shard_map model=2)"),
+    ("tpu", False, 128, 16, 2, "bfloat16", 4, False,
+     "xla (kv heads 2 do not divide over tp=4)"),
+    ("tpu", False, 64, 16, 2, "bfloat16", 1, False,
+     _TILING + "hd=64 heads=16/2 dtype=bfloat16)"),
+    ("tpu", False, 576, 28, 4, "bfloat16", 4, False,
+     _TILING + "hd=576 heads=28/4 dtype=bfloat16)"),
+    ("tpu", False, 128, 4, 3, "bfloat16", 1, False,
+     _TILING + "hd=128 heads=4/3 dtype=bfloat16)"),
+    ("tpu", False, 128, 28, 4, "float16", 1, False,
+     _TILING + "hd=128 heads=28/4 dtype=float16)"),
+    ("cpu", False, 128, 28, 4, "bfloat16", 4, False, "xla (cpu backend)"),
+    ("cpu", False, 576, 4, 3, "float32", 1, False, "xla (cpu backend)"),
+    ("cpu", True, 128, 28, 4, "bfloat16", 4, False,
+     "pallas (shard_map model=4)"),
+    ("cpu", True, 128, 16, 2, "float32", 4, False,
+     "xla (kv heads 2 do not divide over tp=4)"),
+    ("cpu", True, 64, 16, 2, "float32", 1, False, "xla (cpu backend)"),
+    ("tpu", False, 128, 28, 4, "bfloat16", 1, True, "cp-pallas (seq)"),
+    ("tpu", False, 128, 16, 2, "bfloat16", 4, True, "cp-pallas (seq)"),
+    ("tpu", False, 64, 16, 2, "bfloat16", 1, True, "cp-xla-dense (seq)"),
+    ("cpu", False, 128, 28, 4, "float32", 1, True, "cp-xla-dense (seq)"),
+    ("cpu", True, 128, 28, 4, "float32", 2, True, "cp-pallas (seq)"),
+    ("cpu", True, 128, 4, 3, "float32", 1, True, "cp-xla-dense (seq)"),
+])
+def test_attention_path_names_the_recorded_string(
+        backend, interpret, hd, heads, kv, dtype, tp, cp, path):
+    """The one decision over what the code observes, and the exact string
+    `/stats`.attention_paths carries (chipbench's `decode_paths` check
+    and chip_smoke.py compare against it)."""
+    assert attention.attention_path(
+        backend, interpret, hd, heads, kv, dtype, tp,
+        context_parallel=cp) == path

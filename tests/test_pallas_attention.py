@@ -76,6 +76,8 @@ class TestPallasPagedAttention:
         [96, 96, 96, 96],          # full pages
         [1, 17, 33, 90],           # ragged, partial pages
         [5, 96, 0, 50],            # includes an inactive row (ctx 0)
+        [0, 0, 0, 7],              # leading empty rows
+        [64, 0, 0, 0],             # trailing empty rows
     ])
     def test_matches_xla(self, context_lens):
         q, pool, pt = _setup()
@@ -89,45 +91,40 @@ class TestPallasPagedAttention:
                                            np.asarray(ref[b]),
                                            rtol=2e-5, atol=2e-5)
 
-    @pytest.mark.parametrize("context_lens", [
-        [96, 96, 96, 96],          # full pages, even chunk counts
-        [1, 17, 33, 90],           # ragged: odd chunk counts -> pad chunk
-        [5, 96, 0, 50],            # empty row mid-batch: pipeline forward
-        [0, 0, 0, 7],              # leading empty rows
-        [64, 0, 0, 0],             # trailing empty rows
-    ])
-    def test_cross_row_pipeline_matches_xla(self, context_lens,
-                                            monkeypatch):
-        """XLLM_PAGE_PIPELINE=row: rows prefetch each other's first chunk
-        (see _kernel) — numerics must be identical across empty rows, odd
-        chunk counts, and row boundaries."""
-        monkeypatch.setenv("XLLM_PAGE_PIPELINE", "row")
-        monkeypatch.setenv("XLLM_PAGE_CHUNK", "1")   # maximize row turns
+    @pytest.mark.parametrize("chunk", ["1", "16"])
+    def test_page_chunk_override_matches_xla(self, chunk, monkeypatch):
+        """XLLM_PAGE_CHUNK (the arm PR 30's A/B kept): one page a chunk
+        maximises chunk turns and slot swaps, 16 is clamped to the
+        table's 6 pages."""
+        monkeypatch.setenv("XLLM_PAGE_CHUNK", chunk)
         q, pool, pt = _setup()
+        context_lens = [1, 17, 33, 90]
         cl = jnp.asarray(context_lens, jnp.int32)
-        ref = paged_attention_xla(q, pool, 2, pt, cl)
-        got = _kernel(q, pool, 2, pt, cl)
-        for b, c in enumerate(context_lens):
-            if c > 0:
-                np.testing.assert_allclose(np.asarray(got[b]),
-                                           np.asarray(ref[b]),
-                                           rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(
+            np.asarray(_kernel(q, pool, 2, pt, cl)),
+            np.asarray(paged_attention_xla(q, pool, 2, pt, cl)),
+            rtol=2e-5, atol=2e-5)
 
     def test_span_bucketed_xla_gather_parity(self, monkeypatch):
-        """XLLM_XLA_SPAN_BUCKETS=1 forces the pow2 span ladder the
-        accelerator backend uses (the CPU suite default keeps the single
-        full-span branch for compile time): every ladder rung must match
-        the full-span gather, including at occupancies that select the
+        """The pow2 span ladder the accelerator backend uses (the CPU
+        suite keeps the single full-span branch for compile time), steered
+        through the `_backend` hook: every ladder rung must match the
+        full-span gather, including at occupancies that select the
         shortest span."""
+        from xllm_service_tpu.ops import attention
+
         q, pool, pt = _setup()
         for cls in ([8, 12, 4, 16],              # shortest span
                     [40, 41, 33, 50],            # middle rung
                     [96, 96, 96, 96]):           # full span
             cl = jnp.asarray(cls, jnp.int32)
-            monkeypatch.setenv("XLLM_XLA_SPAN_BUCKETS", "0")
             ref = paged_attention_xla(q, pool, 0, pt, cl)
-            monkeypatch.setenv("XLLM_XLA_SPAN_BUCKETS", "1")
-            got = paged_attention_xla(q, pool, 0, pt, cl)
+            with monkeypatch.context() as m:
+                m.setattr(attention, "_backend", lambda: "tpu")
+                hlo = jax.jit(paged_attention_xla, static_argnums=2).lower(
+                    q, pool, 0, pt, cl).as_text()
+                got = paged_attention_xla(q, pool, 0, pt, cl)
+            assert "case" in hlo          # the ladder's switch is traced
             np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                        rtol=2e-6, atol=2e-6)
 
@@ -201,28 +198,6 @@ class TestPallasPagedAttention:
                                                   layer, pt, cl, **opts)
             np.testing.assert_allclose(np.asarray(got), want,
                                        rtol=2e-4, atol=2e-4)
-            np.testing.assert_array_equal(np.asarray(got_pool), want_pool)
-
-    def test_decode_step_rowpipe(self, monkeypatch):
-        """The append + the kernel's cross-row pipelining: same parity
-        contract across empty contexts, page edges, and odd chunk counts
-        (rows that hold nothing but the token just written)."""
-        monkeypatch.setenv("XLLM_PALLAS_INTERPRET", "1")
-        monkeypatch.setenv("XLLM_PAGE_PIPELINE", "row")
-        monkeypatch.setenv("XLLM_PAGE_CHUNK", "1")   # maximize row turns
-        q, pool, pt = _setup()
-        B, n_kv, hd = 4, 4, 128
-        for prev in ([10, 20, 30, 40], [0, 16, 31, 95], [0, 0, 0, 0],
-                     [50, 0, 0, 12]):
-            cl = jnp.asarray(prev, jnp.int32) + 1
-            k_new = jax.random.normal(jax.random.PRNGKey(9), (B, n_kv, hd))
-            v_new = jax.random.normal(jax.random.PRNGKey(10), (B, n_kv, hd))
-            want_pool = _per_layer_append(pool, 1, k_new, v_new, pt, prev)
-            ref = paged_attention_xla(q, jnp.asarray(want_pool), 1, pt, cl)
-            got, got_pool = decode_attention_step(q, k_new, v_new, pool, 1,
-                                                  pt, cl)
-            np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                       rtol=2e-5, atol=2e-5)
             np.testing.assert_array_equal(np.asarray(got_pool), want_pool)
 
     @pytest.mark.parametrize("B,S", [(1, 40), (2, 16), (3, 7), (2, 1)])

@@ -3,9 +3,8 @@
 XLLM_PALLAS_INTERPRET=1 makes the dispatch gates treat the CPU backend as
 kernel-capable and run every Pallas kernel in interpret mode, so these
 tests drive the REAL trace-time routing (the (pool, layer) decode kernel
-behind the in-place append, Pallas chunked-prefill attention) end-to-end
-through the engine and compare greedy outputs against the default XLA
-paths. Tiny 1-layer config with
+behind the in-place append) end-to-end through the engine and compare
+greedy outputs against the default XLA paths. Tiny 1-layer config with
 head_dim=128 (the Mosaic lane-width requirement the gates check).
 """
 
@@ -55,17 +54,3 @@ class TestPallasEngineRouting:
         assert _greedy(engine, PROMPT) == baseline
         assert engine.stats()["attention_paths"]["decode_multi"] == {
             "paged_attention": "pallas"}
-
-    def test_pallas_prefill_matches_default(self, monkeypatch):
-        baseline = _greedy(_pallas_capable_engine(), PROMPT)
-        monkeypatch.setenv("XLLM_PALLAS_INTERPRET", "1")
-        monkeypatch.setenv("XLLM_PREFILL_PALLAS", "1")
-        routed = _greedy(_pallas_capable_engine(), PROMPT)
-        assert routed == baseline
-
-    def test_all_pallas_paths_together(self, monkeypatch):
-        baseline = _greedy(_pallas_capable_engine(), PROMPT)
-        monkeypatch.setenv("XLLM_PALLAS_INTERPRET", "1")
-        monkeypatch.setenv("XLLM_PREFILL_PALLAS", "1")
-        routed = _greedy(_pallas_capable_engine(), PROMPT)
-        assert routed == baseline

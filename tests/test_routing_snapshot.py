@@ -148,11 +148,13 @@ class TestSchedulingRaces:
                     meta = mgr.get_instance_meta(n)
                     if meta is None:
                         continue
-                    with dead_lock:
-                        dead.add((n, meta.incarnation_id))
                     # Replacement: same name, new incarnation (the
                     # deregister+register path the watch plane takes).
+                    # "Dead" means deregistered: until that returns a
+                    # reader is legally routed to this incarnation.
                     mgr.deregister_instance(n, reason="replaced")
+                    with dead_lock:
+                        dead.add((n, meta.incarnation_id))
                     mgr.register_instance(
                         make_meta(n, InstanceType.MIX,
                                   incarnation_id=uuid.uuid4().hex[:8]),

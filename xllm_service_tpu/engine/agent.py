@@ -1944,7 +1944,7 @@ def main() -> None:
                    help="chunked-prefill tokens per engine iteration "
                         "(0 = whole-suffix installs); with a chunk set, "
                         "mid chunks ride decode steps (Sarathi mixed "
-                        "programs) unless XLLM_SARATHI=0")
+                        "programs)")
     p.add_argument("--kv-tier-dram-mb", type=int, default=0,
                    help="host-RAM tier for evicted prefix KV blocks, MiB "
                         "(0 disables tiering; docs/kv_tiering.md)")

@@ -37,7 +37,6 @@ become Pallas/XLA"). Design points for XLA:
 
 from __future__ import annotations
 
-import contextlib
 import os
 import statistics
 import threading
@@ -441,17 +440,15 @@ class InferenceEngine:
         self._turnaround_s: deque[float] = deque(maxlen=_LOOK_AHEAD_SAMPLES)
         self._call_s: deque[float] = deque(maxlen=_LOOK_AHEAD_SAMPLES)
         self._t_landed = 0.0          # time.monotonic() of the last fetch
-        # Sarathi mixed decode+chunk steps (XLLM_SARATHI=0 disables for
-        # A/B; the path additionally requires prefill_chunk_tokens > 0
-        # and a family mixed program — see _ride_chunk_args).
-        self._sarathi = os.environ.get("XLLM_SARATHI", "1") != "0"
-        # Chunks per ride under queue pressure. Shared by the ride gate
-        # AND warmup — a drifted copy would mean the first pressure ride
-        # hits a cold compile on a live request's TBT.
+        # Sarathi mixed decode+chunk steps (a mid chunk rides a decode
+        # call when prefill_chunk_tokens > 0 and the family has a mixed
+        # program, see _ride_chunk_args): chunks per ride under queue
+        # pressure. Shared by the ride gate AND warmup — a drifted copy
+        # would mean the first pressure ride hits a cold compile on a
+        # live request's TBT.
         self._pressure_span_chunks = 4
         self._rode_chunk = False
-        # Last: warmup reads the flags set above (`_sarathi`,
-        # `_pressure_span_chunks`).
+        # Last: warmup reads `_pressure_span_chunks`, set above.
         if cfg.warmup_programs:
             self._warmup_programs()
 
@@ -495,10 +492,11 @@ class InferenceEngine:
         is_vl = cfg.model_family == "qwen2_vl"
         from ..ops.attention import trace_program
 
-        def prog(label):
+        def prog(label, ring=False):
             """Trace context of one program: names it in the attention
-            path record and hands the kernels the mesh."""
-            return trace_program(label, self._paths, self.mesh)
+            path record and hands the kernels the mesh (a seq axis on it
+            makes decode context-parallel; `ring` the prefill)."""
+            return trace_program(label, self._paths, self.mesh, ring)
 
         def pin(d):
             """Hold the decode state to its placement (see __init__)."""
@@ -573,27 +571,20 @@ class InferenceEngine:
 
         @partial(jax.jit, static_argnums=(2,), donate_argnums=(1,))
         def decode_multi(params, d, horizon):
-            from ..ops.attention import decode_context_parallel
-            from ..parallel.mesh import AXIS_SEQ as _SEQ
-
-            cp_ctx = (decode_context_parallel(self.mesh, _SEQ)
-                      if self.seq_parallel > 1 else contextlib.nullcontext())
-
             def step(d, _):
                 positions = d["clens"] - 1
-                with cp_ctx:
-                    if is_vl:
-                        # M-RoPE: rope rotates at sequence index + the
-                        # per-slot delta left by image grids; KV paging
-                        # stays on the plain sequence index.
-                        logits, kv = fam.decode_forward(
-                            params, mcfg, d["last"], positions, d["kv"],
-                            d["pt"], d["clens"],
-                            rope_positions=positions + d["mrope_delta"])
-                    else:
-                        logits, kv = fam.decode_forward(
-                            params, mcfg, d["last"], positions, d["kv"],
-                            d["pt"], d["clens"])
+                if is_vl:
+                    # M-RoPE: rope rotates at sequence index + the
+                    # per-slot delta left by image grids; KV paging
+                    # stays on the plain sequence index.
+                    logits, kv = fam.decode_forward(
+                        params, mcfg, d["last"], positions, d["kv"],
+                        d["pt"], d["clens"],
+                        rope_positions=positions + d["mrope_delta"])
+                else:
+                    logits, kv = fam.decode_forward(
+                        params, mcfg, d["last"], positions, d["kv"],
+                        d["pt"], d["clens"])
                 return _post_decode_forward(dict(d, kv=kv), logits)
 
             with prog("decode_multi"):
@@ -673,9 +664,6 @@ class InferenceEngine:
 
             @partial(jax.jit, donate_argnums=(1,))
             def prefill_install(params, d, packed_in, mm):
-                from ..ops.attention import sequence_parallel_prefill
-                from ..parallel.mesh import AXIS_SEQ
-
                 NS, NB = NUM_STOP_IDS, NUM_BIAS
                 n_ints = P + 4 + NS + NB + 1   # +1: token budget
                 n_floats = 6 + NB
@@ -715,10 +703,8 @@ class InferenceEngine:
                 else:
                     positions = prefix_len + jnp.arange(
                         tokens.shape[1], dtype=jnp.int32)[None, :]
-                sp_ctx = (sequence_parallel_prefill(self.mesh, AXIS_SEQ)
-                          if use_ring else contextlib.nullcontext())
-                with sp_ctx, prog("prefill_install_sp" if use_ring
-                                  else "prefill_install"):
+                with prog("prefill_install_sp" if use_ring
+                          else "prefill_install", ring=use_ring):
                     if is_vl:
                         logits, kv = fam.prefill_forward(
                             params, mcfg, tokens, positions, d["kv"],
@@ -883,8 +869,7 @@ class InferenceEngine:
                                              axis=1)        # [B, Kd+1]
                     prefix = jnp.maximum(d["clens"] - 1, 0)
                     positions = prefix[:, None] + steps
-                    from ..ops.attention import mq_paged_verify
-                    with mq_paged_verify(), prog("spec_multi"):
+                    with prog("spec_multi"):
                         logits, kv = fam.verify_forward(
                             params, mcfg, tokens, positions, d["kv"],
                             d["pt"], prefix, seq_lens)
@@ -1120,13 +1105,11 @@ class InferenceEngine:
             calls.append((self._spec_multi,
                           (jnp.zeros((B,), jnp.int32),
                            self.cfg.speculate_cycles), False))
-        if (self._decode_chunk_multi is not None and self._sarathi
+        if (self._decode_chunk_multi is not None
                 and self.cfg.prefill_chunk_tokens > 0
                 and self.seq_parallel == 1):
             # seq_parallel guard matches _ride_chunk_args: under CP the
-            # ride path never runs (the mixed program lacks the CP trace
-            # context), so warming it would trace non-CP attention
-            # against the seq-sharded pool and corrupt dstate sharding.
+            # ride path never runs, so there is nothing to warm.
             # Sarathi mixed programs: one variant per horizon value per
             # chunk span ([C] single, [4C] pressure span); a cold
             # variant otherwise compiles mid-serving on the first ride
@@ -2026,7 +2009,7 @@ class InferenceEngine:
         install program). Host bookkeeping (written) advances here; the
         device work rides the donated dstate chain in dispatch order."""
         if (self._decode_chunk_multi is None or not self._prefillings
-                or self.seq_parallel > 1 or not self._sarathi):
+                or self.seq_parallel > 1):
             return None
         st = self._prefillings[0]
         if st["req"].mm_embeds is not None:

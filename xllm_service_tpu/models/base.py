@@ -99,51 +99,6 @@ class ModelConfig:
     def kv_size(self) -> int:
         return self.num_kv_heads * self.head_dim
 
-    # ---- roofline accounting (BENCH contract: pct_roofline) ---------------
-    def decode_weight_stream_bytes(self) -> int:
-        """Bytes of weights streamed from HBM per decode token-step.
-
-        Decode at serving batch sizes is weight-bandwidth-bound: every
-        step reads all layer projections + the lm_head once. The
-        embedding table is a gather (B rows, negligible) and is excluded.
-        Covers the dense llama/qwen2/gemma path and MoE (only the routed
-        experts' FFN weights stream per token).
-        """
-        h, L = self.hidden_size, self.num_layers
-        wb = 1 if self.quant == "int8" else 2          # int8 vs bf16
-        if self.kv_lora_rank > 0:
-            # MLA (deepseek family): the streamed attention weights are
-            # q_proj + kv_down + k_rope + per-head k_up/v_up + o_proj,
-            # not the dense GQA projections.
-            dn, dr = self.qk_nope_head_dim, self.qk_rope_head_dim
-            dc, dv = self.kv_lora_rank, self.v_head_dim
-            attn = (h * self.num_heads * (dn + dr)      # q_proj
-                    + h * dc + h * dr                   # kv_down, k_rope
-                    + self.num_heads * dn * dc          # k_up
-                    + self.num_heads * dc * dv          # v_up
-                    + self.num_heads * dv * h)          # o_proj
-        else:
-            attn = h * self.q_size + 2 * h * self.kv_size + self.q_size * h
-        if self.num_experts:
-            n_moe = max(0, L - self.first_dense_layers)
-            n_dense = L - n_moe
-            active = self.num_experts_per_token + self.num_shared_experts
-            moe_mlp = 3 * h * (self.moe_ffn_size or self.ffn_size) * active
-            mlp_total = (n_dense * 3 * h * self.ffn_size + n_moe * moe_mlp)
-        else:
-            mlp_total = L * 3 * h * self.ffn_size
-        norms = L * 2 * h * 2 + h * 2                   # bf16 RMSNorm weights
-        # The logits matmul streams the full [vocab, h] matrix whether or
-        # not it aliases the embedding table (tied models stream it too).
-        head = self.vocab_size * h * wb
-        return (L * attn + mlp_total) * wb + norms + head
-
-    def kv_bytes_per_token(self, context_len: int) -> int:
-        """HBM bytes of KV cache READ per sequence per decode token-step
-        (K and V over the live context, every layer, bf16 pool)."""
-        per_layer = 2 * context_len * self.kv_size * 2
-        return self.num_layers * per_layer
-
 
 @dataclass(frozen=True)
 class VisionConfig:
@@ -262,8 +217,8 @@ def llama3_70b_config() -> ModelConfig:
 
 
 def bench_1b_config() -> ModelConfig:
-    """~1.2B params — fits one v5e chip in bf16 with KV pool; used by
-    bench.py for single-chip decode throughput."""
+    """~1.2B params — fits one v5e chip in bf16 with KV pool; the agent
+    CLI's default `--model-config`."""
     return ModelConfig(name="llama", vocab_size=32768, hidden_size=2048,
                        num_layers=16, num_heads=16, num_kv_heads=8,
                        head_dim=128, ffn_size=8192, max_context_len=4096)

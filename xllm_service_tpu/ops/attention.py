@@ -27,12 +27,13 @@ import contextlib
 import functools
 import logging
 import threading
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..parallel.mesh import AXIS_MODEL
+from ..parallel.mesh import AXIS_MODEL, AXIS_SEQ
 from ..utils import get_logger
 
 logger = get_logger(__name__)
@@ -51,30 +52,52 @@ def _backend() -> str:
 # program is TRACED and is invisible afterwards, so every dispatcher
 # notes which path it took: `{program: {op: path}}`, logged once per
 # distinct entry. The engine traces each of its programs under
-# `trace_program(label, record=engine dict)` and returns that dict from
-# `stats()`; calls outside any program land in `PATH_RECORD[""]`.
+# `trace_program(label, record=engine dict, mesh)` and returns that dict
+# from `stats()`; calls outside any program land in `PATH_RECORD[""]`.
 PATH_RECORD: dict[str, dict[str, str]] = {}
+
+
+class _Program(NamedTuple):
+    label: str = ""
+    record: dict = PATH_RECORD
+    mesh: Any = None
+    ring: bool = False
+
+
 _prog_ctx = threading.local()
 
 
 @contextlib.contextmanager
-def trace_program(label: str, record: dict | None = None, mesh=None):
-    """Trace context for one jitted program: names it in the path record
-    and, with a `mesh` whose model axis is > 1, makes `paged_attention`
-    run its Pallas kernel per head-shard under `shard_map` (Mosaic
-    kernels cannot be partitioned by GSPMD)."""
+def trace_program(label: str, record: dict | None = None, mesh=None,
+                  ring: bool = False):
+    """Trace context for one jitted program, and everything the
+    dispatchers below know about it besides their arguments: its name in
+    the path record and its `mesh`. A model axis > 1 makes
+    `paged_attention` run its Pallas kernel per head-shard under
+    `shard_map` (Mosaic kernels cannot be partitioned by GSPMD); a seq
+    axis > 1 means the KV pool is sharded over it, so `paged_attention`
+    goes through the flash-stats-merge context-parallel op. `ring`
+    (SURVEY.md §5.7): `prefill_attention` runs the suffix self-attention
+    as the blockwise ring over the seq axis — the engine traces that
+    variant as its own program and sends it prefix-free prompts only
+    (prefix attention would need a traced branch, which XLA cannot take
+    on a dynamic prefix_lens)."""
     prev = getattr(_prog_ctx, "cfg", None)
-    _prog_ctx.cfg = (label, PATH_RECORD if record is None else record, mesh)
+    _prog_ctx.cfg = _Program(
+        label, PATH_RECORD if record is None else record, mesh, ring)
     try:
         yield
     finally:
         _prog_ctx.cfg = prev
 
 
+def _program() -> _Program:
+    return getattr(_prog_ctx, "cfg", None) or _Program()
+
+
 def note_path(op: str, path: str) -> None:
-    label, record, _ = getattr(_prog_ctx, "cfg", None) or ("", PATH_RECORD,
-                                                           None)
-    paths = record.setdefault(label, {})
+    prog = _program()
+    paths = prog.record.setdefault(prog.label, {})
     if paths.get(op) == path:
         return
     paths[op] = path
@@ -82,78 +105,23 @@ def note_path(op: str, path: str) -> None:
     loud = _backend() != "cpu" and path.startswith("xla")
     logger.log(logging.WARNING if loud else logging.INFO,
                "attention path: program=%s op=%s -> %s",
-               label or "-", op, path)
+               prog.label or "-", op, path)
 
 
 def program_mesh():
     """The mesh of the program being traced (None outside one, or for a
     single-device program)."""
-    cfg = getattr(_prog_ctx, "cfg", None)
-    mesh = cfg[2] if cfg else None
+    mesh = _program().mesh
     return mesh if mesh is not None and mesh.size > 1 else None
 
 
-def _tp_mesh():
-    """(mesh, tp) of the program being traced when its model axis is
-    sharded, else (None, 1)."""
+def _axis_mesh(axis: str):
+    """(mesh, n) of the program being traced when its `axis` is sharded
+    n > 1 ways, else (None, 1)."""
     mesh = program_mesh()
-    if mesh is None or mesh.shape.get(AXIS_MODEL, 1) <= 1:
+    if mesh is None or mesh.shape.get(axis, 1) <= 1:
         return None, 1
-    return mesh, int(mesh.shape[AXIS_MODEL])
-
-# Sequence-parallel prefill context (SURVEY.md §5.7). The engine activates
-# this while TRACING its long-prefill program; `prefill_attention` then
-# routes the suffix self-attention through the blockwise ring op sharded
-# over the mesh's seq axis. Trace-time only — the engine guarantees the
-# prompt has no cached prefix on this path (prefix attention would need a
-# traced branch, which XLA cannot take on a dynamic prefix_lens).
-_sp_ctx = threading.local()
-
-
-@contextlib.contextmanager
-def sequence_parallel_prefill(mesh, seq_axis: str = "seq"):
-    prev = getattr(_sp_ctx, "cfg", None)
-    _sp_ctx.cfg = (mesh, seq_axis)
-    try:
-        yield
-    finally:
-        _sp_ctx.cfg = prev
-
-
-# Speculative-verify context: the engine sets this while tracing its
-# verify program; `prefill_attention` may then route the short query
-# block through the multi-query paged Pallas kernel (pages-only read —
-# valid because the block KV is written before attention) instead of the
-# gather-based XLA path. Requires XLLM_MQ_PALLAS=1 + a TPU backend:
-# interpret-verified on CPU, Mosaic compile still to be validated on a
-# real chip.
-_mq_ctx = threading.local()
-
-
-@contextlib.contextmanager
-def mq_paged_verify():
-    prev = getattr(_mq_ctx, "on", None)
-    _mq_ctx.on = True
-    try:
-        yield
-    finally:
-        _mq_ctx.on = prev
-
-
-# Context-parallel DECODE context: the engine activates this while tracing
-# its decode program when the KV pool is sharded over the seq axis;
-# `paged_attention` then routes through the flash-stats-merge CP op.
-_cp_ctx = threading.local()
-
-
-@contextlib.contextmanager
-def decode_context_parallel(mesh, seq_axis: str = "seq"):
-    prev = getattr(_cp_ctx, "cfg", None)
-    _cp_ctx.cfg = (mesh, seq_axis)
-    try:
-        yield
-    finally:
-        _cp_ctx.cfg = prev
+    return mesh, int(mesh.shape[axis])
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
@@ -334,61 +302,23 @@ def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     softcap > 0 tanh-caps the attention scores; window > 0 restricts each
     query to the trailing `window` key positions (gemma-2 local layers).
-    Both take the XLA path — the Pallas/ring kernels don't implement them.
+    Both take the XLA path — the ring does not implement them.
     """
     B, S, n_heads, hd = q.shape
     n_kv = k.shape[2]
     n_rep = n_heads // n_kv
 
-    # Pallas MQ gate BEFORE the default-scale computation: a caller
-    # passing an EXPLICIT scale (the MLA latent path, whose cache layout
-    # this GQA kernel must never see) is excluded by `scale is None`
-    # rather than by float comparison against the default. Two users:
-    # - the speculative-verify program (traced under `mq_paged_verify`,
-    #   XLLM_MQ_PALLAS=1);
-    # - chunked/prefix prefill (XLLM_PREFILL_PALLAS=1): the XLA fallback
-    #   gathers every row's full page span dense — [B, H, S, prefix+S]
-    #   scores in HBM, which at long contexts dwarfs the chunk itself.
-    # Both share the kernel's invariant (block KV already written to the
-    # pages — write_kv runs first in prefill_from_embeddings) and
-    # both are excluded under the ring-attention (sp) trace context. The
-    # rows cap keeps the kernel's [S*n_heads, hd] f32 accumulator and
-    # m/l scratch inside VMEM; bigger chunks fall back to XLA.
-    if pool is not None and scale is None \
-            and softcap == 0.0 and window == 0 \
-            and getattr(_sp_ctx, "cfg", None) is None:
-        import os
-
-        in_verify = bool(getattr(_mq_ctx, "on", None))
-        mq_on = in_verify and os.environ.get("XLLM_MQ_PALLAS", "") == "1"
-        # The prefill flag must not bypass the verify path's own opt-in:
-        # each has a separate Mosaic-validation gate.
-        pf_on = (not in_verify
-                 and os.environ.get("XLLM_PREFILL_PALLAS", "") == "1"
-                 and S * n_heads <= 4096)
-        if (mq_on or pf_on) and _tp_mesh()[0] is None \
-                and _mosaic_kernel_ok(q, n_kv):
-            from .pallas_mq_paged_attention import mq_paged_attention_pallas
-
-            # Opt-in kernel on one layer's views (a copy of the layer).
-            note_path("prefill_attention", "pallas-mq")
-            return mq_paged_attention_pallas(q, pool[layer, 0],
-                                             pool[layer, 1],
-                                             page_table, prefix_lens,
-                                             seq_lens,
-                                             interpret=_pallas_interpret())
-
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
 
-    sp = getattr(_sp_ctx, "cfg", None)
-    if sp is not None and (softcap != 0.0 or window != 0):
+    prog = _program()
+    if prog.ring and (softcap != 0.0 or window != 0):
         raise NotImplementedError(
             "ring attention does not support attn softcap/sliding window; "
             "the engine must not enable sequence-parallel prefill for "
             "gemma-2-style models")
-    note_path("prefill_attention", "ring" if sp is not None else "xla-dense")
-    if sp is not None:
+    note_path("prefill_attention", "ring" if prog.ring else "xla-dense")
+    if prog.ring:
         # Context-parallel path: ring attention over the seq mesh axis.
         # Queries past seq_lens are end-padding; causal masking keeps them
         # out of every valid query's window and the engine discards their
@@ -397,8 +327,8 @@ def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         # ppermute traffic stays n_rep times smaller.
         from .ring_attention import ring_attention
 
-        mesh, seq_axis = sp
-        return ring_attention(q, k, v, mesh, seq_axis=seq_axis, scale=scale)
+        return ring_attention(q, k, v, prog.mesh, seq_axis=AXIS_SEQ,
+                              scale=scale)
 
     kf = _repeat_kv(k, n_rep).astype(jnp.float32)
     vf = _repeat_kv(v, n_rep).astype(jnp.float32)
@@ -490,19 +420,35 @@ def _pallas_interpret() -> bool:
     return os.environ.get("XLLM_PALLAS_INTERPRET", "") == "1"
 
 
-def _mosaic_kernel_ok(q: jax.Array, n_kv: int) -> bool:
-    """Shared eligibility gate for the hand-written attention kernels:
-    Mosaic tiling needs the head dim to be a lane-width multiple and GQA
-    an integer group size; the kill switch and CPU backend exclude all
-    Pallas paths at once."""
-    import os
+def attention_path(backend: str, interpret: bool, head_dim: int,
+                   n_heads: int, n_kv: int, dtype, tp: int = 1,
+                   context_parallel: bool = False) -> str:
+    """The path `paged_attention` takes, from what the code can observe
+    while it traces; the string is what `/stats`.attention_paths records
+    (chipbench's `decode_paths` check and chip_smoke.py read it).
 
-    n_heads, hd = q.shape[-2], q.shape[-1]
-    return (hd % 128 == 0 and n_heads % n_kv == 0
-            and q.dtype in (jnp.bfloat16, jnp.float32)
-            and (_backend() != "cpu" or _pallas_interpret())
-            and os.environ.get("XLLM_DISABLE_PALLAS_ATTENTION", "")
-            in ("", "0"))
+    One eligibility rule for the hand-written kernels: Mosaic tiling
+    needs the head dim to be a lane-width multiple and GQA an integer
+    group size, in bf16 or f32; the CPU backend runs them in interpret
+    mode only. A pool sharded over the seq axis goes through the
+    context-parallel op (kernel or dense body by the same rule); head
+    counts that do not divide over `tp` leave the kernel, which runs per
+    head-shard."""
+    dtype = jnp.dtype(dtype)
+    kernel_ok = (head_dim % 128 == 0 and n_heads % n_kv == 0
+                 and dtype in (jnp.bfloat16, jnp.float32)
+                 and (backend != "cpu" or interpret))
+    if context_parallel:
+        return (f"cp-pallas ({AXIS_SEQ})" if kernel_ok
+                else f"cp-xla-dense ({AXIS_SEQ})")
+    if not kernel_ok:
+        why = ("cpu backend" if backend == "cpu"
+               else f"shape outside the kernel's tiling: hd={head_dim} "
+                    f"heads={n_heads}/{n_kv} dtype={dtype}")
+        return f"xla ({why})"
+    if n_kv % tp or n_heads % tp:
+        return f"xla (kv heads {n_kv} do not divide over tp={tp})"
+    return "pallas" if tp == 1 else f"pallas (shard_map model={tp})"
 
 
 def decode_attention_step(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -532,12 +478,7 @@ def _span_buckets_on() -> bool:
     """Span-bucketed gathers compile up to 4 variants of the attention
     subgraph per program — worth it on accelerators (bandwidth saved
     every step), pure compile-time cost on the CPU test backend (the
-    suite pays minutes). XLLM_XLA_SPAN_BUCKETS=1/0 overrides."""
-    import os
-
-    v = os.environ.get("XLLM_XLA_SPAN_BUCKETS", "")
-    if v in ("0", "1"):
-        return v == "1"
+    suite pays minutes)."""
     return _backend() != "cpu"
 
 
@@ -611,11 +552,11 @@ def paged_attention(q: jax.Array, pool: jax.Array, layer: int,
                     context_lens: jax.Array,
                     scale: float | None = None,
                     softcap: float = 0.0, window: int = 0) -> jax.Array:
-    """Backend dispatcher over layer `layer` of the pool
-    [L, 2, num_pages, n_kv, ps, hd]: context-parallel op when the engine
-    traced under `decode_context_parallel` (pool sharded over the seq
-    axis), hand-written Pallas kernel on TPU, XLA gather fallback
-    elsewhere (CPU test meshes) and for shapes outside the kernel's tiling
+    """Dispatcher over layer `layer` of the pool
+    [L, 2, num_pages, n_kv, ps, hd], on `attention_path`'s word:
+    context-parallel op when the program's mesh shards the pool over the
+    seq axis, hand-written Pallas kernel on TPU, XLA gather elsewhere
+    (CPU test meshes) and for shapes outside the kernel's tiling
     constraints. Selection happens at trace time — all paths are
     numerically equivalent (tested). The kernel and the gather both read
     the pool where it lies; only the CP op still takes one layer's views.
@@ -623,61 +564,48 @@ def paged_attention(q: jax.Array, pool: jax.Array, layer: int,
     when the shape qualifies, falling back to XLA otherwise; CP meshes
     refuse such models (the partial-stats merge has no softcap/window
     support)."""
-    cp = getattr(_cp_ctx, "cfg", None)
-    if cp is not None:
+    mesh, tp = _axis_mesh(AXIS_MODEL)
+    seq_mesh, _ = _axis_mesh(AXIS_SEQ)
+    path = attention_path(_backend(), _pallas_interpret(), q.shape[-1],
+                          q.shape[-2], pool.shape[3], q.dtype, tp,
+                          context_parallel=seq_mesh is not None)
+    note_path("paged_attention", path)
+    if seq_mesh is not None:
         if softcap != 0.0 or window != 0:
             raise NotImplementedError(
                 "context-parallel decode does not support attn "
                 "softcap/sliding window")
         from .cp_paged_attention import cp_paged_attention
 
-        mesh, seq_axis = cp
         return cp_paged_attention(q, pool[layer, 0], pool[layer, 1],
-                                  page_table, context_lens, mesh,
-                                  seq_axis=seq_axis, scale=scale)
-
-    mesh, tp = _tp_mesh()
-    n_heads, n_kv = q.shape[-2], pool.shape[3]
-    if not _mosaic_kernel_ok(q, n_kv):
-        import os
-
-        why = ("XLLM_DISABLE_PALLAS_ATTENTION"
-               if os.environ.get("XLLM_DISABLE_PALLAS_ATTENTION", "")
-               not in ("", "0")
-               else "cpu backend" if _backend() == "cpu"
-               else f"shape outside the kernel's tiling: hd={q.shape[-1]} "
-                    f"heads={n_heads}/{n_kv} dtype={q.dtype}")
-        note_path("paged_attention", f"xla ({why})")
-    elif n_kv % tp or n_heads % tp:
-        note_path("paged_attention",
-                  f"xla (kv heads {n_kv} do not divide over tp={tp})")
-    else:
-        from .pallas_paged_attention import paged_attention_pallas
-
-        # softcap/window/scale are static kernel params (gemma-2 decodes
-        # through the kernel too — the XLA fallback gathers every row's
-        # FULL page span dense per layer per step).
-        kernel = functools.partial(paged_attention_pallas,
-                                   interpret=_pallas_interpret(),
+                                  page_table, context_lens, seq_mesh,
+                                  seq_axis=AXIS_SEQ, scale=scale)
+    if path.startswith("xla"):
+        return paged_attention_xla(q, pool, layer, page_table, context_lens,
                                    scale=scale, softcap=softcap,
                                    window=window)
-        layer_id = jnp.full((1,), layer, jnp.int32)
-        if mesh is None:
-            note_path("paged_attention", "pallas")
-            return kernel(q, pool, layer_id, page_table, context_lens)
-        # Tensor parallel: GSPMD cannot partition a Mosaic kernel, so
-        # each device runs it on its own heads — q and the pool are
-        # head-sharded over `model` (KV_PAGES_SPEC), the layer id, the
-        # page table and the lengths replicated. GQA groups stay whole
-        # because both head counts divide by tp. pallas_call outputs
-        # carry no varying-axes metadata, hence check_vma=False.
-        note_path("paged_attention", f"pallas (shard_map model={tp})")
-        heads = P(None, AXIS_MODEL, None)
-        pool_spec = P(None, None, None, AXIS_MODEL, None, None)
-        return jax.shard_map(
-            kernel, mesh=mesh,
-            in_specs=(heads, pool_spec, P(), P(), P()),
-            out_specs=heads, check_vma=False,
-        )(q, pool, layer_id, page_table, context_lens)
-    return paged_attention_xla(q, pool, layer, page_table, context_lens,
+
+    from .pallas_paged_attention import paged_attention_pallas
+
+    # softcap/window/scale are static kernel params (gemma-2 decodes
+    # through the kernel too — the XLA fallback gathers every row's FULL
+    # page span dense per layer per step).
+    kernel = functools.partial(paged_attention_pallas,
+                               interpret=_pallas_interpret(),
                                scale=scale, softcap=softcap, window=window)
+    layer_id = jnp.full((1,), layer, jnp.int32)
+    if mesh is None:
+        return kernel(q, pool, layer_id, page_table, context_lens)
+    # Tensor parallel: GSPMD cannot partition a Mosaic kernel, so each
+    # device runs it on its own heads — q and the pool are head-sharded
+    # over `model` (KV_PAGES_SPEC), the layer id, the page table and the
+    # lengths replicated. GQA groups stay whole because both head counts
+    # divide by tp. pallas_call outputs carry no varying-axes metadata,
+    # hence check_vma=False.
+    heads = P(None, AXIS_MODEL, None)
+    pool_spec = P(None, None, None, AXIS_MODEL, None, None)
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(heads, pool_spec, P(), P(), P()),
+        out_specs=heads, check_vma=False,
+    )(q, pool, layer_id, page_table, context_lens)

@@ -108,20 +108,15 @@ def _partial_kernel(local_pt_ref, starts_ref, n_local_ref, clens_ref,
                     m_out, l_out, acc_out,
                     k_buf, v_buf, sems, m_scr, l_scr, acc_scr,
                     *, page_size: int, n_kv: int, group: int, scale: float,
-                    max_pages: int, chunk: int, pipeline_rows: bool):
+                    max_pages: int, chunk: int):
     """Flash partial stats over this shard's owned pages only.
 
     local_pt_ref: [B, mp] LOCAL page indices, owned entries compacted to
     the front (n_local_ref[b] of them); starts_ref: [B, mp] each entry's
     global token start (ctx for non-owned → fully masked)."""
     b = pl.program_id(0)
-    nb = pl.num_programs(0)
     ctx = clens_ref[b]
-
-    def n_pages_of(row):
-        return jnp.minimum(n_local_ref[row], max_pages)
-
-    n_pages = n_pages_of(b)
+    n_pages = jnp.minimum(n_local_ref[b], max_pages)
 
     m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
@@ -144,8 +139,7 @@ def _partial_kernel(local_pt_ref, starts_ref, n_local_ref, clens_ref,
             # DMA'd — their buffer rows are stale. Position them at
             # ctx so both masks reject them (clamping the table read
             # instead would alias a REAL page's positions and let
-            # stale K/V through). (Covers the pipelined walk's whole
-            # pad chunk too: every entry sits past n_pages.)
+            # stale K/V through).
             st = jnp.where(
                 base + j < n_pages,
                 starts_ref[b, jnp.minimum(base + j, max_pages - 1)],
@@ -168,9 +162,8 @@ def _partial_kernel(local_pt_ref, starts_ref, n_local_ref, clens_ref,
             flash_accumulate(slice(kv * group, (kv + 1) * group),
                              s, v, m_scr, l_scr, acc_scr)
 
-    chunked_page_walk(local_pt_ref, b, nb, n_pages, n_pages_of, chunk,
-                      k_hbm, v_hbm, k_buf, v_buf, sems, compute,
-                      pipeline_rows)
+    chunked_page_walk(local_pt_ref, b, n_pages, chunk, k_hbm, v_hbm,
+                      k_buf, v_buf, sems, compute)
 
     m_out[0] = m_scr[...]
     l_out[0] = l_scr[...]
@@ -185,22 +178,16 @@ def _paged_partial_pallas(q, k_pages, v_pages, local_pt, starts, n_local,
 
     XLLM_PAGE_CHUNK is resolved here, OUTSIDE jit, and passed static — a
     shape-keyed cache would silently pin the first-traced chunk."""
-    import os
-
     return _paged_partial_impl(q, k_pages, v_pages, local_pt, starts,
                                n_local, context_lens, scale=scale,
                                chunk=page_chunk_size(local_pt.shape[1]),
-                               pipeline_rows=os.environ.get(
-                                   "XLLM_PAGE_PIPELINE", "") == "row",
                                interpret=interpret)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("scale", "chunk", "pipeline_rows",
-                                    "interpret"))
+                   static_argnames=("scale", "chunk", "interpret"))
 def _paged_partial_impl(q, k_pages, v_pages, local_pt, starts, n_local,
                         context_lens, *, scale: float, chunk: int,
-                        pipeline_rows: bool = False,
                         interpret: bool = False):
     B, n_q, hd = q.shape
     _, n_kv, page_size, _ = k_pages.shape
@@ -208,8 +195,7 @@ def _paged_partial_impl(q, k_pages, v_pages, local_pt, starts, n_local,
     group = n_q // n_kv
     kernel = functools.partial(_partial_kernel, page_size=page_size,
                                n_kv=n_kv, group=group, scale=scale,
-                               max_pages=max_pages, chunk=chunk,
-                               pipeline_rows=pipeline_rows)
+                               max_pages=max_pages, chunk=chunk)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B,),
@@ -303,12 +289,13 @@ def cp_paged_attention(q: jax.Array, k_pages: jax.Array,
     (or shardable) on the page axis over `seq_axis`; num_pages must divide
     by the axis size. Returns [B, n_heads, hd], identical to
     single-device paged attention (parity-tested)."""
-    from .attention import _mosaic_kernel_ok, _pallas_interpret, note_path
+    from .attention import _backend, _pallas_interpret, attention_path
 
-    kernel_ok = _mosaic_kernel_ok(q, k_pages.shape[1])
-    note_path("paged_attention",
-              f"cp-pallas ({seq_axis})" if kernel_ok
-              else f"cp-xla-dense ({seq_axis})")
+    # The dispatcher's eligibility rule (it records the path).
+    kernel_ok = attention_path(
+        _backend(), _pallas_interpret(), q.shape[-1], q.shape[-2],
+        k_pages.shape[1], q.dtype, context_parallel=True
+    ).startswith("cp-pallas")
     if kernel_ok:
         body = functools.partial(_local_partial_kernelized,
                                  axis_name=seq_axis, scale=scale,

@@ -1,5 +1,5 @@
 """Shared scaffolding for the paged-attention Pallas kernels (decode,
-multi-query verify, context-parallel partial):
+context-parallel partial):
 
 - `make_chunk_dma`: a 2-slot VMEM ring of `chunk`-page blocks, one async
   copy per page (pages are non-contiguous in HBM), waits batched per
@@ -69,94 +69,38 @@ def make_chunk_dma(page_table_ref, b, n_pages, chunk,
     return start_chunk, wait_chunk
 
 
-def chunked_page_walk(page_table_ref, b, nb, n_pages, n_pages_of, chunk,
-                      k_hbm, v_hbm, k_buf, v_buf, sems, compute,
-                      pipeline_rows: bool, c_lo=None, c_lo_of=None):
+def chunked_page_walk(page_table_ref, b, n_pages, chunk,
+                      k_hbm, v_hbm, k_buf, v_buf, sems, compute, c_lo=0):
     """Run the double-buffered page walk for grid row ``b``, calling
-    ``compute(c, slot)`` per chunk.
+    ``compute(c, slot)`` per chunk: chunk c+1 loads while chunk c
+    computes; each row pays one cold-start DMA stall.
 
-    pipeline_rows=False: classic within-row prefetch (chunk c+1 loads
-    while chunk c computes; each row pays one cold-start DMA stall).
-
-    pipeline_rows=True: rows cooperate — the final chunk (or an empty
-    row) prefetches row b+1's FIRST chunk into the free buffer slot,
-    hiding the per-row cold-start stall behind the previous row's
-    compute. Invariants: every non-empty row runs an EVEN chunk count
-    (one masked pad chunk when odd — its DMAs/waits are no-ops via the
-    p < n_pages guards and `compute` must mask it), so rows always start
-    in slot 0 (relative) and end in slot 1; only row 0 cold-starts
-    itself.
-
-    ``n_pages_of(row)`` must return the page count for any row with the
-    same semantics used for ``n_pages`` (= n_pages_of(b)).
-
-    ``c_lo`` / ``c_lo_of(row)`` (optional) give the FIRST chunk to walk —
-    a sliding-window decode (gemma-2 local layers) never needs pages
-    wholly below ctx - window, so the walk can start there instead of
-    chunk 0. Slot parity is relative to c_lo, so the cross-row
-    invariants are unchanged.
+    ``c_lo`` is the FIRST chunk to walk — a sliding-window decode
+    (gemma-2 local layers) never needs pages wholly below ctx - window,
+    so the walk can start there instead of chunk 0.
     """
-    if c_lo is None:
-        c_lo = 0
-        c_lo_of = lambda row: 0   # noqa: E731 — trace-time closure
-    n_chunks = pl.cdiv(n_pages, chunk) - c_lo   # chunks actually walked
-    n_chunks = jnp.maximum(n_chunks, 0)
+    n_chunks = jnp.maximum(pl.cdiv(n_pages, chunk) - c_lo, 0)
     start_chunk, wait_chunk = make_chunk_dma(
         page_table_ref, b, n_pages, chunk, k_hbm, v_hbm, k_buf, v_buf,
         sems)
 
-    if not pipeline_rows:
-        @pl.when(n_chunks > 0)
-        def _run():
-            start_chunk(0, c_lo)
-
-            def body(i, _):
-                c = c_lo + i
-                slot = jax.lax.rem(i, 2)
-
-                @pl.when(i + 1 < n_chunks)
-                def _prefetch():
-                    start_chunk(1 - slot, c + 1)
-
-                wait_chunk(slot, c)
-                compute(c, slot)
-                return ()
-
-            jax.lax.fori_loop(0, n_chunks, body, ())
-        return
-
-    b_next = jnp.minimum(b + 1, nb - 1)
-    start_next, _ = make_chunk_dma(
-        page_table_ref, b_next, n_pages_of(b_next), chunk, k_hbm, v_hbm,
-        k_buf, v_buf, sems)
-    c_lo_next = c_lo_of(b_next)
-    n_chunks_e = n_chunks + jax.lax.rem(n_chunks, 2)     # pad to even
-
-    @pl.when(b == 0)
-    def _cold():
+    @pl.when(n_chunks > 0)
+    def _run():
         start_chunk(0, c_lo)
 
-    @pl.when((n_chunks_e == 0) & (b + 1 < nb))
-    def _forward_empty_row():
-        start_next(0, c_lo_next)
+        def body(i, _):
+            c = c_lo + i
+            slot = jax.lax.rem(i, 2)
 
-    def body(i, _):
-        c = c_lo + i
-        slot = jax.lax.rem(i, 2)
+            @pl.when(i + 1 < n_chunks)
+            def _prefetch():
+                start_chunk(1 - slot, c + 1)
 
-        @pl.when(i + 1 < n_chunks_e)
-        def _prefetch():
-            start_chunk(1 - slot, c + 1)
+            wait_chunk(slot, c)
+            compute(c, slot)
+            return ()
 
-        @pl.when((i + 1 == n_chunks_e) & (b + 1 < nb))
-        def _prefetch_next_row():
-            start_next(0, c_lo_next)
-
-        wait_chunk(slot, c)
-        compute(c, slot)
-        return ()
-
-    jax.lax.fori_loop(0, n_chunks_e, body, ())
+        jax.lax.fori_loop(0, n_chunks, body, ())
 
 
 # --------------------------------------------------------------- page movers

@@ -53,17 +53,11 @@ def _kernel(layer_ref, page_table_ref, context_lens_ref,   # SMEM prefetch
             k_buf, v_buf, sems,                 # scratch: 2-slot chunk ring
             m_scr, l_scr, acc_scr,
             *, page_size: int, n_kv: int, group: int, scale: float,
-            max_pages: int, chunk: int, pipeline_rows: bool,
-            softcap: float, window: int):
+            max_pages: int, chunk: int, softcap: float, window: int):
     b = pl.program_id(0)
-    nb = pl.num_programs(0)
     ctx = context_lens_ref[b]
     k_hbm = pool_hbm.at[layer_ref[0], 0]        # [pages, n_kv, ps, hd] views
     v_hbm = pool_hbm.at[layer_ref[0], 1]
-
-    def n_pages_of(row):
-        return jnp.minimum(pl.cdiv(context_lens_ref[row], page_size),
-                           max_pages)
 
     m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
@@ -92,20 +86,16 @@ def _kernel(layer_ref, page_table_ref, context_lens_ref,   # SMEM prefetch
             flash_accumulate(slice(kv * group, (kv + 1) * group),
                              s, v, m_scr, l_scr, acc_scr)
 
+    c_lo = 0
     if window > 0:
         # Sliding window: pages wholly below ctx - window are never
         # visible — start the walk at the first visible page's chunk.
-        def c_lo_of(row):
-            first = jnp.maximum(context_lens_ref[row] - window, 0)
-            return (first // page_size) // chunk
+        c_lo = (jnp.maximum(ctx - window, 0) // page_size) // chunk
 
-        c_lo, c_lo_fn = c_lo_of(b), c_lo_of
-    else:
-        c_lo, c_lo_fn = None, None
-
-    chunked_page_walk(page_table_ref, b, nb, n_pages_of(b), n_pages_of,
-                      chunk, k_hbm, v_hbm, k_buf, v_buf, sems, compute,
-                      pipeline_rows, c_lo=c_lo, c_lo_of=c_lo_fn)
+    n_pages = jnp.minimum(pl.cdiv(context_lens_ref[b], page_size),
+                          max_pages)
+    chunked_page_walk(page_table_ref, b, n_pages, chunk, k_hbm, v_hbm,
+                      k_buf, v_buf, sems, compute, c_lo=c_lo)
 
     l = jnp.maximum(l_scr[:, :1], 1e-9)
     o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
@@ -127,19 +117,14 @@ def paged_attention_pallas(q: jax.Array, pool: jax.Array,
     score soft-capping, sliding window) so that family decodes through
     this kernel instead of the full-span XLA gather.
 
-    Env knobs are resolved HERE (outside jit) and passed as static args —
-    a jit cache keyed only on shapes would silently pin the first-traced
-    variant for the whole process, defeating in-process A/Bs and tests.
+    XLLM_PAGE_CHUNK is resolved HERE (outside jit) and passed as a static
+    arg — a jit cache keyed only on shapes would silently pin the
+    first-traced chunk for the whole process, defeating in-process A/Bs
+    and tests.
     """
-    import os
-
-    chunk = page_chunk_size(page_table.shape[1])
-    # Cross-row DMA pipelining (see _kernel): XLLM_PAGE_PIPELINE=row
-    # enables; default off until the on-chip A/B proves it.
-    pipeline_rows = os.environ.get("XLLM_PAGE_PIPELINE", "") == "row"
     return _paged_attention_impl(q, pool, layer, page_table,
-                                 context_lens, chunk=chunk,
-                                 pipeline_rows=pipeline_rows,
+                                 context_lens,
+                                 chunk=page_chunk_size(page_table.shape[1]),
                                  scale=(float(scale)
                                         if scale is not None else None),
                                  softcap=float(softcap),
@@ -147,13 +132,11 @@ def paged_attention_pallas(q: jax.Array, pool: jax.Array,
                                  interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "pipeline_rows",
-                                             "scale", "softcap", "window",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk", "scale", "softcap",
+                                             "window", "interpret"))
 def _paged_attention_impl(q: jax.Array, pool: jax.Array,
                           layer: jax.Array, page_table: jax.Array,
                           context_lens: jax.Array, *, chunk: int,
-                          pipeline_rows: bool,
                           scale: float | None = None,
                           softcap: float = 0.0, window: int = 0,
                           interpret: bool = False) -> jax.Array:
@@ -167,7 +150,6 @@ def _paged_attention_impl(q: jax.Array, pool: jax.Array,
     kernel = functools.partial(_kernel, page_size=page_size, n_kv=n_kv,
                                group=group, scale=scale,
                                max_pages=max_pages, chunk=chunk,
-                               pipeline_rows=pipeline_rows,
                                softcap=softcap, window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
