@@ -188,11 +188,12 @@ def kernel_arms(devices):
     def f(shape, dtype, sharding=one):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    def paged(B, max_pages, pool, **kw):
+    def paged(B, max_pages, pool, heads=(n_q, n_kv), **kw):
         from xllm_service_tpu.ops.pallas_paged_attention import (
             paged_attention_pallas)
 
         def thunk():
+            n_q, n_kv = heads
             fn = jax.jit(lambda q, kv, ly, pt, cl: paged_attention_pallas(
                 q, kv, ly, pt, cl, **kw))
             return fn.lower(f((B, n_q, hd), bf16),
@@ -207,6 +208,11 @@ def kernel_arms(devices):
     yield "gemma2_softcap", paged(16, 128, 2048, softcap=50.0,
                                   scale=256 ** -0.5)
     yield "gemma2_window", paged(16, 128, 2048, window=4096 // 8)
+    # The benchmark's two configurations: batch, table width, pool and
+    # heads of chipbench/configs/*/engine.json (chunks of 16 pages, the
+    # run copy in them).
+    yield "paged_qwen25_7b", paged(32, 96, 2048, heads=(28, 4))
+    yield "paged_qwen25_3b", paged(32, 128, 4096, heads=(16, 2))
 
     def mover(which):
         from xllm_service_tpu.ops import pallas_page_dma as dma

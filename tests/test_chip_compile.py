@@ -53,10 +53,12 @@ def chip():
 
 @pytest.mark.parametrize("arm", [
     "paged_b16", "paged_b64", "gemma2_softcap", "gemma2_window",
-    "page_gather_l32", "page_scatter_l32", "cp_partial_stats",
-    "paged_shard_map_tp4"])
+    "paged_qwen25_7b", "paged_qwen25_3b", "page_gather_l32",
+    "page_scatter_l32", "cp_partial_stats", "paged_shard_map_tp4"])
 def test_kernel_compiles_for_v5e(chip, arm):
-    """Each served-path Pallas kernel at Llama-3-8B head shapes is
+    """Each served-path Pallas kernel at Llama-3-8B head shapes (the
+    decode kernel also at the benchmark's two configurations' heads, batch
+    and table width: chunks of 16 pages with the run copy in them) is
     accepted by Mosaic and stays a kernel in the compiled program."""
     compiled = dict(gate.kernel_arms(chip))[arm]()
     assert compiled.as_text().count("tpu_custom_call") >= 1

@@ -75,3 +75,22 @@ class TestCpPagedAttention:
             got = cp_paged_attention(q, kp, vp, pt, clens, mesh=mesh)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
+
+    def test_kernel_path_on_run_tables(self, monkeypatch):
+        """Owned entries that fill a chunk (the 16-wide table) with pages
+        adjacent in the shard's pool, up or down, go through the walk's run
+        copy: ascending, descending with a partly filled last page; a row
+        that crosses the two shards and a scattered one go page by page."""
+        monkeypatch.setenv("XLLM_PALLAS_INTERPRET", "1")
+        q, kp, vp, _, _ = make_case(pages=64, hd=128, H=8, n_kv=2, seed=7)
+        rng = np.random.default_rng(7)
+        pt = jnp.asarray(np.stack([
+            np.arange(1, 17), np.arange(60, 44, -1), np.arange(26, 42),
+            rng.permutation(64)[:16]]).astype(np.int32))
+        clens = jnp.asarray([256, 15 * 16 + 3, 16 * 16 - 9, 200], jnp.int32)
+        want = paged_attention_xla(q, jnp.stack([kp, vp])[None], 0, pt, clens)
+        mesh = build_mesh(MeshConfig(seq=2), devices=jax.devices()[:2])
+        with mesh:
+            got = cp_paged_attention(q, kp, vp, pt, clens, mesh=mesh)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)

@@ -15,6 +15,7 @@ from xllm_service_tpu.common.request import SamplingParams
 from xllm_service_tpu.engine import telemetry as T
 from xllm_service_tpu.engine.agent import EngineAgent
 from xllm_service_tpu.engine.engine import EngineRequest
+from xllm_service_tpu.ops.page_walk import walk_run_counts
 
 from test_e2e_real_engine import _base, cluster  # noqa: F401 (fixture)
 from test_engine import (LONG_CALL, ONE_STEP_CALL, Collector, make_engine,
@@ -136,6 +137,49 @@ def test_decode_counters_follow_the_dispatched_calls():
         == len(seen)
     # a call fetched after its last sequence finished leaves no sample
     assert 0 < len(e.telemetry.decodes) < len(seen)
+
+
+def test_walk_counters_are_the_kernels_rule_on_the_live_rows():
+    """What the decode kernel's page walk fetches, counted at dispatch by
+    its own rule (`walk_run_counts`; the 16-wide table is one chunk). A
+    sequence that grows to 254 tokens on a fresh pool, which hands its 16
+    pages out ascending, fills the chunk only in its last calls: a run
+    then, page by page before. Its pages are freed whole and the free
+    list is a stack, so the next long row holds them descending: a run
+    again, beside a short row that fills no chunk. The counters follow
+    the rule on each live row of each call, and reach `engine_trace` as
+    `total` and `recent`."""
+    e = make_engine(decode_horizon=4)
+    want = [0, 0]
+    run_rows = set()
+    inner = e._decode_multi
+
+    def spy(params, d, horizon):
+        for s in e._running.values():
+            if not s.finished:
+                row = s.pages.all_pages
+                n, runs = walk_run_counts(
+                    row, -(-(s.context_len + 1) // 16), 16)
+                want[0] += n * horizon
+                want[1] += runs * horizon
+                if runs:
+                    run_rows.add(tuple(row))
+        return inner(params, d, horizon)
+
+    e._decode_multi = spy
+    # 20 + 234 tokens on 16 pages of its own, none donated: freed whole
+    run_requests(e, [_req("a", list(range(300, 320)), max_tokens=234)])
+    run_requests(e, [_req("b", list(range(500, 745)), max_tokens=9),
+                     _req("c", list(range(800, 840)), max_tokens=5)])
+    e.step()
+    c = e.telemetry.counters
+    assert (c["walk_chunks"], c["walk_run_chunks"]) == tuple(want)
+    assert 0 < c["walk_run_chunks"] < c["walk_chunks"]
+    assert run_rows == {tuple(range(1, 17)), tuple(range(16, 0, -1))}
+    trace = T.summarize([e.telemetry])
+    total, recent = trace["total"], trace["recent"]
+    assert (total["walk_chunks"], total["walk_run_chunks"]) == tuple(want)
+    assert 0 < recent["walk_run_chunks"] < recent["walk_chunks"] <= want[0]
 
 
 def test_prefill_behind_steps_is_what_was_in_flight_at_the_dispatch():
@@ -279,7 +323,7 @@ def _ticked(seconds: int, per_second: int = 1):
         for k in range(per_second):
             clock.t = s + (k + 0.5) / per_second
             tel.admitted(100, 64, 32, 5.0 + s, 20.0)
-            tel.decode_dispatched(8, 8, 3, 900, 70)
+            tel.decode_dispatched(8, 8, 3, 900, 70, 21, 15)
             tel.decode_fetched(8, 3, 900, 25.0)
         clock.t = s + 1.0
         tel.tick()
@@ -444,6 +488,8 @@ def test_stats_metrics_and_the_prefill_span_carry_the_record(cluster):  # noqa: 
     assert {"queue_ms", "prefill_ms"} <= set(recent)
     assert stats["sarathi_rides"] == total["sarathi_rides"]
     assert "prefill_behind_steps" in total and "prefill_behind_steps" in recent
+    assert 0 <= total["walk_run_chunks"] <= total["walk_chunks"] > 0
+    assert recent["walk_chunks"] <= total["walk_chunks"]
     # the look-ahead rule as it stands, one entry an engine
     (rule,) = stats["look_ahead"]
     assert set(rule) == {"turnaround_ms", "call_ms", "ahead"}
@@ -454,6 +500,7 @@ def test_stats_metrics_and_the_prefill_span_carry_the_record(cluster):  # noqa: 
                  "engine_preemptions_total 0", "engine_sarathi_rides_total ",
                  'engine_host_seconds_total{phase="fetch_wait"} ',
                  "engine_prefill_behind_steps_total ",
+                 "engine_walk_chunks_total ", "engine_walk_run_chunks_total ",
                  'engine_decode_calls_total{horizon="',
                  'engine_prefill_calls_total{bucket="'):
         assert "\n" + line in text, line

@@ -12,9 +12,57 @@ from xllm_service_tpu.ops.attention import (
     paged_attention_xla,
     write_kv,
 )
+from xllm_service_tpu.ops.page_walk import page_chunk_size, walk_run_counts
 from xllm_service_tpu.ops.pallas_paged_attention import paged_attention_pallas
 
 LAYERS = 3          # every case reads/writes one layer of a 3-layer pool
+
+
+def _up(first, n):
+    return list(range(first, first + n))
+
+
+def _down(first, n):
+    return list(range(first, first - n, -1))
+
+
+# name -> (rows, kernel options); a row is (pool pages in table order,
+# context length, (chunks walked, chunks fetched as a run)) over a 64-page
+# pool, pages of 16 tokens and chunks of 16 pages.
+RUN_TABLES = {
+    "one-ascending-run": ([(_up(1, 40), 640, (3, 2)),
+                           (_up(20, 32), 497, (2, 2))], {}),
+    # the last page holds 5 tokens and lies FIRST in its reversed chunk
+    "one-descending-run": ([(_down(63, 32), 31 * 16 + 5, (2, 2)),
+                            (_down(40, 40), 640, (3, 2))], {}),
+    "scattered": ([(list(range(1, 64, 2)), 512, (2, 0)),
+                   (list(range(62, 0, -2)), 16 * 30 + 1, (2, 0))], {}),
+    "broken-inside-a-chunk": (
+        [(_up(1, 16) + _up(17, 6) + _up(30, 10), 512, (2, 1)),
+         (_down(60, 16) + _down(44, 5) + _down(30, 11), 508, (2, 1))], {}),
+    "turns-from-up-to-down": (
+        [(_up(10, 16) + _down(60, 16) + _up(30, 4) + _down(29, 4), 640,
+          (3, 2)),
+         (_down(17, 16) + _up(33, 16), 506, (2, 2)),
+         (_up(1, 8) + _down(40, 8), 256, (1, 0))], {}),
+    "ends-in-a-partial-chunk": ([(_up(1, 21), 21 * 16 - 3, (2, 1)),
+                                 (_down(63, 19), 19 * 16, (2, 1)),
+                                 (_up(30, 9), 129, (1, 0))], {}),
+    "one-page": ([(_up(7, 1), 5, (1, 0)), (_up(63, 1), 16, (1, 0))], {}),
+    "touching-the-pools-last-page": ([(_up(48, 16), 256, (1, 1)),
+                                      (_down(63, 16), 248, (1, 1)),
+                                      (_up(32, 32), 512, (2, 2))], {}),
+    "run-and-per-page-rows-in-one-batch": (
+        [(_up(1, 16), 256, (1, 1)), (list(range(1, 33, 2)), 256, (1, 0)),
+         ([], 0, (0, 0)), (_down(40, 32) + [3, 9], 16 * 33 + 7, (3, 2))],
+        {}),
+    # the window's lower edge falls inside a reversed chunk
+    "descending-run-under-a-window": (
+        [(_down(63, 32), 32 * 16 - 6, (2, 2)),
+         (_up(1, 32), 32 * 16 - 6, (2, 2)),
+         (_down(40, 16) + _up(41, 16), 512, (2, 2))],
+        {"window": 100, "softcap": 30.0}),
+}
 
 
 def _setup(B=4, n_q=8, n_kv=4, hd=128, pages=32, ps=16, max_pages=6, seed=0):
@@ -91,18 +139,42 @@ class TestPallasPagedAttention:
                                            np.asarray(ref[b]),
                                            rtol=2e-5, atol=2e-5)
 
-    @pytest.mark.parametrize("chunk", ["1", "16"])
-    def test_page_chunk_override_matches_xla(self, chunk, monkeypatch):
-        """XLLM_PAGE_CHUNK (the arm PR 30's A/B kept): one page a chunk
-        maximises chunk turns and slot swaps, 16 is clamped to the
-        table's 6 pages."""
-        monkeypatch.setenv("XLLM_PAGE_CHUNK", chunk)
-        q, pool, pt = _setup()
-        context_lens = [1, 17, 33, 90]
-        cl = jnp.asarray(context_lens, jnp.int32)
+    @pytest.mark.parametrize("name", list(RUN_TABLES))
+    def test_run_tables_match_xla(self, name):
+        """The walk fetches a full chunk of 16 adjacent pool pages, up or
+        down, with one DMA a side and every other page alone: each table
+        gives the gather's answer, and the host's rule (what the engine's
+        telemetry counts) names the path each chunk takes."""
+        rows, opts = RUN_TABLES[name]
+        width = 40
+        q, pool, _ = _setup(B=len(rows), pages=64, max_pages=width)
+        pt = np.zeros((len(rows), width), np.int32)     # page 0: garbage
+        for b, (pages, _, _) in enumerate(rows):
+            pt[b, :len(pages)] = pages
+        cl = jnp.asarray([ctx for _, ctx, _ in rows], jnp.int32)
+        assert page_chunk_size(width) == 16
+        for b, (pages, ctx, want) in enumerate(rows):
+            assert walk_run_counts(pt[b], -(-ctx // 16), 16) == want, b
+        ref = paged_attention_xla(q, pool, 1, jnp.asarray(pt), cl, **opts)
+        got = _kernel(q, pool, 1, jnp.asarray(pt), cl, **opts)
+        for b, (_, ctx, _) in enumerate(rows):
+            if ctx > 0:
+                np.testing.assert_allclose(np.asarray(got[b]),
+                                           np.asarray(ref[b]),
+                                           rtol=2e-5, atol=2e-5)
+
+    def test_a_narrow_table_clamps_the_chunk(self):
+        """A table narrower than 16 pages: the chunk is the table, and a
+        row that fills it with adjacent pages is still one run."""
+        assert [page_chunk_size(n) for n in (1, 6, 16, 96)] == [1, 6, 16, 16]
+        q, pool, pt = _setup()              # rows 1..6, 7..12, ...: runs
+        assert walk_run_counts(np.asarray(pt[0]), 6, 6) == (1, 1)
+        assert walk_run_counts(np.asarray(pt[0]), 5, 6) == (1, 0)
+        down = pt[:, ::-1]
+        cl = jnp.asarray([96, 90, 81, 96], jnp.int32)
         np.testing.assert_allclose(
-            np.asarray(_kernel(q, pool, 2, pt, cl)),
-            np.asarray(paged_attention_xla(q, pool, 2, pt, cl)),
+            np.asarray(_kernel(q, pool, 2, down, cl)),
+            np.asarray(paged_attention_xla(q, pool, 2, down, cl)),
             rtol=2e-5, atol=2e-5)
 
     def test_span_bucketed_xla_gather_parity(self, monkeypatch):

@@ -65,6 +65,7 @@ from ..common.types import KvCacheEvent
 from ..devtools import ownership as _ownership
 from ..devtools.locks import make_lock
 from ..models.base import get_model_family
+from ..ops.page_walk import page_chunk_size, walk_run_counts
 from ..parallel.mesh import build_mesh
 from ..parallel.sharding import shard_params
 from ..tokenizer.base import Tokenizer
@@ -2571,13 +2572,23 @@ class InferenceEngine:
 
     def _count_decode_call(self, key, steps: int, snapshot: dict) -> None:
         """Telemetry of one dispatched decode call (O(batch)): the
-        sequences it serves, their context and the pages they hold."""
-        context = pages = 0
+        sequences it serves, their context, the pages they hold, and how
+        the decode kernel's page walk fetches them (its chunks, and those
+        that are one run of adjacent pool pages: the kernel's own rule on
+        the table rows as the call's first step walks them)."""
+        context = pages = chunks = run_chunks = 0
+        ps = self.cfg.page_size
+        chunk = page_chunk_size(self.cfg.pages_per_seq)
         for seq in snapshot.values():
             context += seq.context_len
-            pages += len(seq.pages.cached_pages) + len(seq.pages.own_pages)
+            row = seq.pages.all_pages
+            pages += len(row)
+            walked = min(-(-(seq.context_len + 1) // ps), len(row))
+            n, n_run = walk_run_counts(row, walked, chunk)
+            chunks += n
+            run_chunks += n_run
         self.telemetry.decode_dispatched(key, steps, len(snapshot), context,
-                                         pages)
+                                         pages, chunks, run_chunks)
 
     def _sample_decode_call(self, horizon: int, snapshot: dict,
                             ms_per_tok: float) -> None:

@@ -31,7 +31,11 @@ horizon, reason or phase); `summarize` nests them for output:
               the live sequences' `context_len` at dispatch x horizon: the
               host's count lags the step in flight and does not grow inside
               a call, so this is a LOWER bound of the tokens the attention
-              kernel read), sarathi_rides
+              kernel read), sarathi_rides, walk_chunks / walk_run_chunks
+              (the chunks of 16 table entries the decode kernel's page
+              walk fetches for the live rows at dispatch x horizon, and
+              those of them it fetches as one run of adjacent pool pages:
+              `ops/page_walk.walk_run_counts`, the kernel's own rule)
   pages       sampled once per decode call, weighted by its horizon so that
               x / decode_steps is a mean per step: pages_reserved_steps
               (pages held by live sequences, a shared prefix page once per
@@ -73,7 +77,7 @@ _SCALARS = (
     "prefix_onload_tokens", "prefill_padded_tokens", "preemptions",
     "cancelled", "finished", "prefill_behind_steps", "decode_steps",
     "live_slot_steps", "context_token_steps", "sarathi_rides",
-    "pages_reserved_steps")
+    "pages_reserved_steps", "walk_chunks", "walk_run_chunks")
 
 
 class AdmissionSample(NamedTuple):
@@ -175,13 +179,16 @@ class EngineTelemetry:
         return sample
 
     def decode_dispatched(self, key: Any, steps: int, live: int,
-                          context_tokens: int, pages_reserved: int) -> None:
+                          context_tokens: int, pages_reserved: int,
+                          walk_chunks: int, walk_run_chunks: int) -> None:
         c = self.counters
         self.count_by("decode_calls", key)
         c["decode_steps"] += steps
         c["live_slot_steps"] += live * steps
         c["context_token_steps"] += context_tokens * steps
         c["pages_reserved_steps"] += pages_reserved * steps
+        c["walk_chunks"] += walk_chunks * steps
+        c["walk_run_chunks"] += walk_run_chunks * steps
 
     def decode_fetched(self, horizon: int, live: int, context_tokens: int,
                        ms_per_tok: float) -> None:

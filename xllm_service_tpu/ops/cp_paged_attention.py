@@ -38,12 +38,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
+from .page_walk import page_chunk_size
 from .pallas_page_dma import (
     NEG_INF,
     chunked_page_walk,
     flash_accumulate,
-    masked_kv_f32_pos,
-    page_chunk_size,
+    masked_kv_f32,
 )
 
 _NEG_INF = NEG_INF
@@ -122,9 +122,10 @@ def _partial_kernel(local_pt_ref, starts_ref, n_local_ref, clens_ref,
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def compute(c, slot):
+    def compute(c, slot, d):
         # Per-row global token positions: compacted pages are not
-        # contiguous, so each page contributes start_j + iota(ps).
+        # contiguous, so each page contributes start_j + iota(ps). A chunk
+        # the walk fetched downwards holds its entries in reverse.
         # Built once per orientation from an iota and `chunk` scalar
         # selects: Mosaic has no layout for reshaping a [chunk, ps] i32
         # tile into [1, span] / [span, 1].
@@ -135,14 +136,15 @@ def _partial_kernel(local_pt_ref, starts_ref, n_local_ref, clens_ref,
         pos_row = jnp.full((1, span), ctx, jnp.int32)
         pos_col = jnp.full((span, 1), ctx, jnp.int32)
         for j in range(chunk):
-            # Chunk-padding entries (base+j >= n_pages) were never
+            entry = base + jnp.where(d < 0, chunk - 1 - j, j)
+            # Chunk-padding entries (entry >= n_pages) were never
             # DMA'd — their buffer rows are stale. Position them at
             # ctx so both masks reject them (clamping the table read
             # instead would alias a REAL page's positions and let
             # stale K/V through).
             st = jnp.where(
-                base + j < n_pages,
-                starts_ref[b, jnp.minimum(base + j, max_pages - 1)],
+                entry < n_pages,
+                starts_ref[b, jnp.minimum(entry, max_pages - 1)],
                 ctx)
             lo, hi = j * page_size, (j + 1) * page_size
             pos_row = jnp.where((lane >= lo) & (lane < hi),
@@ -153,8 +155,7 @@ def _partial_kernel(local_pt_ref, starts_ref, n_local_ref, clens_ref,
         q = q_ref[0].astype(jnp.float32) * scale     # [n_q, hd]
         for kv in range(n_kv):
             qh = q[kv * group:(kv + 1) * group, :]   # [G, hd]
-            k, v = masked_kv_f32_pos(k_buf, v_buf, slot, kv,
-                                     pos_col, ctx)
+            k, v = masked_kv_f32(k_buf, v_buf, slot, kv, pos_col, ctx)
             s = jax.lax.dot_general(
                 qh, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)  # [G, span]
@@ -174,10 +175,7 @@ def _paged_partial_pallas(q, k_pages, v_pages, local_pt, starts, n_local,
                           context_lens, scale: float,
                           interpret: bool = False):
     """Per-shard raw flash stats: returns (m [B, n_q, 128],
-    l [B, n_q, 128], acc [B, n_q, hd]) — only column 0 of m/l is live.
-
-    XLLM_PAGE_CHUNK is resolved here, OUTSIDE jit, and passed static — a
-    shape-keyed cache would silently pin the first-traced chunk."""
+    l [B, n_q, 128], acc [B, n_q, hd]) — only column 0 of m/l is live."""
     return _paged_partial_impl(q, k_pages, v_pages, local_pt, starts,
                                n_local, context_lens, scale=scale,
                                chunk=page_chunk_size(local_pt.shape[1]),

@@ -1,9 +1,15 @@
 """Shared scaffolding for the paged-attention Pallas kernels (decode,
 context-parallel partial):
 
-- `make_chunk_dma`: a 2-slot VMEM ring of `chunk`-page blocks, one async
-  copy per page (pages are non-contiguous in HBM), waits batched per
-  chunk;
+- `make_chunk_dma`: a 2-slot VMEM ring of `chunk`-page blocks. A full
+  chunk whose pool pages are adjacent, ascending or descending, is ONE
+  async copy per side (`pool[lowest : lowest + chunk]`), whichever way it
+  runs; any other chunk is one copy per page. The chunk's direction comes
+  back to the kernel, which takes the token positions of a descending
+  chunk's rows in reverse (`chunk_token_offsets`);
+- the same rule over a host page-table row is `page_walk.walk_run_counts`
+  (what the engine's telemetry counts, and the tests' oracle of which
+  path a chunk takes);
 - `masked_kv_f32` / `flash_accumulate`: the per-head chunk read and the
   online-softmax (flash) m/l/acc update.
 
@@ -20,87 +26,121 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def page_chunk_size(max_pages: int, default: int = 8) -> int:
-    """Pages per double-buffered DMA chunk in the paged-attention
-    kernels. Bigger chunks mean fewer, larger DMAs — the decode walk is
-    DMA-latency-bound at serving shapes (B rows x ~pages/chunk waits per
-    layer), so this is a first-order knob. XLLM_PAGE_CHUNK overrides for
-    on-chip A/B; VMEM cost is 4 * chunk * n_kv * ps * hd bytes (two
-    k/v double buffers)."""
-    import os
-
-    try:
-        v = int(os.environ.get("XLLM_PAGE_CHUNK", "") or default)
-    except ValueError:
-        v = default
-    return max(1, min(v, max_pages))
-
-
 def make_chunk_dma(page_table_ref, b, n_pages, chunk,
                    k_hbm, v_hbm, k_buf, v_buf, sems):
-    """Returns (start_chunk(slot, c), wait_chunk(slot, c))."""
+    """Returns (probe(c), start_chunk(slot, c, run), wait_chunk(slot, c,
+    run)). ``probe`` reads chunk c's table entries once and gives
+    (d, lowest): d = +1 / -1 where the chunk is a run up / down from its
+    first page, 0 where it goes page by page; start and wait take that one
+    answer, so the bytes waited for are the bytes started."""
+    last = page_table_ref.shape[1] - 1
 
-    def start_chunk(slot, c):
+    def probe(c):
         base = c * chunk
-        for j in range(chunk):
-            p = base + j
+        # Reads past the table are clamped and cannot count: such a chunk
+        # is not full.
+        first = page_table_ref[b, jnp.minimum(base, last)]
+        up = down = base + chunk <= n_pages
+        for j in range(1, chunk):
+            page = page_table_ref[b, jnp.minimum(base + j, last)]
+            up &= page == first + j
+            down &= page == first - j
+        d = jnp.where(up, 1, jnp.where(down, -1, 0))
+        return d, jnp.where(d < 0, first - (chunk - 1), first)
 
-            @pl.when(p < n_pages)
-            def _():
-                page = page_table_ref[b, p]
-                pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot, j],
-                                      sems.at[slot, 0]).start()
-                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, j],
-                                      sems.at[slot, 1]).start()
+    def each_copy(slot, c, run, act):
+        d, lowest = run
 
-    def wait_chunk(slot, c):
-        base = c * chunk
-        for j in range(chunk):
-            p = base + j
+        @pl.when(d != 0)
+        def _run():
+            act(pltpu.make_async_copy(
+                k_hbm.at[pl.ds(lowest, chunk)], k_buf.at[slot],
+                sems.at[slot, 0]))
+            act(pltpu.make_async_copy(
+                v_hbm.at[pl.ds(lowest, chunk)], v_buf.at[slot],
+                sems.at[slot, 1]))
 
-            @pl.when(p < n_pages)
-            def _():
-                page = page_table_ref[b, p]
-                pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot, j],
-                                      sems.at[slot, 0]).wait()
-                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, j],
-                                      sems.at[slot, 1]).wait()
+        @pl.when(d == 0)
+        def _pages():
+            for j in range(chunk):
+                p = c * chunk + j
 
-    return start_chunk, wait_chunk
+                @pl.when(p < n_pages)
+                def _():
+                    page = page_table_ref[b, p]
+                    act(pltpu.make_async_copy(
+                        k_hbm.at[page], k_buf.at[slot, j],
+                        sems.at[slot, 0]))
+                    act(pltpu.make_async_copy(
+                        v_hbm.at[page], v_buf.at[slot, j],
+                        sems.at[slot, 1]))
+
+    def start_chunk(slot, c, run):
+        each_copy(slot, c, run, lambda copy: copy.start())
+
+    def wait_chunk(slot, c, run):
+        each_copy(slot, c, run, lambda copy: copy.wait())
+
+    return probe, start_chunk, wait_chunk
 
 
 def chunked_page_walk(page_table_ref, b, n_pages, chunk,
                       k_hbm, v_hbm, k_buf, v_buf, sems, compute, c_lo=0):
     """Run the double-buffered page walk for grid row ``b``, calling
-    ``compute(c, slot)`` per chunk: chunk c+1 loads while chunk c
-    computes; each row pays one cold-start DMA stall.
+    ``compute(c, slot, d)`` per chunk: chunk c+1 loads while chunk c
+    computes; each row pays one cold-start DMA stall. ``d`` is the chunk's
+    direction (`make_chunk_dma`): the rows of a chunk with direction -1
+    lie in the buffer in reverse page order.
 
     ``c_lo`` is the FIRST chunk to walk — a sliding-window decode
     (gemma-2 local layers) never needs pages wholly below ctx - window,
     so the walk can start there instead of chunk 0.
     """
     n_chunks = jnp.maximum(pl.cdiv(n_pages, chunk) - c_lo, 0)
-    start_chunk, wait_chunk = make_chunk_dma(
+    probe, start_chunk, wait_chunk = make_chunk_dma(
         page_table_ref, b, n_pages, chunk, k_hbm, v_hbm, k_buf, v_buf,
         sems)
 
     @pl.when(n_chunks > 0)
     def _run():
-        start_chunk(0, c_lo)
+        first = probe(c_lo)
+        start_chunk(0, c_lo, first)
 
-        def body(i, _):
+        def body(i, run):
             c = c_lo + i
             slot = jax.lax.rem(i, 2)
+            # Past the last chunk no chunk is full: nothing is started.
+            ahead = probe(c + 1)
 
             @pl.when(i + 1 < n_chunks)
             def _prefetch():
-                start_chunk(1 - slot, c + 1)
+                start_chunk(1 - slot, c + 1, ahead)
 
-            wait_chunk(slot, c)
-            compute(c, slot)
-            return ()
+            wait_chunk(slot, c, run)
+            compute(c, slot, run[0])
+            return ahead
 
-        jax.lax.fori_loop(0, n_chunks, body, ())
+        jax.lax.fori_loop(0, n_chunks, body, first)
+
+
+def token_offset_maps(chunk: int, page_size: int, shape, dim: int):
+    """What `chunk_token_offsets` needs of a chunk's buffer rows, none of
+    it depending on the chunk: build it once per kernel body, outside the
+    walk. (idx, flip): each row's own index along ``dim`` of an int32
+    ``shape`` (span = chunk * page_size long), its token offset where the
+    chunk's pages lie in table order; and what a row adds where the chunk
+    was fetched downwards, so that page j takes the offsets of page
+    chunk-1-j."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+    page_start = idx - jax.lax.rem(idx, page_size)
+    return idx, (chunk - 1) * page_size - 2 * page_start
+
+
+def chunk_token_offsets(maps, d):
+    """Each buffer row's token offset inside its chunk, for the walk's
+    direction ``d`` (-1: the chunk's pages lie reversed)."""
+    idx, flip = maps
+    return idx + flip * (d < 0).astype(jnp.int32)
 
 
 # --------------------------------------------------------------- page movers
@@ -218,26 +258,13 @@ def scatter_kv_pages(kv, page_ids, block):
     )(page_ids, block, kv)
 
 
-def masked_kv_f32(k_buf, v_buf, slot, kv, start, bound):
+def masked_kv_f32(k_buf, v_buf, slot, kv, pos_col, bound):
     """Read one KV head's chunk from the ring as f32 ``[span, hd]``,
     zeroing V rows at positions >= ``bound``: their probabilities are 0,
     but 0 x garbage from never-DMA'd (or concurrently written) sub-buffers
-    must not reach the accumulator (0 x NaN = NaN). Column-oriented iota
-    (Mosaic cannot transpose 1-bit vectors)."""
-    k = k_buf[slot, :, kv].astype(jnp.float32)
-    span = k.shape[0] * k.shape[1]
-    k = k.reshape(span, -1)
-    v = v_buf[slot, :, kv].astype(jnp.float32).reshape(span, -1)
-    vmask = (start + jax.lax.broadcasted_iota(
-        jnp.int32, (span, 1), 0)) < bound
-    return k, jnp.where(vmask, v, 0.0)
-
-
-def masked_kv_f32_pos(k_buf, v_buf, slot, kv, pos_col, bound):
-    """`masked_kv_f32` for NON-contiguous chunk pages (the CP partial
-    kernel walks a compacted list of locally-owned pages, so row
-    positions come as an explicit column vector ``pos_col: [span, 1]``
-    instead of start+iota)."""
+    must not reach the accumulator (0 x NaN = NaN). Row positions come as
+    a column vector ``pos_col: [span, 1]`` (Mosaic cannot transpose 1-bit
+    vectors): a chunk's rows need not lie in token order."""
     k = k_buf[slot, :, kv].astype(jnp.float32)
     span = k.shape[0] * k.shape[1]
     k = k.reshape(span, -1)

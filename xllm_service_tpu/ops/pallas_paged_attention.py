@@ -13,8 +13,10 @@ Design (v2 — manual double-buffered DMA):
   A scalar and not a static int: every layer's call is then the same
   traced kernel. The kernel
   walks only the pages the sequence actually occupies (`cdiv(ctx, ps)` —
-  a *dynamic* trip count, unlike a grid dimension) and DMAs each page into
-  a 2-slot VMEM scratch ring, prefetching page i+1 while computing page i.
+  a *dynamic* trip count, unlike a grid dimension) in chunks of 16 through
+  a 2-slot VMEM scratch ring, chunk i+1 loading while chunk i computes. A
+  full chunk whose pool pages are adjacent, up or down, is one DMA per
+  side; other pages are one each (ops/pallas_page_dma.py).
 - layer id, page table + context lengths are scalar-prefetch operands
   (SMEM) so DMA source addresses are computable before compute starts.
 - online-softmax accumulation (flash-style m/l/acc) in VMEM scratch; GQA
@@ -37,12 +39,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .page_walk import page_chunk_size
 from .pallas_page_dma import (
     NEG_INF as _NEG_INF,
+    chunk_token_offsets,
     chunked_page_walk,
     flash_accumulate,
     masked_kv_f32,
-    page_chunk_size,
+    token_offset_maps,
 )
 
 
@@ -63,11 +67,16 @@ def _kernel(layer_ref, page_table_ref, context_lens_ref,   # SMEM prefetch
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def compute(c, slot):
-        span = chunk * page_size
+    span = chunk * page_size
+    row_maps = token_offset_maps(chunk, page_size, (1, span), 1)
+    col_maps = token_offset_maps(chunk, page_size, (span, 1), 0)
+
+    def compute(c, slot, d):
         start = c * span
-        token_pos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (1, span), 1)
+        # A chunk fetched downwards lies in the buffer in reverse page
+        # order; the masks follow it, nothing else depends on key order.
+        token_pos = start + chunk_token_offsets(row_maps, d)
+        pos_col = start + chunk_token_offsets(col_maps, d)
         mask = token_pos < ctx
         if window > 0:
             # gemma-2 sliding window: the query sits at position ctx-1,
@@ -76,7 +85,7 @@ def _kernel(layer_ref, page_table_ref, context_lens_ref,   # SMEM prefetch
         q = q_ref[0].astype(jnp.float32) * scale           # [n_q, hd]
         for kv in range(n_kv):
             qh = q[kv * group:(kv + 1) * group, :]         # [G, hd]
-            k, v = masked_kv_f32(k_buf, v_buf, slot, kv, start, ctx)
+            k, v = masked_kv_f32(k_buf, v_buf, slot, kv, pos_col, ctx)
             s = jax.lax.dot_general(
                 qh, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)        # [G, span]
@@ -116,11 +125,6 @@ def paged_attention_pallas(q: jax.Array, pool: jax.Array,
     scale/softcap/window cover the gemma-2 extras (explicit query scale,
     score soft-capping, sliding window) so that family decodes through
     this kernel instead of the full-span XLA gather.
-
-    XLLM_PAGE_CHUNK is resolved HERE (outside jit) and passed as a static
-    arg — a jit cache keyed only on shapes would silently pin the
-    first-traced chunk for the whole process, defeating in-process A/Bs
-    and tests.
     """
     return _paged_attention_impl(q, pool, layer, page_table,
                                  context_lens,
