@@ -9,7 +9,9 @@ chip would refuse is refused here, and `memory_analysis()` says whether a
 program fits the chip's HBM. Nothing executes: a pass is not a chip run.
 
 Arms are the Pallas kernels the served path can reach at Llama-3-8B head
-shapes (32 q / 8 kv heads, head_dim 128, pages of 16), the tensor-parallel
+shapes (32 q / 8 kv heads, head_dim 128, pages of 16) and at the benchmark's
+configurations' (the state-update kernel of its state-space family among
+them), the tensor-parallel
 form of the decode kernel on the 4-device mesh, and the engine's own
 decode / prefill-install programs built by `InferenceEngine._build_programs`
 on a shell engine that holds shapes only. The dispatch gates in
@@ -213,12 +215,30 @@ def kernel_arms(devices):
     # run copy in them).
     yield "paged_qwen25_7b", paged(32, 96, 2048, heads=(28, 4))
     yield "paged_qwen25_3b", paged(32, 128, 4096, heads=(16, 2))
+    # granite-4.0-h-micro's four attention layers: a group of 4, heads of
+    # 64 held at the lane width, a table of 64 pages.
+    yield "paged_granite_h_micro", paged(32, 64, 2048, heads=(32, 8),
+                                         scale=1 / 64)
+
+    def ssm_update():
+        # The state-update kernel at granite-4.0-h-micro's widths: 36
+        # layers x 32 slots of [128, 4096] float32, updated in place.
+        from xllm_service_tpu.ops.pallas_ssm_update import ssm_update_pallas
+
+        L, B, N, K = 36, 32, 128, 4096
+        f32 = jnp.float32
+        return jax.jit(ssm_update_pallas, donate_argnums=(0,)).lower(
+            f((L, B, N, K), f32), f((), i32), f((B,), jnp.bool_),
+            f((B, K), f32), f((B, K), f32), f((B, N), f32),
+            f((B, N), f32)).compile()
+
+    yield "ssm_update_granite_h_micro", ssm_update
 
     def mover(which):
         from xllm_service_tpu.ops import pallas_page_dma as dma
 
         def thunk():
-            L, n = mcfg.num_layers, 8
+            L, n = mcfg.kv_layers, 8
             kv = f((L, 2, 1024, n_kv, ps, hd), bf16)
             ids = f((n,), i32)
             with steer_to_tpu():

@@ -53,7 +53,8 @@ def chip():
 
 @pytest.mark.parametrize("arm", [
     "paged_b16", "paged_b64", "gemma2_softcap", "gemma2_window",
-    "paged_qwen25_7b", "paged_qwen25_3b", "page_gather_l32",
+    "paged_qwen25_7b", "paged_qwen25_3b", "paged_granite_h_micro",
+    "ssm_update_granite_h_micro", "page_gather_l32",
     "page_scatter_l32", "cp_partial_stats", "paged_shard_map_tp4"])
 def test_kernel_compiles_for_v5e(chip, arm):
     """Each served-path Pallas kernel at Llama-3-8B head shapes (the
@@ -106,6 +107,29 @@ def test_decode_step_holds_no_second_pool(chip):
                 * ecfg.page_size * m.head_dim * 2) / 2 ** 30
     assert prog["tpu_custom_calls"] == m.num_layers
     assert prog["temp_gib"] < 0.25 * pool_gib
+
+
+def test_state_space_decode_step_keeps_its_state_in_place(chip):
+    """`granite-4.0-h-micro` as the benchmark serves it (40 layers, B 32,
+    horizon 8): one kernel call a layer (36 state updates, 4 paged
+    attentions), both on `pallas`, and temporaries that are a small part
+    of the 2.25 GiB of per-slot state: no copy of the state, of the pool
+    (4 planes, keys held at 128 lanes) or of a weight stack."""
+    from chipbench.engine_setup import build_engine_config
+
+    name = "granite-4.0-h-micro"
+    ecfg, _ = build_engine_config(REPO / "chipbench" / "configs" / name, 0,
+                                  name)
+    m = ecfg.model
+    assert (m.kv_layers, m.kv_head_dim, m.num_layers) == (4, 128, 40)
+    out = gate.compile_engine_programs(ecfg, device=chip[0],
+                                       horizons=(ecfg.decode_horizon,),
+                                       buckets=())
+    prog = out[f"decode_multi_h{ecfg.decode_horizon}"]
+    assert prog["tpu_custom_calls"] == m.num_layers
+    assert prog["fits_hbm"] and prog["temp_gib"] < 0.25
+    assert out["attention_paths"]["decode_multi"] == {
+        "paged_attention": "pallas", "ssm_update": "pallas"}
 
 
 def test_decode_step_full_width_tp4(chip):

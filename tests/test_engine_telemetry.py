@@ -285,13 +285,20 @@ def test_phase_seconds_sum_to_the_loops_wall_time():
 
 def test_the_six_phases_land_in_a_profiler_trace(tmp_path):
     """A `jax.profiler` session on the CPU: the pump's thread line holds
-    the six `engine.*` annotations, read back by chipbench/hostspans.py."""
+    the six `engine.*` annotations and the `engine.decode_live.<n>.<h>`
+    markers, read back by chipbench/hostspans.py."""
     import jax
 
     from chipbench import hostspans, xplane
 
     e = make_engine(decode_horizon=4)
     run_requests(e, [_req("warm")])      # compile outside the session
+
+    def calls():
+        return sum(e.telemetry.counters.get(f"decode_calls/{h}", 0)
+                   for h in (1, 2, 4))
+
+    before = calls()
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 2
@@ -308,7 +315,16 @@ def test_the_six_phases_land_in_a_profiler_trace(tmp_path):
     spans = hostspans.load_spans(xplane.find_xplane(tmp_path))
     assert len(spans) == 1               # one pump thread
     (line,) = spans.values()
-    assert {s["name"] for s in line} == set(T.PHASES)
+    names = {s["name"] for s in line}
+    assert set(T.PHASES) <= names
+    # beside them one marker per landed decode call, entered and left at
+    # once: the sequences it was dispatched for and its steps
+    marks = [s for s in line if s["name"] not in T.PHASES]
+    assert marks and {s["name"] for s in marks} <= {
+        f"decode_live.1.{h}" for h in (1, 2, 4)}
+    assert "decode_live.1.4" in names and max(s["dur"] for s in marks) < 1e-4
+    # (one landing a dispatch; the warm request's last call may land here)
+    assert 0 <= len(marks) - (calls() - before) <= 1
     pieces = hostspans.exclusive(line)
     assert all(a < b for a, b, _ in pieces)
     assert all(p[1] <= q[0] + 1e-9 for p, q in zip(pieces, pieces[1:]))

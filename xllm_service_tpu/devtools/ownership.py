@@ -348,6 +348,13 @@ STATE_DISCIPLINES: dict[str, str] = {
     # the pump alone (no lock on the write path); other threads copy and
     # compute their views on read.
     "InferenceEngine.telemetry": "init-only",
+    # Names of the model family's own per-slot state buffers in the decode
+    # state (a recurrent state per sequence; empty for most families):
+    # decided by the family and the config, read by admission.
+    "InferenceEngine._slot_state_keys": "init-only",
+    # The devices the engine holds, recorded at construction for `/stats`
+    # (the decode state's arrays are deleted while a call holds them).
+    "InferenceEngine._devices": "immutable",
     "EngineTelemetry._phase": "confined:engine-pump",
     "EngineTelemetry._t_phase": "confined:engine-pump",
     "EngineTelemetry._t_snapshot": "confined:engine-pump",
@@ -449,6 +456,9 @@ THREAD_ROLES: dict[str, dict] = {
             # the static call graph does not follow.
             "EngineTelemetry.switch",
             "EngineTelemetry.tick",
+            # The `engine.decode_live.<n>.<h>` trace marker's emitter
+            # (`_land_decode`, on the pump).
+            "EngineTelemetry.mark_decode_landed",
         ),
     },
     "profiler": {

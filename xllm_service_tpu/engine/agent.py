@@ -674,8 +674,10 @@ class EngineAgent:
             kv_page_size=ecfg.page_size,
             kv_dtype=str(mcfg.dtype.__name__ if hasattr(mcfg.dtype, "__name__")
                          else mcfg.dtype),
-            num_layers=mcfg.num_layers, num_kv_heads=mcfg.num_kv_heads,
-            head_dim=mcfg.head_dim,
+            # The KV pool's layout (what a peer's pages must match): its
+            # planes and the width a key is held at.
+            num_layers=mcfg.kv_layers, num_kv_heads=mcfg.num_kv_heads,
+            head_dim=mcfg.kv_head_dim,
             max_context_len=ecfg.max_seq_len,
             incarnation_id=self.incarnation_id,
             register_ts_ms=int(time.time() * 1000),

@@ -61,7 +61,7 @@ from ..common.request import (
     Usage,
 )
 from ..common.hashing import prefix_block_hashes
-from ..common.types import KvCacheEvent
+from ..common.types import InstanceType, KvCacheEvent
 from ..devtools import ownership as _ownership
 from ..devtools.locks import make_lock
 from ..models.base import get_model_family
@@ -211,6 +211,16 @@ class _Sequence:
     decoded_ok: int = 0
 
 
+def slot_state_keys(cfg: EngineConfig) -> tuple[str, ...]:
+    """Names, in the decode state, of the per-slot buffers the model's
+    family brings itself (`ModelFamily.slot_state`); most bring none."""
+    slot_state = get_model_family(cfg.model_family).slot_state
+    if slot_state is None:
+        return ()
+    return tuple(jax.eval_shape(
+        lambda: slot_state(cfg.model, cfg.max_batch_size)))
+
+
 def new_decode_state(cfg: EngineConfig,
                      shardings: Optional[dict] = None) -> dict[str, jax.Array]:
     """The device-resident decode state, zeroed: on the default device,
@@ -219,10 +229,14 @@ def new_decode_state(cfg: EngineConfig,
         return jax.jit(lambda: new_decode_state(cfg),
                        out_shardings=shardings)()
     mcfg, B = cfg.model, cfg.max_batch_size
+    # A family's own per-slot buffers (a recurrent state per sequence),
+    # under the family's names, donated with the rest.
+    slot_state = get_model_family(cfg.model_family).slot_state
     return {
-        "kv": jnp.zeros((mcfg.num_layers, 2, cfg.num_pages,
-                         mcfg.num_kv_heads, cfg.page_size, mcfg.head_dim),
-                        mcfg.dtype),
+        **(slot_state(mcfg, B) if slot_state is not None else {}),
+        "kv": jnp.zeros((mcfg.kv_layers, 2, cfg.num_pages,
+                         mcfg.num_kv_heads, cfg.page_size,
+                         mcfg.kv_head_dim), mcfg.dtype),
         "counts": jnp.zeros((B, mcfg.vocab_size), jnp.int32),
         "last": jnp.zeros((B,), jnp.int32),
         "clens": jnp.zeros((B,), jnp.int32),
@@ -299,6 +313,7 @@ class InferenceEngine:
             getattr(self.tokenizer, "eos_id", None)
         self.family = get_model_family(cfg.model_family)
         mcfg = cfg.model
+        self._refuse_unsupported_for_slot_state()
 
         if params is None:
             # Random init (benchmarks / tests); real weights come through
@@ -358,8 +373,9 @@ class InferenceEngine:
 
             mc = cfg.model
             self.tier_store = TieredKVStore(
-                block_shape=(mc.num_layers, 2, self.page_mgr.pages_per_block,
-                             mc.num_kv_heads, cfg.page_size, mc.head_dim),
+                block_shape=(mc.kv_layers, 2, self.page_mgr.pages_per_block,
+                             mc.num_kv_heads, cfg.page_size,
+                             mc.kv_head_dim),
                 dtype=mc.dtype,
                 dram_bytes=cfg.kv_tier_dram_bytes,
                 ssd_bytes=cfg.kv_tier_ssd_bytes,
@@ -393,6 +409,13 @@ class InferenceEngine:
         self._dstate_shardings = self._decode_state_shardings()
         self._dstate: dict[str, jax.Array] = new_decode_state(
             cfg, self._dstate_shardings)
+        # The devices the engine holds, taken once: `/stats` reads them
+        # from the HTTP thread, and the pool itself is deleted (donated)
+        # for the length of every call the pump has in flight.
+        self._devices = tuple(sorted(self._dstate["kv"].devices(),
+                                     key=lambda d: d.id))
+        # The family's own per-slot buffers among them, by name.
+        self._slot_state_keys = slot_state_keys(cfg)
         # Which attention path each traced program took (ops/attention.py
         # `note_path`): {program: {op: path}}, filled at trace time.
         self._paths: dict[str, dict[str, str]] = {}
@@ -426,6 +449,8 @@ class InferenceEngine:
         # per-decode-call samples (the agent fits its SLO profiling tables
         # and `/stats`.ttft_spans from them): engine/telemetry.py.
         self.telemetry = EngineTelemetry()
+        self.telemetry.counters["state_bytes_reserved"] = sum(
+            self._dstate[k].nbytes for k in self._slot_state_keys)
         # Decode pipeline: the last dispatched decode call whose tokens
         # have not been emitted yet. Host-side output processing of call
         # k overlaps the device executing call k+1 either way; whether
@@ -452,6 +477,31 @@ class InferenceEngine:
         # Last: warmup reads `_pressure_span_chunks`, set above.
         if cfg.warmup_programs:
             self._warmup_programs()
+
+    def _refuse_unsupported_for_slot_state(self) -> None:
+        """A family with per-slot state of its own (`ModelFamily.
+        slot_state`: a recurrent state per sequence) cannot take what
+        moves or reuses keys without that state. Refused at start, with
+        the reason; nothing stands in for it."""
+        cfg = self.cfg
+        if self.family.slot_state is None:
+            return
+        why = (f"model family {cfg.model_family!r} keeps per-slot recurrent "
+               "state beside the KV pool: ")
+        if cfg.role != InstanceType.MIX:
+            raise ValueError(
+                why + f"role {cfg.role.value} is refused (a PD handoff "
+                "carries KV pages only; the state at the prompt's end "
+                "would be lost); run it as MIX")
+        if cfg.prefill_chunk_tokens > 0:
+            raise ValueError(
+                why + f"prefill_chunk_tokens={cfg.prefill_chunk_tokens} is "
+                "refused (a prefill chunk does not carry the state of the "
+                "chunk before it); use 0")
+        if self.mesh is not None and self.mesh.size > 1:
+            raise ValueError(
+                why + f"a mesh of {self.mesh.size} devices is refused (the "
+                "state buffers and their update kernel are not sharded)")
 
     # ---------------------------------------------------------- properties
     @property
@@ -491,6 +541,9 @@ class InferenceEngine:
         spec_on = cfg.speculate_k > 0 and fam.verify_forward is not None
         LH = cfg.max_seq_len
         is_vl = cfg.model_family == "qwen2_vl"
+        # A family with per-slot state of its own (ModelFamily.slot_state).
+        state_keys = slot_state_keys(cfg)
+        stateful = bool(state_keys)
         from ..ops.attention import trace_program
 
         def prog(label, ring=False):
@@ -582,6 +635,15 @@ class InferenceEngine:
                         params, mcfg, d["last"], positions, d["kv"],
                         d["pt"], d["clens"],
                         rope_positions=positions + d["mrope_delta"])
+                elif stateful:
+                    # The family's per-slot buffers ride the scan's carry
+                    # with the pool; only live slots' state advances.
+                    logits, kv, state = fam.decode_forward(
+                        params, mcfg, d["last"], positions, d["kv"],
+                        d["pt"], d["clens"],
+                        state={k: d[k] for k in state_keys},
+                        live=d["active"])
+                    d = dict(d, **state)
                 else:
                     logits, kv = fam.decode_forward(
                         params, mcfg, d["last"], positions, d["kv"],
@@ -711,6 +773,16 @@ class InferenceEngine:
                             params, mcfg, tokens, positions, d["kv"],
                             page_row[None, :], prefix_len[None],
                             seq_len[None], mm_embeds=mm)
+                    elif stateful:
+                        logits, kv, state = fam.prefill_forward(
+                            params, mcfg, tokens, positions, d["kv"],
+                            page_row[None, :], prefix_len[None],
+                            seq_len[None])
+                        # The admitted slot's state, whole: that is also
+                        # what clears the last occupant's.
+                        d = dict(d, **{
+                            k: d[k].at[:, slot].set(v[:, 0].astype(
+                                d[k].dtype)) for k, v in state.items()})
                     else:
                         logits, kv = fam.prefill_forward(
                             params, mcfg, tokens, positions, d["kv"],
@@ -1286,7 +1358,7 @@ class InferenceEngine:
         """The devices this engine holds, as JAX reports them, with each
         one's bytes in use — so a launcher that must stay off JAX (one
         process per chip) can still see where the engine landed."""
-        devs = sorted(self._dstate["kv"].devices(), key=lambda d: d.id)
+        devs = self._devices
         return {
             "platform": devs[0].platform,
             "device_kind": devs[0].device_kind,
@@ -1921,7 +1993,11 @@ class InferenceEngine:
         # excluded entirely: their token ids are image-blind (identical
         # placeholder runs for different images), so cached KV could be
         # silently reused across different images.
-        if req.mm_embeds is not None:
+        # So are families with per-slot state of their own: a cached page
+        # of keys is no use without the recurrent state at its boundary,
+        # which nothing keeps. The cache is not asked, and not fed
+        # (`prefix_skipped_stateful` counts the admission).
+        if req.mm_embeds is not None or self._slot_state_keys:
             matched, cached_pages, cached_hashes = 0, [], []
             prompt_hashes = None
         else:
@@ -2157,7 +2233,9 @@ class InferenceEngine:
         # Donate completed prompt blocks to the prefix cache (skip only the
         # blocks matched FROM the cache; self-written chunks are donated).
         # Multimodal KV is never donated — the hash ignores image content.
-        if req.mm_embeds is None:
+        if self._slot_state_keys:
+            self.telemetry.counters["prefix_skipped_stateful"] += 1
+        elif req.mm_embeds is None:
             stored, donated = self.page_mgr.store_prefix(
                 prompt, seq.pages.all_pages,
                 skip_blocks=cache_matched // cfg.hash_block_size,
@@ -2622,6 +2700,7 @@ class InferenceEngine:
         emits its tokens."""
         with self.telemetry.phase("fetch_wait"):
             call.landed = self._fetch(call.packed)   # [H, B, 2+2K]
+        self.telemetry.mark_decode_landed(len(call.snapshot), call.horizon)
         now = time.monotonic()
         # The chip ran it from its dispatch, or from when the call before
         # it landed if it was queued behind that one.

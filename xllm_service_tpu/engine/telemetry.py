@@ -19,6 +19,13 @@ horizon, reason or phase); `summarize` nests them for output:
               prefix_onload_tokens (the part of them restored from DRAM/SSD),
               prefill_calls/<bucket|chunk>,
               prefill_padded_tokens (bucket - suffix),
+              prefix_skipped_stateful (admissions whose prompt the prefix
+              cache was not asked about, and whose blocks it was not given:
+              the model's family keeps a recurrent state per slot, and a
+              cached page of keys is no use without the state at its
+              boundary), state_bytes_reserved (bytes of those per-slot
+              state buffers, set once at start: a gauge, 0 for most
+              families),
               admissions_blocked/<no_slot|no_pages> (loop iterations that
               left a waiting request unadmitted), preemptions, cancelled,
               finished, prefill_behind_steps (decode steps dispatched and
@@ -77,7 +84,8 @@ _SCALARS = (
     "prefix_onload_tokens", "prefill_padded_tokens", "preemptions",
     "cancelled", "finished", "prefill_behind_steps", "decode_steps",
     "live_slot_steps", "context_token_steps", "sarathi_rides",
-    "pages_reserved_steps", "walk_chunks", "walk_run_chunks")
+    "pages_reserved_steps", "walk_chunks", "walk_run_chunks",
+    "prefix_skipped_stateful", "state_bytes_reserved")
 
 
 class AdmissionSample(NamedTuple):
@@ -189,6 +197,18 @@ class EngineTelemetry:
         c["pages_reserved_steps"] += pages_reserved * steps
         c["walk_chunks"] += walk_chunks * steps
         c["walk_run_chunks"] += walk_run_chunks * steps
+
+    def mark_decode_landed(self, live: int, horizon: int) -> None:
+        """One `TraceAnnotation` entered and left at once, just after a
+        `decode_multi` call's result was fetched:
+        `engine.decode_live.<live>.<horizon>`, the sequences the call was
+        dispatched for and its steps. Inert without a profiler session.
+        A reader of the trace pairs it with the device execution that
+        ended just before it and so prices that very call (bytes a live
+        slot, say) from the trace alone, where `live_slot_steps` covers
+        another window than the traced seconds."""
+        with TraceAnnotation(f"engine.decode_live.{live}.{horizon}"):
+            pass
 
     def decode_fetched(self, horizon: int, live: int, context_tokens: int,
                        ms_per_tok: float) -> None:

@@ -84,6 +84,40 @@ class ModelConfig:
     sliding_window_pattern: int = 0
     query_pre_attn_scalar: float = 0.0
     sandwich_norms: bool = False
+    # Hybrid state-space models (models/granite_hybrid.py). `layer_types`
+    # states each layer's mixer, "mamba" or "attention", in order; empty
+    # means every layer is attention. The Mamba-2 widths: `ssm_heads` heads
+    # of `ssm_head_dim` channels, one group of state size `ssm_state`, a
+    # causal convolution of `ssm_conv` taps, prefill scanned in chunks of
+    # `ssm_chunk`. The four Granite multipliers scale the embeddings, each
+    # residual branch, the attention scores (0 = head_dim**-0.5) and divide
+    # the logits. `kv_held_dim` is the width a key is held at in the pool
+    # where that is not `head_dim` (0): a family whose heads are narrower
+    # than the paged kernel's tiling states the padded width here.
+    layer_types: tuple = ()
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    embed_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attn_multiplier: float = 0.0
+    logits_scaling: float = 1.0
+    kv_held_dim: int = 0
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers that hold keys and values: the planes of the KV pool."""
+        if not self.layer_types:
+            return self.num_layers
+        return sum(t == "attention" for t in self.layer_types)
+
+    @property
+    def kv_head_dim(self) -> int:
+        """The width a key is held at in the pool: `head_dim` unless the
+        configuration states another (`kv_held_dim`)."""
+        return self.kv_held_dim or self.head_dim
 
     def layer_is_local(self, layer: int) -> bool:
         """True if `layer` uses sliding-window (local) attention."""
@@ -150,6 +184,18 @@ class ModelFamily:
     # models/quant.quantized_einsum (weight-only int8). MoE expert stacks
     # and the MLA latent path are not quant-aware yet.
     supports_int8: bool = False
+    # Optional per-slot device state beside the KV pool (a recurrent state
+    # per sequence): `slot_state(cfg, max_batch_size) -> {name: zeros
+    # [layers, max_batch_size, ...]}`. The engine keeps the buffers in its
+    # donated decode state under those names. With it, `prefill_forward`
+    # returns a third value, the admitted sequence's final state
+    # {name: [layers, 1, ...]}, which the engine writes over the slot's
+    # (whole: that is also what clears the last occupant's), and
+    # `decode_forward` takes `state=` and `live=` ([B] bool: the slots that
+    # advance) and returns the buffers as a third value. Such a family has
+    # no prefix to reuse and nothing to hand off: engine.py refuses what
+    # it cannot run at start.
+    slot_state: Optional[Callable[..., Any]] = None
 
 
 _REGISTRY: dict[str, ModelFamily] = {}
@@ -174,6 +220,8 @@ def get_model_family(name: str) -> ModelFamily:
             from . import gemma  # noqa: F401
         elif name == "mixtral":
             from . import mixtral  # noqa: F401
+        elif name == "granite_hybrid":
+            from . import granite_hybrid  # noqa: F401
     fam = _REGISTRY.get(name)
     if fam is None:
         raise ValueError(f"unknown model family: {name}")

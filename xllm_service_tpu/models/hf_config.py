@@ -11,7 +11,8 @@ to every engine); this module is the TPU framework's equivalent entry:
 
 Families map to the registered model families (models/__init__.py):
 llama / qwen2 (qkv-bias llama) / gemma2 / mixtral / deepseek_v2(.5) /
-qwen2_vl. Anything else raises with the offending model_type.
+qwen2_vl / granitemoehybrid (dense: Mamba-2 + GQA). Anything else raises
+with the offending model_type.
 """
 
 from __future__ import annotations
@@ -46,6 +47,51 @@ def _common(hf: dict) -> dict[str, Any]:
         tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
         max_context_len=int(hf.get("max_position_embeddings", 8192)),
     )
+
+
+def _granite_hybrid(hf: dict, ckpt_dir) -> dict[str, Any]:
+    """Granite-4.0-H (models/granite_hybrid.py). What the family does not
+    compute is refused here, with the reason, not approximated."""
+    def refuse(why: str):
+        raise ValueError(f"granitemoehybrid under {ckpt_dir}: {why}")
+
+    if hf.get("num_local_experts", 0):
+        refuse(f"num_local_experts={hf['num_local_experts']}: routed "
+               "experts are not implemented in this family (dense only: "
+               "the shared SwiGLU of width shared_intermediate_size)")
+    if hf.get("mamba_n_groups", 1) != 1:
+        refuse(f"mamba_n_groups={hf['mamba_n_groups']}: the state update "
+               "computes one group of B and C")
+    if hf.get("position_embedding_type", "nope") != "nope":
+        refuse(f"position_embedding_type "
+               f"{hf['position_embedding_type']!r}: the family applies no "
+               "position embedding (nope)")
+    if hf.get("attention_bias") or hf.get("mamba_proj_bias"):
+        refuse("projection biases are not implemented")
+    if not hf.get("mamba_conv_bias", True):
+        refuse("a convolution without bias is not implemented")
+    if not hf.get("tie_word_embeddings", False):
+        refuse("an untied output head is not implemented")
+    heads, d_head = hf["mamba_n_heads"], hf["mamba_d_head"]
+    if heads * d_head != hf["mamba_expand"] * hf["hidden_size"]:
+        refuse(f"mamba_n_heads x mamba_d_head = {heads * d_head} is not "
+               f"mamba_expand x hidden_size")
+    kw = _common(hf)
+    kw.update(
+        name="granite_hybrid",
+        ffn_size=hf["shared_intermediate_size"],
+        layer_types=tuple(hf["layer_types"]),
+        ssm_heads=heads, ssm_head_dim=d_head,
+        ssm_state=hf["mamba_d_state"], ssm_conv=hf["mamba_d_conv"],
+        ssm_chunk=hf["mamba_chunk_size"],
+        embed_multiplier=float(hf["embedding_multiplier"]),
+        residual_multiplier=float(hf["residual_multiplier"]),
+        attn_multiplier=float(hf["attention_multiplier"]),
+        logits_scaling=float(hf["logits_scaling"]),
+        # heads of 64 are outside the paged kernel's tiling (head_dim %
+        # 128): the family holds them zero-padded to the lane width
+        kv_held_dim=-(-kw["head_dim"] // 128) * 128)
+    return kw
 
 
 def model_config_from_hf(ckpt_dir: str | Path, *,
@@ -127,11 +173,13 @@ def model_config_from_hf(ckpt_dir: str | Path, *,
                 out_tokens=(image // patch // merge) ** 2,
                 temporal_patch_size=int(vc.get("temporal_patch_size", 2)),
                 spatial_merge_size=merge))
+    elif mt == "granitemoehybrid":
+        kw = _granite_hybrid(hf, ckpt_dir)
     else:
         raise ValueError(
             f"unsupported HF model_type {mt!r} under {ckpt_dir} — "
             f"supported: llama, qwen2, gemma2, mixtral, deepseek_v2/3, "
-            f"qwen2_vl")
+            f"qwen2_vl, granitemoehybrid")
 
     if dtype is not None:
         kw["dtype"] = dtype
