@@ -210,9 +210,10 @@ def kernel_arms(devices):
     yield "gemma2_softcap", paged(16, 128, 2048, softcap=50.0,
                                   scale=256 ** -0.5)
     yield "gemma2_window", paged(16, 128, 2048, window=4096 // 8)
-    # The benchmark's two configurations: batch, table width, pool and
-    # heads of chipbench/configs/*/engine.json (chunks of 16 pages, the
-    # run copy in them).
+    # The benchmark's configurations: batch, table width, pool and heads
+    # of chipbench/configs/*/engine.json (chunks of 16 pages, the run copy
+    # in them), on the bfloat16 pool whose K and V the kernel hands to the
+    # MXU as they lie: the 7B's groups of 7 rows sit on no (16, 128) tile.
     yield "paged_qwen25_7b", paged(32, 96, 2048, heads=(28, 4))
     yield "paged_qwen25_3b", paged(32, 128, 4096, heads=(16, 2))
     # granite-4.0-h-micro's four attention layers: a group of 4, heads of

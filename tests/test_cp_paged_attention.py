@@ -76,13 +76,20 @@ class TestCpPagedAttention:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 
-    def test_kernel_path_on_run_tables(self, monkeypatch):
+    @pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32-pool", "bfloat16-pool"])
+    def test_kernel_path_on_run_tables(self, monkeypatch, pool_dtype):
         """Owned entries that fill a chunk (the 16-wide table) with pages
         adjacent in the shard's pool, up or down, go through the walk's run
         copy: ascending, descending with a partly filled last page; a row
-        that crosses the two shards and a scattered one go page by page."""
+        that crosses the two shards and a scattered one go page by page.
+        A bfloat16 pool's K and V reach the products as bfloat16 (the
+        guard over V is then per 32-bit word, two rows each) and the
+        answer stays the float32 one."""
         monkeypatch.setenv("XLLM_PALLAS_INTERPRET", "1")
         q, kp, vp, _, _ = make_case(pages=64, hd=128, H=8, n_kv=2, seed=7)
+        kp, vp = kp.astype(pool_dtype), vp.astype(pool_dtype)
+        q = q.astype(pool_dtype).astype(jnp.float32)
         rng = np.random.default_rng(7)
         pt = jnp.asarray(np.stack([
             np.arange(1, 17), np.arange(60, 44, -1), np.arange(26, 42),

@@ -81,16 +81,18 @@ def _kernel(q, pool, layer, pt, cl, **kw):
 
 
 def _per_layer_attention(q, k_pages, v_pages, pt, cl, scale=None,
-                         softcap=0.0, window=0):
+                         softcap=0.0, window=0, dtype=np.float64):
     """The plain per-layer form: one layer's K and V pages as arrays of
-    their own, dense softmax per row in numpy."""
-    q, k_pages, v_pages = (np.asarray(a, np.float64)
+    their own, dense softmax per row in numpy, every operation in
+    ``dtype`` (float32: what an exact float32 evaluation rounds away)."""
+    q, k_pages, v_pages = (np.asarray(a.astype(jnp.float32), dtype)
                            for a in (q, k_pages, v_pages))
     pt, cl = np.asarray(pt), np.asarray(cl)
     B, n_q, hd = q.shape
     n_kv, ps = k_pages.shape[1], k_pages.shape[2]
-    scale = hd ** -0.5 if scale is None else scale
-    out = np.zeros((B, n_q, hd))
+    scale = dtype(hd ** -0.5 if scale is None else scale)
+    softcap = dtype(softcap)
+    out = np.zeros((B, n_q, hd), dtype)
     for b in range(B):
         c = int(cl[b])
         if c == 0:
@@ -117,6 +119,116 @@ def _per_layer_append(pool, layer, k_new, v_new, pt, pos):
         want[layer, 0, page, :, p % ps, :] = np.asarray(k_new)[b]
         want[layer, 1, page, :, p % ps, :] = np.asarray(v_new)[b]
     return want
+
+
+# name -> rows of (pool pages in table order, context length) over a
+# 64-page pool: no two rows share a page, every long row ends mid-page and
+# mid-chunk, and a short row FOLLOWS a long one, so its chunk's other
+# sub-buffers are the long row's stale pages.
+BF16_TABLES = {
+    "runs-up": [(_up(1, 38), 37 * 16 + 5), (_up(60, 1), 3),
+                (_up(40, 20), 20 * 16 - 9)],
+    "runs-down": [(_down(63, 38), 37 * 16 + 5), (_down(1, 1), 16),
+                  (_down(24, 20), 20 * 16 - 9)],
+    "page-by-page": [(list(range(1, 64, 2)), 31 * 16 + 7), ([60], 9),
+                     (list(range(58, 20, -2)), 18 * 16 + 1)],
+}
+BF16_HEADS = [(28, 4), (16, 2), (32, 8)]      # the benchmark's three cells
+
+
+def _bf16_case(table, heads, poison=False, q_dtype=jnp.float32):
+    """(q, pool, pt, cl) over a BFLOAT16 pool. ``q`` holds bfloat16
+    numbers in ``q_dtype``: as float32 the kernel's output is float32 and
+    shows the kernel's own error, not its output's rounding. ``poison``
+    fills every pool position that no row may read (page 0, unlisted
+    pages, a row's last page from its context on) with NaN and Inf."""
+    rows = BF16_TABLES[table]
+    k1, k2 = jax.random.split(jax.random.PRNGKey(3))
+    pool = np.asarray(jax.random.normal(
+        k1, (LAYERS, 2, 64, heads[1], 16, 128), jnp.float32))
+    pt = np.zeros((len(rows), 40), np.int32)            # page 0: garbage
+    if poison:
+        readable = np.zeros((64, 16), bool)
+        for pages, ctx in rows:
+            for i, page in enumerate(pages):
+                readable[page, :max(0, min(16, ctx - 16 * i))] = True
+        bad = np.where(np.arange(64 * 16).reshape(64, 16) % 2 == 0,
+                       np.nan, np.inf).astype(np.float32)
+        pool = np.where(readable[None, None, :, None, :, None], pool,
+                        bad[None, None, :, None, :, None])
+    for b, (pages, _) in enumerate(rows):
+        pt[b, :len(pages)] = pages
+    q = jax.random.normal(k2, (len(rows), heads[0], 128), jnp.float32)
+    return (q.astype(jnp.bfloat16).astype(q_dtype),
+            jnp.asarray(pool).astype(jnp.bfloat16), jnp.asarray(pt),
+            jnp.asarray([ctx for _, ctx in rows], jnp.int32))
+
+
+def _errors_against_float64(got, q, pool, pt, cl, **opts):
+    """(the kernel's largest error, an exact float32 evaluation's) against
+    the float64 evaluation of the same bfloat16 inputs."""
+    args = (q, pool[1, 0], pool[1, 1], pt, cl)
+    want = _per_layer_attention(*args, **opts)
+    f32 = _per_layer_attention(*args, dtype=np.float32, **opts)
+    return (np.abs(np.asarray(got, np.float64) - want).max(),
+            np.abs(f32 - want).max())
+
+
+class TestBfloat16Pool:
+    """A bfloat16 pool's K and V meet the MXU as bfloat16, the
+    probabilities as three bfloat16 terms: the result stays float32's."""
+
+    @pytest.mark.parametrize("heads", BF16_HEADS, ids=str)
+    @pytest.mark.parametrize("table", list(BF16_TABLES))
+    def test_error_is_float32_rounding(self, table, heads):
+        q, pool, pt, cl = _bf16_case(table, heads)
+        got = _kernel(q, pool, 1, pt, cl)
+        assert got.dtype == jnp.float32
+        err, f32_err = _errors_against_float64(got, q, pool, pt, cl)
+        assert err <= 2 * f32_err, (err, f32_err)
+
+    @pytest.mark.parametrize("heads", BF16_HEADS, ids=str)
+    @pytest.mark.parametrize("table", list(BF16_TABLES))
+    def test_garbage_past_the_context_stays_out(self, table, heads):
+        """NaN and Inf wherever the pool holds no row's tokens, so also in
+        the ring's stale sub-buffers of the short row (and the interpreter
+        starts the ring itself as NaN): the guard over V, a mask of its
+        32-bit words, keeps 0 x garbage out and changes no other bit."""
+        q, pool, pt, cl = _bf16_case(table, heads, poison=True)
+        clean = _bf16_case(table, heads)[1]
+        assert not np.isfinite(np.asarray(pool[1], np.float32)).all()
+        got = np.asarray(_kernel(q, pool, 1, pt, cl))
+        assert np.isfinite(got).all()
+        np.testing.assert_array_equal(
+            got, np.asarray(_kernel(q, clean, 1, pt, cl)))
+
+    @pytest.mark.parametrize("heads", BF16_HEADS, ids=str)
+    def test_a_bfloat16_query_rounds_nothing_but_its_output(self, heads):
+        """The served types: q bfloat16 as the pool. The output is the
+        float32 one rounded once: a float32 rounding flips one output in
+        thousands across a bfloat16 boundary, a bfloat16 rounding inside
+        (of the probabilities, say) moves four in ten."""
+        q, pool, pt, cl = _bf16_case("runs-up", heads, q_dtype=jnp.bfloat16)
+        got = _kernel(q, pool, 1, pt, cl)
+        assert got.dtype == jnp.bfloat16
+        wide = _kernel(q.astype(jnp.float32), pool, 1, pt, cl)
+        got = np.asarray(got, np.float32)
+        want = np.asarray(wide.astype(jnp.bfloat16), np.float32)
+        assert (got != want).mean() < 1e-3
+        np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=0)
+
+    @pytest.mark.parametrize("opts", [
+        {"softcap": 30.0}, {"window": 100},
+        {"softcap": 50.0, "window": 33, "scale": 0.0625},
+    ], ids=["softcap", "window", "softcap-window-scale"])
+    @pytest.mark.parametrize("table", ["runs-down", "page-by-page"])
+    def test_gemma2_options_on_a_bfloat16_pool(self, table, opts):
+        """softcap, window and the explicit scale act on the float32
+        scores; a window's lower edge falls inside a reversed chunk."""
+        q, pool, pt, cl = _bf16_case(table, (16, 2), poison=True)
+        got = _kernel(q, pool, 1, pt, cl, **opts)
+        err, f32_err = _errors_against_float64(got, q, pool, pt, cl, **opts)
+        assert err <= 2 * f32_err, (err, f32_err)
 
 
 class TestPallasPagedAttention:

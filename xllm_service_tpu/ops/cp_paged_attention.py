@@ -41,9 +41,10 @@ from jax.sharding import Mesh, PartitionSpec as P
 from .page_walk import page_chunk_size
 from .pallas_page_dma import (
     NEG_INF,
+    attend_chunk,
     chunked_page_walk,
-    flash_accumulate,
-    masked_kv_f32,
+    kv_word_rows,
+    v_word_mask,
 )
 
 _NEG_INF = NEG_INF
@@ -107,7 +108,7 @@ def _partial_kernel(local_pt_ref, starts_ref, n_local_ref, clens_ref,
                     k_hbm, v_hbm,                # LOCAL pool shard in HBM
                     m_out, l_out, acc_out,
                     k_buf, v_buf, sems, m_scr, l_scr, acc_scr,
-                    *, page_size: int, n_kv: int, group: int, scale: float,
+                    *, page_size: int, group: int, scale: float,
                     max_pages: int, chunk: int):
     """Flash partial stats over this shard's owned pages only.
 
@@ -132,9 +133,13 @@ def _partial_kernel(local_pt_ref, starts_ref, n_local_ref, clens_ref,
         base = c * chunk
         span = chunk * page_size
         lane = jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
-        sub = jax.lax.broadcasted_iota(jnp.int32, (span, 1), 0)
+        # The column form is per 32-bit word of V (`v_word_mask`): the
+        # position of the first of the rows a word holds.
+        word_rows = kv_word_rows(v_buf.dtype)
+        sub = word_rows * jax.lax.broadcasted_iota(
+            jnp.int32, (span // word_rows, 1), 0)
         pos_row = jnp.full((1, span), ctx, jnp.int32)
-        pos_col = jnp.full((span, 1), ctx, jnp.int32)
+        pos_col = jnp.full((span // word_rows, 1), ctx, jnp.int32)
         for j in range(chunk):
             entry = base + jnp.where(d < 0, chunk - 1 - j, j)
             # Chunk-padding entries (entry >= n_pages) were never
@@ -152,16 +157,10 @@ def _partial_kernel(local_pt_ref, starts_ref, n_local_ref, clens_ref,
             pos_col = jnp.where((sub >= lo) & (sub < hi),
                                 st + sub - lo, pos_col)
         mask = pos_row < ctx
-        q = q_ref[0].astype(jnp.float32) * scale     # [n_q, hd]
-        for kv in range(n_kv):
-            qh = q[kv * group:(kv + 1) * group, :]   # [G, hd]
-            k, v = masked_kv_f32(k_buf, v_buf, slot, kv, pos_col, ctx)
-            s = jax.lax.dot_general(
-                qh, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [G, span]
-            s = jnp.where(mask, s, _NEG_INF)
-            flash_accumulate(slice(kv * group, (kv + 1) * group),
-                             s, v, m_scr, l_scr, acc_scr)
+        attend_chunk(q_ref[0], group, k_buf, v_buf, slot,
+                     v_word_mask(pos_col, ctx, word_rows), scale,
+                     lambda s: jnp.where(mask, s, _NEG_INF),
+                     m_scr, l_scr, acc_scr)
 
     chunked_page_walk(local_pt_ref, b, n_pages, chunk, k_hbm, v_hbm,
                       k_buf, v_buf, sems, compute)
@@ -192,7 +191,7 @@ def _paged_partial_impl(q, k_pages, v_pages, local_pt, starts, n_local,
     max_pages = local_pt.shape[1]
     group = n_q // n_kv
     kernel = functools.partial(_partial_kernel, page_size=page_size,
-                               n_kv=n_kv, group=group, scale=scale,
+                               group=group, scale=scale,
                                max_pages=max_pages, chunk=chunk)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,

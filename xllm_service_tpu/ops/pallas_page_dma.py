@@ -10,8 +10,12 @@ context-parallel partial):
 - the same rule over a host page-table row is `page_walk.walk_run_counts`
   (what the engine's telemetry counts, and the tests' oracle of which
   path a chunk takes);
-- `masked_kv_f32` / `flash_accumulate`: the per-head chunk read and the
-  online-softmax (flash) m/l/acc update.
+- `attend_chunk`: one chunk's online-softmax (flash) m/l/acc update of
+  every KV head. `k_operand` / `v_operand` hand a head's K and V to the
+  MXU in the type the pool holds them (bfloat16 stays bfloat16: no
+  float32 copy), `v_word_mask` is the guard over V's rows past the
+  context, `exact_rows` keeps the probabilities' 24 bits as three
+  bfloat16 terms.
 
 Extracted so a fix to the DMA pattern or the accumulate numerics lands
 in every kernel at once."""
@@ -123,15 +127,16 @@ def chunked_page_walk(page_table_ref, b, n_pages, chunk,
         jax.lax.fori_loop(0, n_chunks, body, first)
 
 
-def token_offset_maps(chunk: int, page_size: int, shape, dim: int):
+def token_offset_maps(chunk: int, page_size: int, shape, dim: int,
+                      step: int = 1):
     """What `chunk_token_offsets` needs of a chunk's buffer rows, none of
     it depending on the chunk: build it once per kernel body, outside the
-    walk. (idx, flip): each row's own index along ``dim`` of an int32
-    ``shape`` (span = chunk * page_size long), its token offset where the
-    chunk's pages lie in table order; and what a row adds where the chunk
-    was fetched downwards, so that page j takes the offsets of page
-    chunk-1-j."""
-    idx = jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+    walk. (idx, flip): the index of every ``step``-th buffer row along
+    ``dim`` of an int32 ``shape`` (span / step long), its token offset
+    where the chunk's pages lie in table order; and what a row adds where
+    the chunk was fetched downwards, so that page j takes the offsets of
+    page chunk-1-j."""
+    idx = step * jax.lax.broadcasted_iota(jnp.int32, shape, dim)
     page_start = idx - jax.lax.rem(idx, page_size)
     return idx, (chunk - 1) * page_size - 2 * page_start
 
@@ -258,36 +263,140 @@ def scatter_kv_pages(kv, page_ids, block):
     )(page_ids, block, kv)
 
 
-def masked_kv_f32(k_buf, v_buf, slot, kv, pos_col, bound):
-    """Read one KV head's chunk from the ring as f32 ``[span, hd]``,
-    zeroing V rows at positions >= ``bound``: their probabilities are 0,
-    but 0 x garbage from never-DMA'd (or concurrently written) sub-buffers
-    must not reach the accumulator (0 x NaN = NaN). Row positions come as
-    a column vector ``pos_col: [span, 1]`` (Mosaic cannot transpose 1-bit
-    vectors): a chunk's rows need not lie in token order."""
-    k = k_buf[slot, :, kv].astype(jnp.float32)
-    span = k.shape[0] * k.shape[1]
-    k = k.reshape(span, -1)
-    v = v_buf[slot, :, kv].astype(jnp.float32).reshape(span, -1)
-    return k, jnp.where(pos_col < bound, v, 0.0)
+def kv_word_rows(dtype) -> int:
+    """Token rows that share one 32-bit word of a ring buffer of ``dtype``
+    (consecutive rows, the first in the low bits): 2 for bfloat16."""
+    return 4 // jnp.dtype(dtype).itemsize
 
 
-def flash_accumulate(rows, s, v, m_scr, l_scr, acc_scr):
-    """Online-softmax update of the (m, l, acc) scratch rows with masked
-    scores ``s: [R, span]`` and values ``v: [span, hd]``. Fully-masked
-    rows are exact: p is re-zeroed where s is the mask sentinel, so a row
-    whose every key is masked in this chunk contributes nothing (without
-    the guard, exp(NEG_INF - NEG_INF) = 1 would pollute l/acc)."""
-    m_prev = m_scr[rows, :1]
-    l_prev = l_scr[rows, :1]
-    m_cur = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_new)
-    p_ = jnp.exp(s - m_new)
-    p_ = jnp.where(s <= NEG_INF / 2, 0.0, p_)
-    l_new = l_prev * alpha + jnp.sum(p_, axis=1, keepdims=True)
-    acc_scr[rows, :] = acc_scr[rows, :] * alpha + \
-        jax.lax.dot_general(p_, v, (((1,), (0,)), ((), ())),
+def v_word_mask(first_pos, bound, rows: int):
+    """The guard over V as a mask of its 32-bit words: all ones over each
+    row at a position < ``bound``, zeros over the others. Those have
+    probability 0, but 0 x garbage from never-DMA'd (or concurrently
+    written) sub-buffers must not reach the accumulator (0 x NaN = NaN),
+    and an AND clears any bit pattern. ``first_pos: [span / rows, 1]`` is
+    the position of each word's first row, as a column (Mosaic cannot
+    transpose 1-bit vectors): a chunk's rows need not lie in token
+    order, the ``rows`` of a word do."""
+    bits = 32 // rows
+    keep = jnp.zeros(first_pos.shape, jnp.uint32)
+    for r in range(rows):
+        field = jnp.uint32(((1 << bits) - 1) << (bits * r))
+        keep |= jnp.where(first_pos + r < bound, field, jnp.uint32(0))
+    return keep
+
+
+def _kv_words(buf, slot, kv):
+    w = buf.bitcast(jnp.uint32)[slot, :, kv]
+    return w.reshape(w.shape[0] * w.shape[1], -1)
+
+
+def k_operand(k_buf, slot, kv):
+    """One KV head's keys of the chunk in ring slot ``slot`` as the score
+    matmul's ``[span, hd]`` operand, in the type the pool holds them. K
+    and V never exist in float32 here: a bfloat16 x bfloat16 product is
+    exact in float32, so a float32 copy of either adds no bit. They are
+    read as the buffer's 32-bit words, whose vregs are the packed
+    (16, 128) tiles the MXU takes (read as bfloat16 a buffer row comes
+    half a vreg at a time and is shuffled into those tiles, which costs
+    what the float32 copies did)."""
+    return pltpu.bitcast(_kv_words(k_buf, slot, kv), k_buf.dtype)
+
+
+def v_operand(v_buf, slot, kv, v_keep):
+    """The head's values, as `k_operand` its keys; the words pass
+    ``v_keep`` (`v_word_mask`) on the way."""
+    return pltpu.bitcast(_kv_words(v_buf, slot, kv) & v_keep, v_buf.dtype)
+
+
+def exact_rows(x, dtype):
+    """``x: [R, n]`` as rows of ``dtype`` that sum to it exactly, and how
+    many terms they are: ``x`` itself for float32, or where it is of that
+    type already; float32 ``x`` for bfloat16 is three terms (hi, mid, lo:
+    3 x 8 significant bits carry all 24) stacked on the row side
+    ``[3R, n]``, so ONE product against a bfloat16 operand is the float32
+    product to the last bit of each term, and the extra rows ride on the
+    few-row side, not on the other side's tiles. Fewer terms would round
+    ``x``: a precision change."""
+    if dtype != jnp.bfloat16 or x.dtype == dtype:
+        return x.astype(dtype), 1
+    x = x.astype(jnp.float32)
+    hi = x.astype(jnp.bfloat16).astype(jnp.float32)
+    rest = x - hi
+    mid = rest.astype(jnp.bfloat16).astype(jnp.float32)
+    return jnp.concatenate([hi, mid, rest - mid]).astype(jnp.bfloat16), 3
+
+
+def sum_terms(y, terms: int):
+    """Undo `exact_rows`' stacking on a product's rows: ``[terms * R, n]``
+    -> ``[R, n]``, summed in float32."""
+    rows = y.shape[0] // terms
+    out = y[:rows]
+    for t in range(1, terms):
+        out = out + y[t * rows:(t + 1) * rows]
+    return out
+
+
+def head_scores(q, k, scale: float):
+    """Scores ``[G, span]`` of one KV head's query rows ``q: [G, hd]``
+    against its chunk's keys ``k: [span, hd]`` (`k_operand`), float32
+    accumulation. ``scale`` multiplies the float32 scores, not ``q``
+    before the product: a bfloat16 ``q`` then meets bfloat16 keys as the
+    numbers both are, and every product is exact."""
+    q_rows, terms = exact_rows(q, k.dtype)
+    s = jax.lax.dot_general(q_rows, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
+    return sum_terms(s, terms) * scale
+
+
+def flash_softmax(rows, s, m_scr, l_scr):
+    """The online-softmax half of a flash update: moves the (m, l) scratch
+    rows on by masked scores ``s: [R, span]`` and returns (p, alpha), the
+    chunk's unnormalised probabilities and the factor the old accumulator
+    is worth under the new maximum. Fully-masked rows are exact: p is
+    re-zeroed where s is the mask sentinel, so a row whose every key is
+    masked in this chunk contributes nothing (without the guard,
+    exp(NEG_INF - NEG_INF) = 1 would pollute l/acc)."""
+    m_prev = m_scr[rows, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
+    l_scr[rows, :1] = l_scr[rows, :1] * alpha + jnp.sum(p, axis=1,
+                                                        keepdims=True)
     m_scr[rows, :1] = m_new
-    l_scr[rows, :1] = l_new
+    return p, alpha
+
+
+def flash_values(rows, p, alpha, v, acc_scr):
+    """The other half: ``acc = acc * alpha + p . v`` on the scratch rows,
+    ``v: [span, hd]`` as `v_operand` gives it; the probabilities meet it
+    through `exact_rows`, so the product is float32's whatever V's
+    type."""
+    p_rows, terms = exact_rows(p, v.dtype)
+    pv = jax.lax.dot_general(p_rows, v, (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    acc_scr[rows, :] = acc_scr[rows, :] * alpha + sum_terms(pv, terms)
+
+
+def attend_chunk(q, group: int, k_buf, v_buf, slot, v_keep, scale: float,
+                 finish_scores, m_scr, l_scr, acc_scr):
+    """One chunk's flash update of every KV head: ``q: [n_q, hd]`` in
+    groups of ``group`` rows against the chunk in ring slot ``slot``;
+    ``finish_scores`` takes a head's scaled scores ``[G, span]`` to the
+    masked ones (`NEG_INF` where a key is not visible).
+
+    Three passes over the heads, not one pass of three steps. A head's
+    p . V waits for its softmax, and that for its q . K^T to come back
+    from the MXU and through two cross-lane reductions: head after head,
+    the MXU idles through every one of those waits. Pass by pass, the
+    heads' products stand behind one another with nothing to wait for."""
+    heads = [slice(kv * group, (kv + 1) * group)
+             for kv in range(k_buf.shape[2])]
+    scores = [finish_scores(head_scores(q[rows], k_operand(k_buf, slot, kv),
+                                        scale))
+              for kv, rows in enumerate(heads)]
+    probs = [flash_softmax(rows, s, m_scr, l_scr)
+             for rows, s in zip(heads, scores)]
+    for kv, (rows, (p, alpha)) in enumerate(zip(heads, probs)):
+        flash_values(rows, p, alpha, v_operand(v_buf, slot, kv, v_keep),
+                     acc_scr)

@@ -20,7 +20,13 @@ Design (v2 — manual double-buffered DMA):
 - layer id, page table + context lengths are scalar-prefetch operands
   (SMEM) so DMA source addresses are computable before compute starts.
 - online-softmax accumulation (flash-style m/l/acc) in VMEM scratch; GQA
-  via a static loop over KV heads with G query rows each.
+  via static loops over KV heads with G query rows each, three passes a
+  chunk (all scores, all softmaxes, all values: `attend_chunk`).
+- K and V go to the MXU as the pool holds them: for a bfloat16 pool the
+  bfloat16 `q` rows against bfloat16 K (scale, softcap and mask on the
+  float32 scores), and the float32 probabilities as three bfloat16 terms
+  against bfloat16 V: every product exact, float32 accumulation, no
+  float32 copy of K or V. A float32 pool keeps float32 operands.
 - KV page layout ``[..., num_pages, n_kv, page_size, head_dim]``: one page
   is a contiguous (n_kv, ps, hd) block whose minor dims match the bf16
   (16, 128) tile.
@@ -42,11 +48,12 @@ from jax.experimental.pallas import tpu as pltpu
 from .page_walk import page_chunk_size
 from .pallas_page_dma import (
     NEG_INF as _NEG_INF,
+    attend_chunk,
     chunk_token_offsets,
     chunked_page_walk,
-    flash_accumulate,
-    masked_kv_f32,
+    kv_word_rows,
     token_offset_maps,
+    v_word_mask,
 )
 
 
@@ -56,7 +63,7 @@ def _kernel(layer_ref, page_table_ref, context_lens_ref,   # SMEM prefetch
             o_ref,                              # VMEM block [1, n_q, hd]
             k_buf, v_buf, sems,                 # scratch: 2-slot chunk ring
             m_scr, l_scr, acc_scr,
-            *, page_size: int, n_kv: int, group: int, scale: float,
+            *, page_size: int, group: int, scale: float,
             max_pages: int, chunk: int, softcap: float, window: int):
     b = pl.program_id(0)
     ctx = context_lens_ref[b]
@@ -69,31 +76,30 @@ def _kernel(layer_ref, page_table_ref, context_lens_ref,   # SMEM prefetch
 
     span = chunk * page_size
     row_maps = token_offset_maps(chunk, page_size, (1, span), 1)
-    col_maps = token_offset_maps(chunk, page_size, (span, 1), 0)
+    word_rows = kv_word_rows(v_buf.dtype)
+    word_maps = token_offset_maps(chunk, page_size, (span // word_rows, 1),
+                                  0, step=word_rows)
 
     def compute(c, slot, d):
         start = c * span
         # A chunk fetched downwards lies in the buffer in reverse page
         # order; the masks follow it, nothing else depends on key order.
         token_pos = start + chunk_token_offsets(row_maps, d)
-        pos_col = start + chunk_token_offsets(col_maps, d)
         mask = token_pos < ctx
         if window > 0:
             # gemma-2 sliding window: the query sits at position ctx-1,
             # so visible keys are >= ctx - window (matches the XLA path).
             mask &= token_pos >= ctx - window
-        q = q_ref[0].astype(jnp.float32) * scale           # [n_q, hd]
-        for kv in range(n_kv):
-            qh = q[kv * group:(kv + 1) * group, :]         # [G, hd]
-            k, v = masked_kv_f32(k_buf, v_buf, slot, kv, pos_col, ctx)
-            s = jax.lax.dot_general(
-                qh, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)        # [G, span]
+
+        def finish_scores(s):
             if softcap > 0.0:
                 s = softcap * jnp.tanh(s / softcap)
-            s = jnp.where(mask, s, _NEG_INF)
-            flash_accumulate(slice(kv * group, (kv + 1) * group),
-                             s, v, m_scr, l_scr, acc_scr)
+            return jnp.where(mask, s, _NEG_INF)
+
+        v_keep = v_word_mask(start + chunk_token_offsets(word_maps, d),
+                             ctx, word_rows)
+        attend_chunk(q_ref[0], group, k_buf, v_buf, slot, v_keep, scale,
+                     finish_scores, m_scr, l_scr, acc_scr)
 
     c_lo = 0
     if window > 0:
@@ -151,7 +157,7 @@ def _paged_attention_impl(q: jax.Array, pool: jax.Array,
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
 
-    kernel = functools.partial(_kernel, page_size=page_size, n_kv=n_kv,
+    kernel = functools.partial(_kernel, page_size=page_size,
                                group=group, scale=scale,
                                max_pages=max_pages, chunk=chunk,
                                softcap=softcap, window=window)
