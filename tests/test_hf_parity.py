@@ -123,6 +123,37 @@ def make_model_dir(d: Path, model_type: str) -> Path:
             "routed_scaling_factor": 1.0,
             "moe_layer_freq": 1,
         }
+    elif model_type == "deepseek_v3":
+        # the DeepSeek-V3 router as published: a sigmoid of every logit, a
+        # non-zero choice-only bias, the chosen normalised, a routed scale
+        # that is not 1, and interleaved rotary pairs
+        from xllm_service_tpu.models.deepseek_moe import tiny_mla_config
+        from test_loader import make_hf_deepseek_checkpoint
+        cfg = tiny_mla_config(
+            dtype=jnp.float32, first_dense_layers=1, num_layers=3,
+            num_experts=8, num_experts_per_token=3, num_shared_experts=2,
+            router_scoring="sigmoid", router_bias=True,
+            router_norm_topk=True, routed_scale=2.448, rope_interleave=True)
+        tensors = make_hf_deepseek_checkpoint(d, cfg)
+        _write_index(d, tensors)
+        arch = "DeepseekV3ForCausalLM"
+        extra = {
+            "q_lora_rank": None, "rope_scaling": None,
+            "kv_lora_rank": cfg.kv_lora_rank,
+            "qk_nope_head_dim": cfg.qk_nope_head_dim,
+            "qk_rope_head_dim": cfg.qk_rope_head_dim,
+            "head_dim": cfg.qk_rope_head_dim,    # HF's rotary width
+            "v_head_dim": cfg.v_head_dim,
+            "n_routed_experts": cfg.num_experts,
+            "num_experts_per_tok": cfg.num_experts_per_token,
+            "n_shared_experts": cfg.num_shared_experts,
+            "moe_intermediate_size": cfg.moe_ffn_size,
+            "first_k_dense_replace": cfg.first_dense_layers,
+            "scoring_func": "sigmoid", "topk_method": "noaux_tc",
+            "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+            "routed_scaling_factor": cfg.routed_scale,
+            "rope_interleave": True, "moe_layer_freq": 1,
+        }
     else:
         raise AssertionError(model_type)
     base["rope_theta"] = cfg.rope_theta   # always the weights' theta
@@ -156,7 +187,8 @@ def test_hf_config_mapping(tmp_path):
 
 
 @pytest.mark.parametrize("model_type", ["llama", "qwen2", "gemma2",
-                                        "mixtral", "deepseek_v2"])
+                                        "mixtral", "deepseek_v2",
+                                        "deepseek_v3"])
 def test_greedy_parity_full_stack(tmp_path, model_type):
     d = make_model_dir(tmp_path, model_type)
     out = drill.run_drill(str(d), prompt="the capital of france is",
@@ -164,8 +196,86 @@ def test_greedy_parity_full_stack(tmp_path, model_type):
     assert out["ok"], out
     assert out["tokens_matched"] == out["tokens_total"] == 12
     assert out["model_type"] == {"gemma2": "gemma",
-                                 "deepseek_v2": "deepseek_moe"}.get(
+                                 "deepseek_v2": "deepseek_moe",
+                                 "deepseek_v3": "deepseek_moe"}.get(
         model_type, model_type)
+
+
+def _hf_logits(d: Path, tokens: list) -> np.ndarray:
+    import torch
+    from transformers import AutoModelForCausalLM
+
+    model = AutoModelForCausalLM.from_pretrained(
+        str(d), torch_dtype=torch.float32).eval()
+    with torch.no_grad():
+        return model(torch.tensor([tokens])).logits[0].numpy()
+
+
+@pytest.mark.parametrize("model_type", ["deepseek_v2", "deepseek_v3"])
+def test_deepseek_logits_are_hfs_own(tmp_path, model_type):
+    """The program's float32 forward against transformers' own
+    `DeepseekV2ForCausalLM` / `DeepseekV3ForCausalLM` on one synthetic
+    checkpoint, logit by logit: a prefill of 11 tokens through the paged
+    cache, then 5 decode steps from it (the absorbed latent form with the
+    interleaved rotation, the router's form with its choice-only bias,
+    grouped experts). 2e-4 of logits whose spread is ~40 (random normal
+    weights of variance 1): float32 sums in another order; a forward with
+    the rotation as halves, the bias in the weights, or no routed scale
+    reads 1 or more."""
+    import jax
+    from xllm_service_tpu.models import deepseek_moe as dm
+    from xllm_service_tpu.models.hf_config import load_checkpoint
+
+    d = make_model_dir(tmp_path, model_type)
+    toks = [2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 3, 5, 9, 2]
+    want = _hf_logits(d, toks)
+    cfg = model_config_from_hf(d, dtype=jnp.float32)
+    assert cfg.rope_interleave and cfg.kv_held_dim == 128
+    if model_type == "deepseek_v3":
+        assert (cfg.router_scoring, cfg.router_bias, cfg.router_norm_topk,
+                cfg.routed_scale) == ("sigmoid", True, True, 2.448)
+    else:
+        assert (cfg.router_scoring, cfg.router_bias,
+                cfg.router_norm_topk) == ("softmax", False, False)
+    params = load_checkpoint(d, cfg)
+    assert ("bias" in params["moe"]["router"]) == cfg.router_bias
+    n = 11
+    kv = jnp.zeros((cfg.num_layers, 2, 16, 1, 16, cfg.kv_head_dim),
+                   jnp.float32)
+    pt = jnp.arange(1, 5, dtype=jnp.int32)[None, :]
+    with jax.default_matmul_precision("highest"):
+        lg, kv = dm.verify_forward(
+            params, cfg, jnp.asarray([toks[:n] + [0] * 5]),
+            jnp.arange(16)[None, :], kv, pt, jnp.zeros((1,), jnp.int32),
+            jnp.asarray([n]))
+        got = [np.asarray(lg[0, :n])]
+        for i in range(n, len(toks)):
+            lg, kv = dm.decode_forward(
+                params, cfg, jnp.asarray([toks[i]]), jnp.asarray([i]), kv,
+                pt, jnp.asarray([i + 1]))
+            got.append(np.asarray(lg))
+    got = np.concatenate(got)
+    assert want.std() > 5
+    assert np.max(np.abs(got - want)) < 2e-4 * want.std()
+
+
+@pytest.mark.parametrize("key,value,why", [
+    ("q_lora_rank", 64, "q_lora_rank=64"),
+    ("n_group", 4, "n_group=4"),
+    ("topk_group", 2, "topk_group=2"),
+    ("rope_scaling", {"type": "yarn", "factor": 4.0}, "rope_scaling="),
+    ("scoring_func", "tanh", "scoring_func 'tanh'"),
+])
+def test_hf_config_refuses_what_deepseek_moe_does_not_compute(
+        tmp_path, key, value, why):
+    """What the family cannot state is refused by the config's own key,
+    never dropped."""
+    d = make_model_dir(tmp_path, "deepseek_v3")
+    hf = json.loads((d / "config.json").read_text())
+    assert model_config_from_hf(d).router_scoring == "sigmoid"
+    (d / "config.json").write_text(json.dumps({**hf, key: value}))
+    with pytest.raises(ValueError, match=why):
+        model_config_from_hf(d)
 
 
 def test_resolve_checkpoint_reports_unavailable(monkeypatch, tmp_path):

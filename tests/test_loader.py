@@ -177,7 +177,8 @@ class TestOrbaxRoundtrip:
 def make_hf_deepseek_checkpoint(tmp_path, cfg, seed=0):
     """Synthetic HF DeepSeek-V2 layout: MLA attention (kv_a/kv_b fused
     projections), layer 0 dense (first_k_dense_replace=1), MoE layers with
-    routed + shared experts."""
+    routed + shared experts; with `cfg.router_bias` DeepSeek-V3's
+    `e_score_correction_bias` beside each router."""
     from safetensors.numpy import save_file
 
     rng = np.random.default_rng(seed)
@@ -211,6 +212,8 @@ def make_hf_deepseek_checkpoint(tmp_path, cfg, seed=0):
             tensors[p + "mlp.down_proj.weight"] = t(D, F)
         else:
             tensors[p + "mlp.gate.weight"] = t(E, D)
+            if cfg.router_bias:
+                tensors[p + "mlp.gate.e_score_correction_bias"] = 0.2 * t(E)
             for e in range(E):
                 ep = p + f"mlp.experts.{e}."
                 tensors[ep + "gate_proj.weight"] = t(Fe, D)

@@ -83,9 +83,11 @@ class TestDeepSeekMoE:
         logits = x @ lp["router"]["kernel"]
         topv, _ = jax.lax.top_k(logits, cfg.num_experts_per_token)
         assert topv.shape == (5, 2)
-        out = _moe_mlp(lp, x, cfg)
+        out, counts = _moe_mlp(params["moe"], 0, x, cfg)
         assert out.shape == x.shape
         assert bool(jnp.all(jnp.isfinite(out)))
+        # five rows, two experts each of four: all four touched or fewer
+        assert int(counts[0]) == 5 and 2 <= int(counts[1]) <= 4
 
     def test_expert_parallel_matches_single_device(self):
         cfg, fam, params = self._setup()

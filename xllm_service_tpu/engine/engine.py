@@ -544,6 +544,9 @@ class InferenceEngine:
         # A family with per-slot state of its own (ModelFamily.slot_state).
         state_keys = slot_state_keys(cfg)
         stateful = bool(state_keys)
+        # A family that routes tokens to experts takes the active mask and
+        # hands back what its router did (ModelFamily.decode_forward_routed).
+        routed = fam.decode_forward_routed is not None and not stateful
         from ..ops.attention import trace_program
 
         def prog(label, ring=False):
@@ -615,12 +618,20 @@ class InferenceEngine:
             return d, (toks, chosen, tv, ti)
 
         def _pack_scan_outputs(d, ys):
-            toks, chosen, tv, ti = ys
+            toks, chosen, tv, ti, *router_counts = ys
             # ONE packed download [H, B, 2+2K] f32 (token/ids are exact in
             # f32 below 2^24).
             packed = jnp.concatenate(
                 [toks[..., None].astype(jnp.float32), chosen[..., None],
                  tv, ti.astype(jnp.float32)], axis=-1)
+            if router_counts:
+                # A routing family's counts [H, 2] ride home as one more
+                # row behind the batch's: [H, B+1, 2+2K], the row's first
+                # two cells (never a transfer of their own).
+                row = jnp.pad(
+                    router_counts[0].astype(jnp.float32),
+                    ((0, 0), (0, packed.shape[-1] - 2)))[:, None, :]
+                packed = jnp.concatenate([packed, row], axis=1)
             return d, packed
 
         @partial(jax.jit, static_argnums=(2,), donate_argnums=(1,))
@@ -644,6 +655,15 @@ class InferenceEngine:
                         state={k: d[k] for k in state_keys},
                         live=d["active"])
                     d = dict(d, **state)
+                elif routed:
+                    # Only rows that hold a running request reach an
+                    # expert; a slot that stops inside the call leaves
+                    # `active` at that step (its `clens` stays).
+                    logits, kv, counts = fam.decode_forward_routed(
+                        params, mcfg, d["last"], positions, d["kv"],
+                        d["pt"], d["clens"], live=d["active"])
+                    d, ys = _post_decode_forward(dict(d, kv=kv), logits)
+                    return d, ys + (counts,)
                 else:
                     logits, kv = fam.decode_forward(
                         params, mcfg, d["last"], positions, d["kv"],
@@ -2701,6 +2721,11 @@ class InferenceEngine:
         with self.telemetry.phase("fetch_wait"):
             call.landed = self._fetch(call.packed)   # [H, B, 2+2K]
         self.telemetry.mark_decode_landed(len(call.snapshot), call.horizon)
+        B = self.cfg.max_batch_size
+        if call.landed.shape[1] > B:
+            # the router's counts of each step, behind the batch's rows
+            rows, touched = call.landed[:, B, :2].sum(axis=0)
+            self.telemetry.moe_landed(call.horizon, int(rows), int(touched))
         now = time.monotonic()
         # The chip ran it from its dispatch, or from when the call before
         # it landed if it was queued behind that one.

@@ -43,6 +43,15 @@ horizon, reason or phase); `summarize` nests them for output:
               walk fetches for the live rows at dispatch x horizon, and
               those of them it fetches as one run of adjacent pool pages:
               `ops/page_walk.walk_run_counts`, the kernel's own rule)
+  moe         of a family that routes tokens to experts, counted on the
+              device by the router itself and brought home in the decode
+              call's own result (`moe_landed`): moe_steps (steps of the
+              calls that brought counts), moe_tokens_routed (rows that
+              held a running request, summed over those steps: a slot that
+              stops inside a call leaves at that step),
+              moe_experts_touched (experts that got at least one row,
+              summed over steps and expert layers: each is read from HBM
+              at least once)
   pages       sampled once per decode call, weighted by its horizon so that
               x / decode_steps is a mean per step: pages_reserved_steps
               (pages held by live sequences, a shared prefix page once per
@@ -85,7 +94,8 @@ _SCALARS = (
     "cancelled", "finished", "prefill_behind_steps", "decode_steps",
     "live_slot_steps", "context_token_steps", "sarathi_rides",
     "pages_reserved_steps", "walk_chunks", "walk_run_chunks",
-    "prefix_skipped_stateful", "state_bytes_reserved")
+    "prefix_skipped_stateful", "state_bytes_reserved",
+    "moe_steps", "moe_tokens_routed", "moe_experts_touched")
 
 
 class AdmissionSample(NamedTuple):
@@ -208,6 +218,18 @@ class EngineTelemetry:
         slot, say) from the trace alone, where `live_slot_steps` covers
         another window than the traced seconds."""
         with TraceAnnotation(f"engine.decode_live.{live}.{horizon}"):
+            pass
+
+    def moe_landed(self, steps: int, routed: int, touched: int) -> None:
+        """The router's counts of one landed decode call, and their marker
+        on the trace's host plane, `engine.moe.landed.<touched>.<routed>.
+        <steps>`, left as `mark_decode_landed` leaves its own: a reader of
+        the trace prices that very call's expert products."""
+        c = self.counters
+        c["moe_steps"] += steps
+        c["moe_tokens_routed"] += routed
+        c["moe_experts_touched"] += touched
+        with TraceAnnotation(f"engine.moe.landed.{touched}.{routed}.{steps}"):
             pass
 
     def decode_fetched(self, horizon: int, live: int, context_tokens: int,

@@ -52,6 +52,19 @@ class ModelConfig:
     num_shared_experts: int = 0
     moe_ffn_size: int = 0           # per-expert ffn width
     first_dense_layers: int = 1     # leading dense layers before MoE blocks
+    # The router's form (models/deepseek_moe.py `_route`). `router_scoring`
+    # "softmax" (Mixtral, DeepSeek-V2) or "sigmoid" (DeepSeek-V3: a sigmoid
+    # of every logit); `router_bias`: a per-expert bias leaf beside the
+    # router's kernel that enters the CHOICE of experts only, never their
+    # weights (`topk_method` noaux_tc); `router_norm_topk`: the chosen
+    # scores are normalised over the chosen; `routed_scale` multiplies
+    # them. `rope_interleave`: the rotary part of q and k comes as adjacent
+    # pairs (2i, 2i+1), not as two halves.
+    router_scoring: str = "softmax"
+    router_bias: bool = False
+    router_norm_topk: bool = True
+    routed_scale: float = 1.0
+    rope_interleave: bool = False
     # Multimodal (qwen2_vl family).
     vision: Optional["VisionConfig"] = None
     image_token_id: int = 151655   # <|image_pad|> placeholder id
@@ -196,6 +209,14 @@ class ModelFamily:
     # no prefix to reuse and nothing to hand off: engine.py refuses what
     # it cannot run at start.
     slot_state: Optional[Callable[..., Any]] = None
+    # Optional decode step of a family that routes tokens to experts:
+    # `decode_forward`'s arguments and `live=` ([B] bool: the rows that
+    # hold a running request; the others reach no expert), returning a
+    # third value, int32 [2]: the rows this step routed, and the experts
+    # that got at least one of them summed over the expert layers. The
+    # engine brings the counts home in the result a decode call already
+    # returns (`/stats`.engine_trace `moe_*`).
+    decode_forward_routed: Optional[Callable[..., Any]] = None
 
 
 _REGISTRY: dict[str, ModelFamily] = {}

@@ -1,12 +1,19 @@
 """DeepSeek-V2-style MoE family (BASELINE config 4: expert-parallel decode).
 
 Mixture-of-experts transformer with shared + routed experts and top-k
-softmax gating, designed for **expert parallelism over the mesh `expert`
-axis**: expert-stacked weights `[L, E, D, F]` are sharded on E, every token
-is scored against all experts with a dense dispatch einsum, and the gated
-combine contracts the expert dimension — GSPMD turns that contraction into
-a psum over the expert axis (the TPU-idiomatic EP decode; no all-to-all
-token shuffling needed at serving batch sizes).
+gating in the router form the configuration states (`_route`: softmax over
+the chosen, DeepSeek-V2's softmax of all, DeepSeek-V3's sigmoid with a
+choice-only bias and a routed scale). Where the expert stacks
+`[L, E, D, F]` are whole on one device in the model's type, the live rows'
+(token, expert) pairs are sorted by expert and each projection is ONE
+grouped product over the experts that got a row (`_experts_grouped`,
+ops/grouped_matmul.py): an expert nobody chose is not read. Under a mesh
+(**expert parallelism over the `expert` axis**: the stacks sharded on E)
+and with int8 stacks every token is scored against all experts with a
+dense dispatch einsum and the gated combine contracts the expert
+dimension — GSPMD turns that contraction into a psum over the expert axis
+(no all-to-all token shuffling needed at serving batch sizes).
+`experts_path` decides, from what the code sees while tracing.
 
 Attention is **MLA (multi-head latent attention)** when
 `kv_lora_rank > 0` (the DeepSeek-V2 design): the paged cache stores one
@@ -25,20 +32,29 @@ load_hf_deepseek_safetensors).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import (
+    _backend,
+    _pallas_interpret,
+    apply_rope,
     decode_attention_step,
+    note_path,
+    paged_attention,
     prefill_attention,
+    program_mesh,
     rms_norm,
     write_kv,
 )
+from ..ops.grouped_matmul import grouped_matmul, grouped_path
 from ..parallel.mesh import AXIS_EXPERT, AXIS_MODEL
 from ..parallel.sharding import ShardingRules
 from .base import ModelConfig, ModelFamily, register_model_family
-from .quant import quantized_einsum
+from .quant import is_quantized, quantized_einsum
 from .llama import _project_qkv, _unembed
 
 Params = dict
@@ -71,7 +87,7 @@ MOE_STACKED_RULES = ShardingRules(rules=[
     (r"shared/down_proj/kernel", P(None, AXIS_MODEL, None)),
     (r"dense_mlp/(gate_proj|up_proj)/kernel", P(None, None, AXIS_MODEL)),
     (r"dense_mlp/down_proj/kernel", P(None, AXIS_MODEL, None)),
-    (r"router/kernel", P()),
+    (r"router/(kernel|bias)", P()),
     (r"embed/embedding", P(AXIS_MODEL, None)),
     (r"(q_proj|k_proj|v_proj)/kernel", P(None, None, AXIS_MODEL)),
     (r"o_proj/kernel", P(None, AXIS_MODEL, None)),
@@ -175,7 +191,13 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> Params:
         },
         "moe": {
             "router": {"kernel": dense(keys[5], (Lm, D, E), D)
-                       .astype(jnp.float32)},
+                       .astype(jnp.float32),
+                       # DeepSeek-V3's `e_score_correction_bias`: drawn,
+                       # not zero, so that a forward that left it out of
+                       # the choice would compute another model
+                       **({"bias": 0.1 * jax.random.normal(
+                           keys[14], (Lm, E), jnp.float32)}
+                          if cfg.router_bias else {})},
             "experts": {
                 "gate_proj": {"kernel": dense(keys[6], (Lm, E, D, Fe), D)},
                 "up_proj": {"kernel": dense(keys[7], (Lm, E, D, Fe), D)},
@@ -202,29 +224,120 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> Params:
     return out
 
 
-def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    """x: [..., D] -> [..., D]. Dense dispatch: all experts score all
-    tokens; the combine contracts the (sharded) expert axis."""
-    orig_shape = x.shape
-    x2 = x.reshape(-1, orig_shape[-1])                     # [T, D]
-    # Router in f32 for stable softmax.
-    logits = x2.astype(jnp.float32) @ lp["router"]["kernel"]   # [T, E]
+def _route(router: Params, x2: jax.Array, cfg: ModelConfig):
+    """x2 [T, D] -> (chosen experts [T, k] int32, their weights [T, k]
+    float32), by the configuration's form. Scores in float32."""
+    logits = x2.astype(jnp.float32) @ router["kernel"]          # [T, E]
     k = cfg.num_experts_per_token
-    topv, topi = jax.lax.top_k(logits, k)
-    gates_k = jax.nn.softmax(topv, axis=-1)                # [T, k]
-    # Scatter the top-k gates back to a dense [T, E] map.
-    gates = jnp.zeros_like(logits).at[
-        jnp.arange(x2.shape[0])[:, None], topi].set(gates_k)
+    if cfg.router_scoring == "sigmoid":
+        # DeepSeek-V3: a sigmoid of every logit; the bias enters the
+        # choice and never the weights.
+        scores = jax.nn.sigmoid(logits)
+        choice = scores
+        if "bias" in router:
+            choice = scores + router["bias"].astype(jnp.float32)
+        _, topi = jax.lax.top_k(choice, k)
+        gates = jnp.take_along_axis(scores, topi, axis=-1)
+        if cfg.router_norm_topk:
+            gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+    else:
+        topv, topi = jax.lax.top_k(logits, k)
+        if cfg.router_norm_topk:
+            # a softmax over the chosen logits = the softmax over all,
+            # normalised over the chosen (Mixtral)
+            gates = jax.nn.softmax(topv, axis=-1)
+        else:
+            gates = jnp.take_along_axis(
+                jax.nn.softmax(logits, axis=-1), topi, axis=-1)
+    if cfg.routed_scale != 1.0:
+        gates = gates * cfg.routed_scale
+    return topi, gates
 
-    g = quantized_einsum("td,edf->etf", x2,
-                         lp["experts"]["gate_proj"]["kernel"])
-    u = quantized_einsum("td,edf->etf", x2,
-                         lp["experts"]["up_proj"]["kernel"])
+
+def experts_path(cfg: ModelConfig, experts: Params) -> str:
+    """How the routed experts' products run, from what the code sees while
+    it traces; `/stats`.attention_paths["moe_experts"]. Grouped wherever
+    the experts are whole on one device in the model's type; a mesh (the
+    Pallas product cannot be partitioned by GSPMD, and an `expert` axis
+    wants the contraction it has) and int8 stacks keep the dense
+    contraction over every expert."""
+    mesh = program_mesh()
+    if mesh is not None:
+        return f"dense (mesh {dict(mesh.shape)})"
+    if is_quantized(experts["gate_proj"]["kernel"]):
+        return "dense (int8 experts)"
+    return grouped_path(_backend(), _pallas_interpret())
+
+
+def _experts_dense(experts: Params, x2, topi, gates, E: int):
+    """Dense dispatch: all experts score all tokens; the combine
+    contracts the (sharded) expert axis."""
+    # Scatter the top-k gates back to a dense [T, E] map.
+    dense_gates = jnp.zeros((x2.shape[0], E), jnp.float32).at[
+        jnp.arange(x2.shape[0])[:, None], topi].set(gates)
+    g = quantized_einsum("td,edf->etf", x2, experts["gate_proj"]["kernel"])
+    u = quantized_einsum("td,edf->etf", x2, experts["up_proj"]["kernel"])
     h = jax.nn.silu(g) * u                                 # [E, T, Fe]
     eo = quantized_einsum("etf,efd->etd", h,
-                          lp["experts"]["down_proj"]["kernel"])
-    routed = jnp.einsum("etd,te->td", eo.astype(jnp.float32),
-                        gates).astype(x.dtype)
+                          experts["down_proj"]["kernel"])
+    return jnp.einsum("etd,te->td", eo.astype(jnp.float32), dense_gates)
+
+
+def _experts_grouped(stacks: Params, layer: int, x2, pair_expert, sizes,
+                     gates, live):
+    """Grouped dispatch: the (token, expert) pairs sorted by expert
+    (`pair_expert` [T*k]; a dead row's pairs carry E, sort behind every
+    expert and belong to no group), one grouped product per projection
+    over the experts that got a row (`sizes` [E]; ops/grouped_matmul.py),
+    un-sorted and weighed."""
+    T, k = gates.shape
+    order = jnp.argsort(pair_expert, stable=True)
+    xs = x2[order // k]                                    # [T*k, D]
+    mm = functools.partial(grouped_matmul, layer=layer, group_sizes=sizes,
+                           backend=_backend(),
+                           interpret=_pallas_interpret())
+    g = mm(xs, stacks["gate_proj"]["kernel"])
+    u = mm(xs, stacks["up_proj"]["kernel"])
+    eo = mm(jax.nn.silu(g) * u, stacks["down_proj"]["kernel"])
+    # back to (token, choice) order; rows of no group are undefined
+    eo = eo[jnp.argsort(order)].reshape(T, k, -1)
+    eo = jnp.where(live[:, None, None], eo, 0)
+    return jnp.einsum("tkd,tk->td", eo.astype(jnp.float32), gates)
+
+
+def _moe_mlp(moe: Params, layer: int, x: jax.Array, cfg: ModelConfig,
+             live: jax.Array | None = None):
+    """The expert block of MoE layer `layer` of the stacked subtree `moe`.
+    x: [..., D] -> ([..., D], counts). `live` [...] bool: the rows that
+    hold a token of a running request; the others reach no expert (None:
+    all). counts int32 [2]: the live rows, and the experts that got at
+    least one of them. One function for prefill, verify and decode."""
+    orig_shape = x.shape
+    x2 = x.reshape(-1, orig_shape[-1])                     # [T, D]
+    live = (jnp.ones(x2.shape[:1], bool) if live is None
+            else live.reshape(-1))
+    lp = {name: jax.tree.map(lambda a: a[layer], moe[name])
+          for name in moe if name != "experts"}
+    with jax.named_scope("moe.route"):
+        topi, gates = _route(lp["router"], x2, cfg)
+        E = cfg.num_experts
+        # every (token, expert) pair's expert, E for a dead row's; how
+        # many pairs each expert got
+        pair_expert = jnp.where(live[:, None], topi, E).reshape(-1)
+        sizes = jnp.zeros((E + 1,), jnp.int32).at[pair_expert].add(1)[:E]
+        counts = jnp.stack([live.sum(), (sizes > 0).sum()]).astype(
+            jnp.int32)
+    path = experts_path(cfg, moe["experts"])
+    note_path("moe_experts", path)
+    with jax.named_scope("moe.experts"):
+        if path.startswith("grouped"):
+            routed = _experts_grouped(moe["experts"], layer, x2,
+                                      pair_expert, sizes, gates, live)
+        else:
+            routed = _experts_dense(
+                jax.tree.map(lambda a: a[layer], moe["experts"]), x2,
+                topi, gates, E)
+    routed = routed.astype(x.dtype)
 
     if "shared" in lp:
         sg = quantized_einsum("td,df->tf", x2,
@@ -234,21 +347,39 @@ def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
         routed = routed + quantized_einsum(
             "tf,fd->td", jax.nn.silu(sg) * su,
             lp["shared"]["down_proj"]["kernel"]).astype(routed.dtype)
-    return routed.reshape(orig_shape)
+    return routed.reshape(orig_shape), counts
+
+
+def _rope_part(x: jax.Array, positions, cfg: ModelConfig) -> jax.Array:
+    """Rotary embedding of q's or k's rope part [..., heads, dr]. With
+    `rope_interleave` the part comes as adjacent pairs (2i, 2i+1): both q
+    and k are de-interleaved and then rotated as halves, as the published
+    code does; their products are those of rotating the pairs in place."""
+    if cfg.rope_interleave:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    return apply_rope(x, positions, cfg.rope_theta)
+
+
+def _lanes(x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """[..., head_dim] -> [..., kv_head_dim], zeros behind: the latent as
+    the pool holds it (576 at 640 lanes, `kv_held_dim`); scores and the
+    sliced output are unchanged."""
+    pad = cfg.kv_head_dim - x.shape[-1]
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),)) if pad else x
 
 
 def _mla_attention(lp, cfg, h, mode, kv_pages, layer, page_table,
                    prefix_lens, seq_lens, positions, context_lens):
     """mode: "prefill" | "decode" | "dense" (dense = no paged cache at
-    all — the embeddings path; nothing is written)."""
-    """MLA (DeepSeek-V2): the cache stores one [kv_lora_rank ‖ rope] latent
+    all — the embeddings path; nothing is written).
+
+    MLA (DeepSeek-V2): the cache stores one [kv_lora_rank ‖ rope] latent
     per token; per-head K up-projection is absorbed into the query and the
     V up-projection applied after attention — so the existing paged
-    attention ops run unchanged over latents (n_kv=1).
+    attention ops run unchanged over latents (n_kv=1). The latent is
+    written as K and as V.
 
     Returns (attn_out flattened [..., H*dv], kv_pages)."""
-    from ..ops.attention import apply_rope, paged_attention_xla
-
     H, dn = cfg.num_heads, cfg.qk_nope_head_dim
     dr, dc, dv = cfg.qk_rope_head_dim, cfg.kv_lora_rank, cfg.v_head_dim
 
@@ -256,17 +387,19 @@ def _mla_attention(lp, cfg, h, mode, kv_pages, layer, page_table,
     c = quantized_einsum("...d,dc->...c", h, lp["kv_down"]["kernel"])
     c = rms_norm(c, lp["kv_norm"]["scale"], cfg.rms_eps)
     k_r = quantized_einsum("...d,dr->...r", h, lp["k_rope"]["kernel"])
-    k_r = apply_rope(k_r[..., None, :], positions, cfg.rope_theta)[..., 0, :]
-    entry = jnp.concatenate([c, k_r], axis=-1)[..., None, :]  # [..., 1, dc+dr]
+    k_r = _rope_part(k_r[..., None, :], positions, cfg)[..., 0, :]
+    entry = _lanes(jnp.concatenate([c, k_r], axis=-1),
+                   cfg)[..., None, :]                  # [..., 1, held]
 
     # Queries: nope part absorbed through the K up-projection.
     q = quantized_einsum("...d,df->...f", h, lp["q_proj"]["kernel"])
     q = q.reshape(*q.shape[:-1], H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _rope_part(q_rope, positions, cfg)
     q_c = quantized_einsum("...hd,hdc->...hc", q_nope,
                            lp["k_up"]["kernel"])
-    q_lat = jnp.concatenate([q_c, q_rope], axis=-1)   # [..., H, dc+dr]
+    q_lat = _lanes(jnp.concatenate([q_c, q_rope], axis=-1),
+                   cfg)                                # [..., H, held]
     # True scale is over the uncompressed per-head key width.
     scale = 1.0 / ((dn + dr) ** 0.5)
 
@@ -281,9 +414,11 @@ def _mla_attention(lp, cfg, h, mode, kv_pages, layer, page_table,
                                  page_table, prefix_lens, seq_lens,
                                  scale=scale)
     else:
-        kv_pages = write_kv(kv_pages, layer, entry[:, None], entry[:, None],
-                            page_table, positions, jnp.ones_like(positions))
-        attn = paged_attention_xla(q_lat, kv_pages, layer, page_table,
+        with jax.named_scope("mla.decode"):
+            kv_pages = write_kv(kv_pages, layer, entry[:, None],
+                                entry[:, None], page_table, positions,
+                                jnp.ones_like(positions))
+            attn = paged_attention(q_lat, kv_pages, layer, page_table,
                                    context_lens, scale=scale)
     # The weighted sum over [c ‖ k_rope] entries: keep the latent part,
     # apply the absorbed V up-projection per head.
@@ -301,9 +436,13 @@ def _dense_mlp(mp: Params, x: jax.Array) -> jax.Array:
 
 
 def _run_layers(params, cfg, x, kv_pages, mode, page_table, prefix_lens,
-                seq_lens, positions, context_lens):
+                seq_lens, positions, context_lens, live=None):
     """Unrolled layer loop over the one donated pool, written in place and
-    read as `(pool, layer)` (see models/llama.py)."""
+    read as `(pool, layer)` (see models/llama.py). `live`: the rows of `x`
+    that reach the experts (`_moe_mlp`). Returns (x, kv_pages, counts):
+    the live rows, and the experts that got one summed over the expert
+    layers."""
+    counts = jnp.zeros((2,), jnp.int32)
     use_mla = cfg.kv_lora_rank > 0
     Ld = cfg.first_dense_layers
     dense = kv_pages is None            # embeddings: no cache at all
@@ -337,30 +476,45 @@ def _run_layers(params, cfg, x, kv_pages, mode, page_table, prefix_lens,
                 jax.tree.map(lambda a, _l=l: a[_l], params["dense_mlp"]),
                 h2)
         else:
-            x = x + _moe_mlp(
-                jax.tree.map(lambda a, _l=l - Ld: a[_l], params["moe"]),
-                h2, cfg)
-    return x, kv_pages
+            y, c = _moe_mlp(params["moe"], l - Ld, h2, cfg, live)
+            x = x + y
+            counts = jnp.stack([c[0], counts[1] + c[1]])
+    return x, kv_pages, counts
+
+
+def _suffix_live(tokens, seq_lens):
+    """[B, S] bool: the rows of a prefill or verify block that hold a
+    token (the rest is bucket padding and reaches no expert)."""
+    return jnp.arange(tokens.shape[1])[None, :] < seq_lens[:, None]
 
 
 def prefill_forward(params, cfg, tokens, positions, kv_pages, page_table,
                     prefix_lens, seq_lens):
     x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
-    x, kv_pages = _run_layers(params, cfg, x, kv_pages, "prefill",
-                              page_table, prefix_lens, seq_lens, positions,
-                              None)
+    x, kv_pages, _ = _run_layers(
+        params, cfg, x, kv_pages, "prefill", page_table, prefix_lens,
+        seq_lens, positions, None, live=_suffix_live(tokens, seq_lens))
     idx = jnp.maximum(seq_lens - 1, 0)
     last = x[jnp.arange(x.shape[0]), idx]
     return _unembed(params, cfg, last), kv_pages
 
 
+def decode_forward_routed(params, cfg, tokens, positions, kv_pages,
+                          page_table, context_lens, *, live):
+    """One decode step in which only the `live` rows ([B] bool; None: all)
+    reach the experts. Returns (logits, kv_pages, counts): `_run_layers`'
+    counts of this step (`ModelFamily.decode_forward_routed`)."""
+    x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
+    x, kv_pages, counts = _run_layers(
+        params, cfg, x, kv_pages, "decode", page_table, None, None,
+        positions, context_lens, live=live)
+    return _unembed(params, cfg, x), kv_pages, counts
+
+
 def decode_forward(params, cfg, tokens, positions, kv_pages, page_table,
                    context_lens):
-    x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
-    x, kv_pages = _run_layers(params, cfg, x, kv_pages, "decode",
-                              page_table, None, None, positions,
-                              context_lens)
-    return _unembed(params, cfg, x), kv_pages
+    return decode_forward_routed(params, cfg, tokens, positions, kv_pages,
+                                 page_table, context_lens, live=None)[:2]
 
 
 def verify_forward(params, cfg, tokens, positions, kv_pages, page_table,
@@ -369,9 +523,9 @@ def verify_forward(params, cfg, tokens, positions, kv_pages, page_table,
     handles short multi-token blocks against the paged cache (MLA or GQA);
     this returns per-position logits [B, S, V]."""
     x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
-    x, kv_pages = _run_layers(params, cfg, x, kv_pages, "prefill",
-                              page_table, prefix_lens, seq_lens, positions,
-                              None)
+    x, kv_pages, _ = _run_layers(
+        params, cfg, x, kv_pages, "prefill", page_table, prefix_lens,
+        seq_lens, positions, None, live=_suffix_live(tokens, seq_lens))
     return _unembed(params, cfg, x), kv_pages
 
 
@@ -382,9 +536,9 @@ def embed_forward(params, cfg, tokens, seq_lens):
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :],
                                  (B, S))
     x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
-    x, _ = _run_layers(params, cfg, x, None, "prefill", None,
-                       jnp.zeros((B,), jnp.int32), seq_lens, positions,
-                       None)
+    x, _, _ = _run_layers(params, cfg, x, None, "prefill", None,
+                          jnp.zeros((B,), jnp.int32), seq_lens, positions,
+                          None, live=_suffix_live(tokens, seq_lens))
     from ..ops.attention import rms_norm as _rms
     x = _rms(x, params["final_norm"]["scale"], cfg.rms_eps)
     mask = (jnp.arange(S)[None, :] < seq_lens[:, None])[..., None]
@@ -401,4 +555,5 @@ register_model_family(ModelFamily(
     verify_forward=verify_forward,
     embed_forward=embed_forward,
     supports_int8=True,
+    decode_forward_routed=decode_forward_routed,
 ))

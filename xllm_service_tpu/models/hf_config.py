@@ -94,6 +94,62 @@ def _granite_hybrid(hf: dict, ckpt_dir) -> dict[str, Any]:
     return kw
 
 
+def _deepseek(hf: dict, ckpt_dir) -> dict[str, Any]:
+    """DeepSeek-V2 / -V3 (models/deepseek_moe.py): latent attention and
+    routed experts. What the family does not compute is refused here, by
+    the config's own key, not dropped."""
+    mt = hf["model_type"]
+
+    def refuse(why: str):
+        raise ValueError(f"{mt} under {ckpt_dir}: {why}")
+
+    if hf.get("q_lora_rank") is not None:
+        refuse(f"q_lora_rank={hf['q_lora_rank']}: a low-rank query "
+               "projection is not implemented (q_proj is one kernel)")
+    for key in ("n_group", "topk_group"):
+        if (hf.get(key) or 1) > 1:
+            refuse(f"{key}={hf[key]}: group-limited routing is not "
+                   "implemented (the experts are chosen over all of them)")
+    if hf.get("rope_scaling") is not None:
+        refuse(f"rope_scaling={hf['rope_scaling']!r}: scaled rotary "
+               "embeddings are not implemented")
+    scoring = hf.get("scoring_func", "softmax")
+    method = hf.get("topk_method", "greedy")
+    if scoring not in ("softmax", "sigmoid"):
+        refuse(f"scoring_func {scoring!r}: softmax and sigmoid are "
+               "implemented")
+    if method not in ("greedy", "group_limited_greedy", "noaux_tc"):
+        refuse(f"topk_method {method!r} is not implemented")
+    kw = _common(hf)
+    latent = hf["kv_lora_rank"] + hf["qk_rope_head_dim"]
+    # MLA: the paged cache stores one [kv_lora_rank + rope] latent
+    # per token — advertised as a single wide KV head (the engine's
+    # pool layout; see deepseek_v2_lite_config), held at the next lane
+    # multiple (576 -> 640) so that the paged kernel's tiling takes it.
+    kw.update(
+        name="deepseek_moe",
+        num_kv_heads=1,
+        head_dim=latent,
+        kv_held_dim=-(-latent // 128) * 128,
+        kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"],
+        num_experts=hf.get("n_routed_experts", 0),
+        num_experts_per_token=hf.get("num_experts_per_tok", 2),
+        num_shared_experts=hf.get("n_shared_experts", 0),
+        moe_ffn_size=hf.get("moe_intermediate_size", 0),
+        first_dense_layers=hf.get("first_k_dense_replace", 1),
+        router_scoring=scoring,
+        router_bias=method == "noaux_tc",
+        router_norm_topk=bool(hf.get("norm_topk_prob", mt == "deepseek_v3")),
+        routed_scale=float(hf.get("routed_scaling_factor", 1.0)),
+        # V2's published code rotates adjacent pairs (as complex numbers);
+        # V3's config says which
+        rope_interleave=bool(hf.get("rope_interleave", True)))
+    return kw
+
+
 def model_config_from_hf(ckpt_dir: str | Path, *,
                          dtype=None,
                          max_context_len: int | None = None) -> ModelConfig:
@@ -133,23 +189,7 @@ def model_config_from_hf(ckpt_dir: str | Path, *,
                   moe_ffn_size=hf["intermediate_size"],
                   num_shared_experts=0, first_dense_layers=0)
     elif mt in ("deepseek_v2", "deepseek_v3"):
-        kw = _common(hf)
-        # MLA: the paged cache stores one [kv_lora_rank + rope] latent
-        # per token — advertised as a single wide KV head (the engine's
-        # pool layout; see deepseek_v2_lite_config).
-        kw.update(
-            name="deepseek_moe",
-            num_kv_heads=1,
-            head_dim=hf["kv_lora_rank"] + hf["qk_rope_head_dim"],
-            kv_lora_rank=hf["kv_lora_rank"],
-            qk_nope_head_dim=hf["qk_nope_head_dim"],
-            qk_rope_head_dim=hf["qk_rope_head_dim"],
-            v_head_dim=hf["v_head_dim"],
-            num_experts=hf.get("n_routed_experts", 0),
-            num_experts_per_token=hf.get("num_experts_per_tok", 2),
-            num_shared_experts=hf.get("n_shared_experts", 0),
-            moe_ffn_size=hf.get("moe_intermediate_size", 0),
-            first_dense_layers=hf.get("first_k_dense_replace", 1))
+        kw = _deepseek(hf, ckpt_dir)
     elif mt == "qwen2_vl":
         from . import qwen2_vl  # noqa: F401 — registers the family
         from .base import VisionConfig

@@ -206,6 +206,9 @@ def load_hf_deepseek_safetensors(ckpt_dir: str | Path, cfg: ModelConfig,
             acc(lay, "v_proj/kernel", L, li, t.T)
         elif leaf == "mlp.gate.weight":
             acc(moe, "router/kernel", Lm, mi, t.T.astype(np.float32))
+        elif leaf == "mlp.gate.e_score_correction_bias":
+            # DeepSeek-V3: enters the choice of experts only.
+            acc(moe, "router/bias", Lm, mi, t.astype(np.float32))
         elif leaf.startswith("mlp.experts."):
             e_str, _, w = leaf[len("mlp.experts."):].partition(".")
             ei = int(e_str)

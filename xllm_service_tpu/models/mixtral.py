@@ -15,6 +15,7 @@ from .base import ModelConfig, ModelFamily, register_model_family
 from .deepseek_moe import (
     MOE_STACKED_RULES,
     decode_forward,
+    decode_forward_routed,
     embed_forward,
     init_params,
     prefill_forward,
@@ -50,4 +51,5 @@ register_model_family(ModelFamily(
     verify_forward=verify_forward,
     embed_forward=embed_forward,
     supports_int8=True,
+    decode_forward_routed=decode_forward_routed,
 ))
