@@ -33,6 +33,7 @@ import contextlib
 import dataclasses
 import json
 import os
+import re
 import sys
 import time
 
@@ -161,6 +162,37 @@ def compile_engine_programs(cfg, mesh=None, device=None, horizons=(1,),
     return out
 
 
+def instruction_counts(hlo_text: str) -> dict:
+    """How many device operations a program is made of: the fusions, loops,
+    sorts and copies of every computation of the optimised HLO that is not
+    a fusion's or a reducer's body (the entry, and the loop bodies and
+    branches it calls: at a horizon above 1 the step is a `while` body).
+    Each executes as an op of its own, a few microseconds whatever its
+    size: a step of hundreds of small ones is bound by their number."""
+    kinds = {"fusion": "fusions", "while": "whiles", "sort": "sorts",
+             "copy": "copies"}
+    ops, inner, current = {}, set(), None    # per computation; bodies' names
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            current = ops.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            current = None
+        elif current is not None:
+            op = re.search(r" (fusion|while|sort|copy)\(", line)
+            if op:
+                current.append(kinds[op.group(1)])
+            if " call(" not in line:        # a call's target runs as ops
+                inner.update(re.findall(
+                    r"(?:calls|to_apply)=%?([\w.\-]+)", line))
+    counts = dict.fromkeys(kinds.values(), 0)
+    for name, found in ops.items():
+        if name not in inner:
+            for kind in found:
+                counts[kind] += 1
+    return counts
+
+
 def describe_compiled(compiled, seconds: float) -> dict:
     m = compiled.memory_analysis()
     text = compiled.as_text()
@@ -174,6 +206,7 @@ def describe_compiled(compiled, seconds: float) -> dict:
         "fits_hbm": total <= HBM_BYTES,
         "tpu_custom_calls": text.count("tpu_custom_call"),
         "all_reduces": text.count("all-reduce("),
+        **instruction_counts(text),
     }
 
 

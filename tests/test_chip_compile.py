@@ -145,3 +145,46 @@ def test_decode_step_full_width_tp4(chip):
     assert prog["all_reduces"] >= 2 * 2      # o_proj + down_proj per layer
     assert out["attention_paths"]["decode_multi"] == {
         "paged_attention": "pallas (shard_map model=4)"}
+
+
+def test_instruction_counts_read_the_ops_a_program_executes():
+    """`describe_compiled`'s op counts: the entry, loop bodies and called
+    computations count; a fusion's body and a sort's comparator do not.
+    (No compile: a hand-written module.)"""
+    text = """HloModule jit_step
+
+%fused_computation.1 (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  ROOT %copy.9 = f32[8]{0} copy(%p)
+}
+
+%compare.2 (a: f32[], b: f32[]) -> pred[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %lt = pred[] compare(%a, %b), direction=LT
+}
+
+%body.3 (t: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %t = (s32[], f32[8]{0}) parameter(0)
+  %x = f32[8]{0} get-tuple-element(%t), index=1
+  %fusion.4 = f32[8]{0} fusion(%x), kind=kLoop, calls=%fused_computation.1
+  %sort.5 = f32[8]{0} sort(%fusion.4), dimensions={0}, to_apply=%compare.2
+  %copy.6 = f32[8]{0} copy(%sort.5)
+  ROOT %tuple = (s32[], f32[8]{0}) tuple(%i, %copy.6)
+}
+
+%cond.7 (t: (s32[], f32[8])) -> pred[] {
+  %t = (s32[], f32[8]{0}) parameter(0)
+  ROOT %done = pred[] constant(false)
+}
+
+ENTRY %main.8 (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0)
+  %fusion.10 = f32[8]{0} fusion(%x), kind=kLoop, calls=%fused_computation.1
+  %fusion.11 = (f32[8]{0}, f32[8]{0}) fusion(%x), kind=kLoop, calls=%fused_computation.1
+  %while.12 = (s32[], f32[8]{0}) while(%init), condition=%cond.7, body=%body.3
+  ROOT %out = f32[8]{0} get-tuple-element(%while.12), index=1
+}
+"""
+    assert gate.instruction_counts(text) == {
+        "fusions": 3, "whiles": 1, "sorts": 1, "copies": 1}

@@ -130,22 +130,96 @@ def test_dead_rows_reach_no_expert():
     assert dm._moe_mlp(moe, 0, x, cfg, none)[1].tolist() == [0, 0]
 
 
-@pytest.mark.parametrize("sizes", [[5, 0, 9, 3], [0, 0, 0, 0], [24, 0, 0, 0]])
-def test_the_pallas_grouped_product_in_interpret_mode(sizes):
-    """The kernel the chip runs (megablox over the whole stack with one
-    layer's groups set) against plain products; rows of no group are the
-    caller's to mask."""
+def _pairs(sizes, total):
+    """Pairs' experts with `sizes[g]` pairs on group g and the rest dead,
+    shuffled: the plan has to sort them."""
+    pe = np.concatenate([np.full(n, g) for g, n in enumerate(sizes)]
+                        + [np.full(total - sum(sizes), len(sizes))])
+    return np.random.default_rng(sum(sizes)).permutation(pe).astype(np.int32)
+
+
+def _plan_cases():
+    rng = np.random.default_rng(7)
+    mixed = rng.integers(0, 128, 192)
+    mixed[rng.random(192) < 0.6] = 128
+    return {
+        # name: (pairs' experts, experts, row tile)
+        "decode-192-pairs": (rng.integers(0, 128, 192), 128, 16),
+        "prefill-3072-pairs": (rng.integers(0, 128, 3072), 128, 32),
+        "all-on-one-expert": (np.full(192, 5), 128, 16),
+        "no-live-row": (np.full(192, 128), 128, 16),
+        "dead-rows-mixed-in": (mixed, 128, 16),
+        "an-expert-over-three-tiles": (_pairs([3, 0, 0, 40, 0, 0, 0, 5], 64),
+                                       8, 16),
+        "pairs-not-a-multiple-of-the-tile": (rng.integers(0, 9, 50), 8, 16),
+    }
+
+
+_PLAN_CASES = _plan_cases()
+
+
+@pytest.mark.parametrize("case", _PLAN_CASES)
+def test_the_dispatch_plan_is_the_librarys_group_metadata(case):
+    """`dispatch_plan` against `megablox.gmm.make_group_metadata`, the
+    independent reference (it builds the list from the sizes with
+    cumulative sums, `repeat` and a histogram): the same offsets, the same
+    (group, row tile) visits in the same order up to the count; past it
+    the plan repeats its last visit. The order is the stable sort's and
+    `inverse` undoes it."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import (
+        make_group_metadata)
+
+    pe, E, tm = _PLAN_CASES[case]
+    P = len(pe)
+    plan = jax.jit(gm.dispatch_plan, static_argnums=(1, 2))(
+        jnp.asarray(pe, jnp.int32), E, tm)
+    assert np.array_equal(plan.order, np.argsort(pe, kind="stable"))
+    assert np.array_equal(np.asarray(plan.inverse)[np.asarray(plan.order)],
+                          np.arange(P))
+    assert np.array_equal(plan.sizes, np.bincount(pe, minlength=E + 1)[:E])
+    (offsets, group_ids, m_tile_ids), n = make_group_metadata(
+        group_sizes=plan.sizes, m=-(-P // tm) * tm, tm=tm,
+        start_group=jnp.int32(0), num_nonzero_groups=E,
+        visit_empty_groups=False)
+    n = int(n)
+    assert int(plan.num_visits) == n
+    assert np.array_equal(plan.offsets, offsets)
+    assert plan.group_ids.shape == group_ids.shape == (-(-P // tm) + E - 1,)
+    assert np.array_equal(plan.group_ids[:n], group_ids[:n])
+    assert np.array_equal(plan.m_tile_ids[:n], m_tile_ids[:n])
+    last = max(n - 1, 0)
+    assert (np.asarray(plan.group_ids[n:]) == plan.group_ids[last]).all()
+    assert (np.asarray(plan.m_tile_ids[n:]) == plan.m_tile_ids[last]).all()
+    assert int(plan.group_ids.max()) < E
+    if case == "an-expert-over-three-tiles":
+        # group 3 holds rows 3..42: tiles 0, 1 and 2; it shares tile 0
+        # with group 0 and tile 2 with group 7
+        assert plan.group_ids[:n].tolist() == [0, 3, 3, 3, 7]
+        assert plan.m_tile_ids[:n].tolist() == [0, 0, 1, 2, 2]
+    if case == "no-live-row":
+        assert n == 0
+
+
+@pytest.mark.parametrize("sizes,layer", [
+    ([5, 0, 9, 3], 2), ([0, 0, 0, 0], 2), ([24, 0, 0, 0], 2),
+    ([1, 16, 0, 6], 1)])
+def test_the_pallas_grouped_product_in_interpret_mode(sizes, layer):
+    """The kernel the chip runs (the repo's, over the whole stack, with
+    the layer's index added to the visit's group) against plain products;
+    rows of no group are the caller's to mask."""
     L, G, K, N = 3, 4, 256, 128
     stack = jax.random.normal(jax.random.PRNGKey(0), (L, G, K, N))
     x = jax.random.normal(jax.random.PRNGKey(1), (24, K))
-    sz = jnp.asarray(sizes, jnp.int32)
+    plan = gm.dispatch_plan(
+        jnp.asarray(np.sort(_pairs(sizes, 24))), G, gm.row_tile(24, G))
+    assert plan.sizes.tolist() == sizes
     want, row = [], 0
     for g, n in enumerate(sizes):
-        want.append(x[row:row + n] @ stack[2, g])
+        want.append(x[row:row + n] @ stack[layer, g])
         row += n
     want = jnp.concatenate(want)
     for interpret in (True, False):
-        got = gm.grouped_matmul(x, stack, 2, sz, backend="cpu",
+        got = gm.grouped_matmul(x, stack, layer, plan, backend="cpu",
                                 interpret=interpret)
         assert got.shape == (24, N)
         assert np.allclose(np.asarray(got[:row]), np.asarray(want),
@@ -157,6 +231,97 @@ def test_the_pallas_grouped_product_in_interpret_mode(sizes):
     assert gm._tiling(192, 2048, 768, 128, 2) == (16, 2048, 768)
     assert gm._tiling(192, 768, 2048, 128, 2) == (16, 768, 2048)
     assert gm._tiling(64, 4096, 14336, 8, 2)[1:] == (2048, 768)
+    # a plan made for another row tile is refused, not mis-read
+    with pytest.raises(ValueError, match="visits"):
+        gm.grouped_matmul(x, stack, layer, gm.dispatch_plan(
+            jnp.zeros((24,), jnp.int32), G, 8), backend="cpu",
+            interpret=True)
+
+
+def test_the_pallas_kernel_takes_column_strips_and_a_ragged_k():
+    """Widths past one block: K in tiles of 2048 with a remainder that is
+    masked, N in strips of 768, bfloat16 operands; a group that straddles
+    row tiles. Against `ragged_dot` to the output's rounding."""
+    L, G, K, N = 2, 3, 2048 + 512, 1024
+    stack = (0.05 * jax.random.normal(jax.random.PRNGKey(0), (L, G, K, N))
+             ).astype(jnp.bfloat16)
+    x = jax.random.normal(jax.random.PRNGKey(1), (40, K)).astype(
+        jnp.bfloat16)
+    assert gm._tiling(40, K, N, G, 2) == (16, 2048, 768)
+    plan = gm.dispatch_plan(jnp.asarray(np.sort(_pairs([17, 0, 20], 40))),
+                            G, 16)
+    got, want = (gm.grouped_matmul(x, stack, 1, plan, backend="cpu",
+                                   interpret=i)[:37].astype(jnp.float32)
+                 for i in (True, False))
+    assert float(jnp.abs(want).max()) > 4
+    assert float(jnp.abs(got - want).max()) <= 2 ** -5
+
+
+def test_the_expert_block_on_the_cpu_is_the_parents_bit_for_bit():
+    """`_moe_mlp` through `ragged_dot` with the plan's order, inverse and
+    sizes: the live rows' output of the tree before the plan (PR 36's,
+    values pasted from it), to the last bit."""
+    import hashlib
+
+    cfg = _cfg(**V3)
+    moe, x, live = _block(cfg)
+    y, counts = dm._moe_mlp(moe, 1, x, cfg, live)
+    rows = np.asarray(y)[np.asarray(live)]
+    assert counts.tolist() == [22, 8]
+    assert [float(v).hex() for v in rows[0, :4]] == [
+        "-0x1.0e8b740000000p-2", "-0x1.4418920000000p-1",
+        "0x1.5d078c0000000p+0", "-0x1.44b7d20000000p-2"]
+    assert [float(v).hex() for v in rows[-1, -4:]] == [
+        "-0x1.abce260000000p-1", "-0x1.5d17a80000000p-1",
+        "-0x1.624b9c0000000p+0", "0x1.8e7eac0000000p-2"]
+    assert hashlib.sha256(rows.tobytes()).hexdigest() == (
+        "250f68bc9775f200fb9552bfc21526e91d3d4ce7cde18e141c56c597be0d1ee0")
+
+
+def _equations(jaxpr, into):
+    """Every equation of `jaxpr` by primitive, through nested programs but
+    not into a kernel's body."""
+    for eqn in jaxpr.eqns:
+        into[eqn.primitive.name] = into.get(eqn.primitive.name, 0) + 1
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (list, tuple))
+                          else [value]):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    _equations(inner, into)
+    return into
+
+
+def test_an_expert_layer_plans_once_in_few_operations():
+    """One expert layer of the benchmark's sparse configuration at
+    decode's shape (32 rows, 128 experts, 6 a token), traced down the TPU
+    branch: three kernel calls on one plan, no loop, one sort beside the
+    router's `top_k`, and few equations around them. A library upgrade or
+    an edit that brings a product's own group metadata back (PR 36: three
+    times ~150 equations and a `searchsorted` loop a layer) fails here."""
+    from benchmarks import compile_gate as gate
+
+    cfg = dm.tiny_mla_config(
+        dtype=jnp.bfloat16, hidden_size=2048, moe_ffn_size=768,
+        num_experts=128, num_experts_per_token=6, first_dense_layers=1,
+        num_layers=3, **V3)
+    moe = jax.eval_shape(
+        lambda: dm.init_params(cfg, jax.random.PRNGKey(0)))["moe"]
+    with gate.steer_to_tpu():
+        traced = jax.make_jaxpr(
+            lambda m, x, live: dm._moe_mlp(m, 1, x, cfg, live))(
+                moe, jax.ShapeDtypeStruct((32, 2048), jnp.bfloat16),
+                jax.ShapeDtypeStruct((32,), jnp.bool_))
+    count = _equations(traced.jaxpr, {})
+    assert count.pop("pallas_call") == 3
+    assert "while" not in count and "scan" not in count
+    assert count["sort"] == 1 and count["top_k"] == 1
+    assert "cumsum" not in count and "reduce_window_sum" not in count
+    count.pop("jit", None)                  # the wrappers themselves
+    # 198 as landed (PR 37), + 10%
+    assert sum(count.values()) <= 218, count
 
 
 def test_the_path_is_decided_from_what_the_code_sees():

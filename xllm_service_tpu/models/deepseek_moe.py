@@ -38,8 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..ops import attention as _attention
 from ..ops.attention import (
-    _backend,
     _pallas_interpret,
     apply_rope,
     decode_attention_step,
@@ -50,7 +50,8 @@ from ..ops.attention import (
     rms_norm,
     write_kv,
 )
-from ..ops.grouped_matmul import grouped_matmul, grouped_path
+from ..ops.grouped_matmul import (
+    dispatch_plan, grouped_matmul, grouped_path, row_tile)
 from ..parallel.mesh import AXIS_EXPERT, AXIS_MODEL
 from ..parallel.sharding import ShardingRules
 from .base import ModelConfig, ModelFamily, register_model_family
@@ -266,7 +267,7 @@ def experts_path(cfg: ModelConfig, experts: Params) -> str:
         return f"dense (mesh {dict(mesh.shape)})"
     if is_quantized(experts["gate_proj"]["kernel"]):
         return "dense (int8 experts)"
-    return grouped_path(_backend(), _pallas_interpret())
+    return grouped_path(_attention._backend(), _pallas_interpret())
 
 
 def _experts_dense(experts: Params, x2, topi, gates, E: int):
@@ -283,24 +284,22 @@ def _experts_dense(experts: Params, x2, topi, gates, E: int):
     return jnp.einsum("etd,te->td", eo.astype(jnp.float32), dense_gates)
 
 
-def _experts_grouped(stacks: Params, layer: int, x2, pair_expert, sizes,
-                     gates, live):
-    """Grouped dispatch: the (token, expert) pairs sorted by expert
-    (`pair_expert` [T*k]; a dead row's pairs carry E, sort behind every
-    expert and belong to no group), one grouped product per projection
-    over the experts that got a row (`sizes` [E]; ops/grouped_matmul.py),
-    un-sorted and weighed."""
+def _experts_grouped(stacks: Params, layer: int, x2, plan, gates, live):
+    """Grouped dispatch: the (token, expert) pairs sorted by expert as
+    the layer's plan has them (ops/grouped_matmul.py `dispatch_plan`: a
+    dead row's pairs sort behind every expert and belong to no group),
+    one grouped product per projection over the experts that got a row,
+    all three on the one plan, un-sorted and weighed."""
     T, k = gates.shape
-    order = jnp.argsort(pair_expert, stable=True)
-    xs = x2[order // k]                                    # [T*k, D]
-    mm = functools.partial(grouped_matmul, layer=layer, group_sizes=sizes,
-                           backend=_backend(),
+    xs = x2[plan.order // k]                               # [T*k, D]
+    mm = functools.partial(grouped_matmul, layer=layer, plan=plan,
+                           backend=_attention._backend(),
                            interpret=_pallas_interpret())
     g = mm(xs, stacks["gate_proj"]["kernel"])
     u = mm(xs, stacks["up_proj"]["kernel"])
     eo = mm(jax.nn.silu(g) * u, stacks["down_proj"]["kernel"])
     # back to (token, choice) order; rows of no group are undefined
-    eo = eo[jnp.argsort(order)].reshape(T, k, -1)
+    eo = eo[plan.inverse].reshape(T, k, -1)
     eo = jnp.where(live[:, None, None], eo, 0)
     return jnp.einsum("tkd,tk->td", eo.astype(jnp.float32), gates)
 
@@ -318,25 +317,31 @@ def _moe_mlp(moe: Params, layer: int, x: jax.Array, cfg: ModelConfig,
             else live.reshape(-1))
     lp = {name: jax.tree.map(lambda a: a[layer], moe[name])
           for name in moe if name != "experts"}
+    E = cfg.num_experts
     with jax.named_scope("moe.route"):
         topi, gates = _route(lp["router"], x2, cfg)
-        E = cfg.num_experts
-        # every (token, expert) pair's expert, E for a dead row's; how
-        # many pairs each expert got
+        # every (token, expert) pair's expert, E for a dead row's
         pair_expert = jnp.where(live[:, None], topi, E).reshape(-1)
-        sizes = jnp.zeros((E + 1,), jnp.int32).at[pair_expert].add(1)[:E]
-        counts = jnp.stack([live.sum(), (sizes > 0).sum()]).astype(
-            jnp.int32)
     path = experts_path(cfg, moe["experts"])
     note_path("moe_experts", path)
     with jax.named_scope("moe.experts"):
         if path.startswith("grouped"):
-            routed = _experts_grouped(moe["experts"], layer, x2,
-                                      pair_expert, sizes, gates, live)
+            # the layer's dispatch, once: the three products and the
+            # router's count read the same plan
+            with jax.named_scope("moe.plan"):
+                plan = dispatch_plan(pair_expert, E,
+                                     row_tile(pair_expert.shape[0], E))
+            sizes = plan.sizes
+            routed = _experts_grouped(moe["experts"], layer, x2, plan,
+                                      gates, live)
         else:
+            sizes = jnp.zeros((E + 1,), jnp.int32).at[pair_expert].add(
+                1)[:E]
             routed = _experts_dense(
                 jax.tree.map(lambda a: a[layer], moe["experts"]), x2,
                 topi, gates, E)
+    # how many rows are live, how many experts got a pair
+    counts = jnp.stack([live.sum(), (sizes > 0).sum()]).astype(jnp.int32)
     routed = routed.astype(x.dtype)
 
     if "shared" in lp:
@@ -393,6 +398,14 @@ def _mla_attention(lp, cfg, h, mode, kv_pages, layer, page_table,
 
     # Queries: nope part absorbed through the K up-projection.
     q = quantized_einsum("...d,df->...f", h, lp["q_proj"]["kernel"])
+    # Pin q as the projection makes it. Without the barrier the TPU
+    # compiler lays q out for the per-head product below (heads leading)
+    # and carries that back through the projection to its weight: every
+    # step it cut all layers' `q_proj` out of the stack into buffers of
+    # their own and transposed them (0.4 GB of traffic a step at
+    # kanana-2's widths, PERF.md §6 PR 37) where every other weight is
+    # read in place.
+    q = jax.lax.optimization_barrier(q)
     q = q.reshape(*q.shape[:-1], H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     q_rope = _rope_part(q_rope, positions, cfg)
