@@ -64,7 +64,7 @@ from ..common.hashing import prefix_block_hashes
 from ..common.types import InstanceType, KvCacheEvent
 from ..devtools import ownership as _ownership
 from ..devtools.locks import make_lock
-from ..models.base import get_model_family
+from ..models.base import block, get_model_family
 from ..ops.page_walk import page_chunk_size, walk_run_counts
 from ..parallel.mesh import build_mesh
 from ..parallel.sharding import shard_params
@@ -567,6 +567,7 @@ class InferenceEngine:
                                  d["pp"], d["rp"], d["counts"],
                                  d["bias_ids"], d["bias_vals"])
 
+        @block("sample")
         def _post_decode_forward(d, logits):
             """Shared tail of one decode step (sampling, penalties,
             logprobs, device-side stop/budget freeze) — used by both the
@@ -617,6 +618,7 @@ class InferenceEngine:
             d["active"] = advance
             return d, (toks, chosen, tv, ti)
 
+        @block("sample")
         def _pack_scan_outputs(d, ys):
             toks, chosen, tv, ti, *router_counts = ys
             # ONE packed download [H, B, 2+2K] f32 (token/ids are exact in
@@ -809,58 +811,62 @@ class InferenceEngine:
                             page_row[None, :], prefix_len[None],
                             seq_len[None])
                 d = dict(d, kv=kv)
-                st = SamplingState(
-                    floats[0:1], floats[1:2].astype(jnp.int32), floats[2:3],
-                    floats[3:4], floats[4:5], floats[5:6],
-                    counts_row[None, :],
-                    ints[P + 4 + NS:P + 4 + NS + NB][None, :],
-                    floats[6:6 + NB][None, :])
-                toks, logprobs = sample_tokens(
-                    logits, st, key[None, :], (prefix_len + seq_len)[None])
-                chosen = jnp.take_along_axis(logprobs, toks[:, None],
-                                             axis=-1)[:, 0]
-                tv, ti = jax.lax.top_k(logprobs, K)
-                # Install the slot.
-                d["pt"] = d["pt"].at[slot].set(page_row)
-                d["last"] = d["last"].at[slot].set(toks[0])
-                d["clens"] = d["clens"].at[slot].set(prefix_len + seq_len + 1)
-                d["active"] = d["active"].at[slot].set(True)
-                d["temp"] = d["temp"].at[slot].set(floats[0])
-                d["topk"] = d["topk"].at[slot].set(
-                    floats[1].astype(jnp.int32))
-                d["topp"] = d["topp"].at[slot].set(floats[2])
-                d["fp"] = d["fp"].at[slot].set(floats[3])
-                d["pp"] = d["pp"].at[slot].set(floats[4])
-                d["rp"] = d["rp"].at[slot].set(floats[5])
-                d["keys"] = d["keys"].at[slot].set(key)
-                d["want_lp"] = d["want_lp"].at[slot].set(ints[P + 3] > 0)
-                d["stop_ids"] = d["stop_ids"].at[slot].set(
-                    ints[P + 4:P + 4 + NS])
-                d["bias_ids"] = d["bias_ids"].at[slot].set(
-                    ints[P + 4 + NS:P + 4 + NS + NB])
-                d["bias_vals"] = d["bias_vals"].at[slot].set(
-                    floats[6:6 + NB])
-                d["counts"] = d["counts"].at[slot].set(
-                    counts_row.at[toks[0]].add(1))
-                d["budget"] = d["budget"].at[slot].set(
-                    ints[P + 4 + NS + NB])
-                if is_vl:
-                    d["mrope_delta"] = d["mrope_delta"].at[slot].set(mdelta)
-                if spec_on:
-                    # Seed the device history with the uploaded suffix +
-                    # the first sampled token; tokens before prefix_len
-                    # were never uploaded, so drafts search from there.
-                    hpos = prefix_len + jnp.arange(S, dtype=jnp.int32)
-                    hpos = jnp.where(jnp.arange(S) < seq_len, hpos, LH)
-                    d["hist"] = d["hist"].at[slot, hpos].set(
-                        tokens[0], mode="drop")
-                    d["hist"] = d["hist"].at[
-                        slot, prefix_len + seq_len].set(toks[0],
-                                                        mode="drop")
-                    d["hist_lo"] = d["hist_lo"].at[slot].set(prefix_len)
-                packed = jnp.concatenate(
-                    [toks.astype(jnp.float32), chosen, tv[0],
-                     ti[0].astype(jnp.float32)])
+                # The install tail: the first token's sampling and the
+                # slot's sampling state, one block with a decode step's tail.
+                with block("sample"):
+                    st = SamplingState(
+                        floats[0:1], floats[1:2].astype(jnp.int32), floats[2:3],
+                        floats[3:4], floats[4:5], floats[5:6],
+                        counts_row[None, :],
+                        ints[P + 4 + NS:P + 4 + NS + NB][None, :],
+                        floats[6:6 + NB][None, :])
+                    toks, logprobs = sample_tokens(
+                        logits, st, key[None, :], (prefix_len + seq_len)[None])
+                    chosen = jnp.take_along_axis(logprobs, toks[:, None],
+                                                 axis=-1)[:, 0]
+                    tv, ti = jax.lax.top_k(logprobs, K)
+                    # Install the slot.
+                    d["pt"] = d["pt"].at[slot].set(page_row)
+                    d["last"] = d["last"].at[slot].set(toks[0])
+                    d["clens"] = d["clens"].at[slot].set(
+                        prefix_len + seq_len + 1)
+                    d["active"] = d["active"].at[slot].set(True)
+                    d["temp"] = d["temp"].at[slot].set(floats[0])
+                    d["topk"] = d["topk"].at[slot].set(
+                        floats[1].astype(jnp.int32))
+                    d["topp"] = d["topp"].at[slot].set(floats[2])
+                    d["fp"] = d["fp"].at[slot].set(floats[3])
+                    d["pp"] = d["pp"].at[slot].set(floats[4])
+                    d["rp"] = d["rp"].at[slot].set(floats[5])
+                    d["keys"] = d["keys"].at[slot].set(key)
+                    d["want_lp"] = d["want_lp"].at[slot].set(ints[P + 3] > 0)
+                    d["stop_ids"] = d["stop_ids"].at[slot].set(
+                        ints[P + 4:P + 4 + NS])
+                    d["bias_ids"] = d["bias_ids"].at[slot].set(
+                        ints[P + 4 + NS:P + 4 + NS + NB])
+                    d["bias_vals"] = d["bias_vals"].at[slot].set(
+                        floats[6:6 + NB])
+                    d["counts"] = d["counts"].at[slot].set(
+                        counts_row.at[toks[0]].add(1))
+                    d["budget"] = d["budget"].at[slot].set(
+                        ints[P + 4 + NS + NB])
+                    if is_vl:
+                        d["mrope_delta"] = d["mrope_delta"].at[slot].set(mdelta)
+                    if spec_on:
+                        # Seed the device history with the uploaded suffix +
+                        # the first sampled token; tokens before prefix_len
+                        # were never uploaded, so drafts search from there.
+                        hpos = prefix_len + jnp.arange(S, dtype=jnp.int32)
+                        hpos = jnp.where(jnp.arange(S) < seq_len, hpos, LH)
+                        d["hist"] = d["hist"].at[slot, hpos].set(
+                            tokens[0], mode="drop")
+                        d["hist"] = d["hist"].at[
+                            slot, prefix_len + seq_len].set(toks[0],
+                                                            mode="drop")
+                        d["hist_lo"] = d["hist_lo"].at[slot].set(prefix_len)
+                    packed = jnp.concatenate(
+                        [toks.astype(jnp.float32), chosen, tv[0],
+                         ti[0].astype(jnp.float32)])
                 return pin(d), packed
 
             return prefill_install

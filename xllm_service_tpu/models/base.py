@@ -18,7 +18,25 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
+import jax
 import jax.numpy as jnp
+
+# The blocks of a served program: every device op of a forward and of the
+# engine's program tails is traced under exactly one of them, in every
+# family, so that a device trace can be summed by block (docs/
+# observability.md "By block"; the benchmark's `block.*` metrics).
+BLOCKS = ("attn", "mlp", "moe", "ssm", "head", "sample")
+BLOCK_PREFIX = "blk."
+
+
+def block(name: str):
+    """The scope of one block of the model: `with block("attn"): ...`.
+    A name in HLO metadata and nothing else (no op is added or moved);
+    scopes of a finer grain (`moe.route`, `mla.decode`, `ssm_update`, a
+    kernel's jit) nest inside it."""
+    if name not in BLOCKS:
+        raise ValueError(f"no block {name!r} among {BLOCKS}")
+    return jax.named_scope(BLOCK_PREFIX + name)
 
 
 @dataclass(frozen=True)

@@ -54,7 +54,7 @@ from ..ops.grouped_matmul import (
     dispatch_plan, grouped_matmul, grouped_path, row_tile)
 from ..parallel.mesh import AXIS_EXPERT, AXIS_MODEL
 from ..parallel.sharding import ShardingRules
-from .base import ModelConfig, ModelFamily, register_model_family
+from .base import ModelConfig, ModelFamily, block, register_model_family
 from .quant import is_quantized, quantized_einsum
 from .llama import _project_qkv, _unembed
 
@@ -311,47 +311,51 @@ def _moe_mlp(moe: Params, layer: int, x: jax.Array, cfg: ModelConfig,
     hold a token of a running request; the others reach no expert (None:
     all). counts int32 [2]: the live rows, and the experts that got at
     least one of them. One function for prefill, verify and decode."""
-    orig_shape = x.shape
-    x2 = x.reshape(-1, orig_shape[-1])                     # [T, D]
-    live = (jnp.ones(x2.shape[:1], bool) if live is None
-            else live.reshape(-1))
-    lp = {name: jax.tree.map(lambda a: a[layer], moe[name])
-          for name in moe if name != "experts"}
-    E = cfg.num_experts
-    with jax.named_scope("moe.route"):
-        topi, gates = _route(lp["router"], x2, cfg)
-        # every (token, expert) pair's expert, E for a dead row's
-        pair_expert = jnp.where(live[:, None], topi, E).reshape(-1)
     path = experts_path(cfg, moe["experts"])
     note_path("moe_experts", path)
-    with jax.named_scope("moe.experts"):
-        if path.startswith("grouped"):
-            # the layer's dispatch, once: the three products and the
-            # router's count read the same plan
-            with jax.named_scope("moe.plan"):
-                plan = dispatch_plan(pair_expert, E,
-                                     row_tile(pair_expert.shape[0], E))
-            sizes = plan.sizes
-            routed = _experts_grouped(moe["experts"], layer, x2, plan,
-                                      gates, live)
-        else:
-            sizes = jnp.zeros((E + 1,), jnp.int32).at[pair_expert].add(
-                1)[:E]
-            routed = _experts_dense(
-                jax.tree.map(lambda a: a[layer], moe["experts"]), x2,
-                topi, gates, E)
-    # how many rows are live, how many experts got a pair
-    counts = jnp.stack([live.sum(), (sizes > 0).sum()]).astype(jnp.int32)
-    routed = routed.astype(x.dtype)
+    with block("moe"):
+        orig_shape = x.shape
+        x2 = x.reshape(-1, orig_shape[-1])                 # [T, D]
+        live = (jnp.ones(x2.shape[:1], bool) if live is None
+                else live.reshape(-1))
+        lp = {name: jax.tree.map(lambda a: a[layer], moe[name])
+              for name in moe if name != "experts"}
+        E = cfg.num_experts
+        with jax.named_scope("moe.route"):
+            topi, gates = _route(lp["router"], x2, cfg)
+            # every (token, expert) pair's expert, E for a dead row's
+            pair_expert = jnp.where(live[:, None], topi, E).reshape(-1)
+        with jax.named_scope("moe.experts"):
+            if path.startswith("grouped"):
+                # the layer's dispatch, once: the three products and the
+                # router's count read the same plan
+                with jax.named_scope("moe.plan"):
+                    plan = dispatch_plan(pair_expert, E,
+                                         row_tile(pair_expert.shape[0], E))
+                sizes = plan.sizes
+                routed = _experts_grouped(moe["experts"], layer, x2, plan,
+                                          gates, live)
+            else:
+                sizes = jnp.zeros((E + 1,), jnp.int32).at[
+                    pair_expert].add(1)[:E]
+                routed = _experts_dense(
+                    jax.tree.map(lambda a: a[layer], moe["experts"]), x2,
+                    topi, gates, E)
+        # how many rows are live, how many experts got a pair
+        counts = jnp.stack([live.sum(),
+                            (sizes > 0).sum()]).astype(jnp.int32)
+        routed = routed.astype(x.dtype)
 
     if "shared" in lp:
-        sg = quantized_einsum("td,df->tf", x2,
-                              lp["shared"]["gate_proj"]["kernel"])
-        su = quantized_einsum("td,df->tf", x2,
-                              lp["shared"]["up_proj"]["kernel"])
-        routed = routed + quantized_einsum(
-            "tf,fd->td", jax.nn.silu(sg) * su,
-            lp["shared"]["down_proj"]["kernel"]).astype(routed.dtype)
+        # the shared expert is a dense MLP over every row: the MLP block's
+        with block("mlp"):
+            sg = quantized_einsum("td,df->tf", x2,
+                                  lp["shared"]["gate_proj"]["kernel"])
+            su = quantized_einsum("td,df->tf", x2,
+                                  lp["shared"]["up_proj"]["kernel"])
+            routed = routed + quantized_einsum(
+                "tf,fd->td", jax.nn.silu(sg) * su,
+                lp["shared"]["down_proj"]["kernel"]).astype(routed.dtype)
     return routed.reshape(orig_shape), counts
 
 
@@ -460,38 +464,47 @@ def _run_layers(params, cfg, x, kv_pages, mode, page_table, prefix_lens,
     Ld = cfg.first_dense_layers
     dense = kv_pages is None            # embeddings: no cache at all
     for l in range(cfg.num_layers):
-        lp = jax.tree.map(lambda a, _l=l: a[_l], params["layers"])
-        h = rms_norm(x, lp["input_norm"]["scale"], cfg.rms_eps)
-        if use_mla:
-            attn, kv_pages = _mla_attention(
-                lp, cfg, h, "dense" if dense else mode, kv_pages, l,
-                page_table, prefix_lens, seq_lens, positions, context_lens)
-        else:
-            q, k, v = _project_qkv(lp, h, cfg, positions)
-            if dense:
-                attn = prefill_attention(
-                    q, k, v, None, None, None,
-                    jnp.zeros(x.shape[:1], jnp.int32), seq_lens)
-            elif mode == "prefill":
-                kv_pages = write_kv(kv_pages, l, k, v, page_table,
-                                    prefix_lens, seq_lens)
-                attn = prefill_attention(q, k, v, kv_pages, l,
-                                         page_table, prefix_lens, seq_lens)
+        with block("attn"):
+            lp = jax.tree.map(lambda a, _l=l: a[_l], params["layers"])
+            h = rms_norm(x, lp["input_norm"]["scale"], cfg.rms_eps)
+            if use_mla:
+                attn, kv_pages = _mla_attention(
+                    lp, cfg, h, "dense" if dense else mode, kv_pages, l,
+                    page_table, prefix_lens, seq_lens, positions,
+                    context_lens)
             else:
-                attn, kv_pages = decode_attention_step(
-                    q, k, v, kv_pages, l, page_table, context_lens)
-            attn = attn.reshape(*attn.shape[:-2], cfg.q_size)
-        x = x + quantized_einsum("...f,fd->...d", attn,
-                                 lp["o_proj"]["kernel"])
-        h2 = rms_norm(x, lp["post_attn_norm"]["scale"], cfg.rms_eps)
+                q, k, v = _project_qkv(lp, h, cfg, positions)
+                if dense:
+                    attn = prefill_attention(
+                        q, k, v, None, None, None,
+                        jnp.zeros(x.shape[:1], jnp.int32), seq_lens)
+                elif mode == "prefill":
+                    kv_pages = write_kv(kv_pages, l, k, v, page_table,
+                                        prefix_lens, seq_lens)
+                    attn = prefill_attention(q, k, v, kv_pages, l,
+                                             page_table, prefix_lens,
+                                             seq_lens)
+                else:
+                    attn, kv_pages = decode_attention_step(
+                        q, k, v, kv_pages, l, page_table, context_lens)
+                attn = attn.reshape(*attn.shape[:-2], cfg.q_size)
+            x = x + quantized_einsum("...f,fd->...d", attn,
+                                     lp["o_proj"]["kernel"])
         if l < Ld:
-            x = x + _dense_mlp(
-                jax.tree.map(lambda a, _l=l: a[_l], params["dense_mlp"]),
-                h2)
+            with block("mlp"):
+                h2 = rms_norm(x, lp["post_attn_norm"]["scale"], cfg.rms_eps)
+                x = x + _dense_mlp(
+                    jax.tree.map(lambda a, _l=l: a[_l],
+                                 params["dense_mlp"]), h2)
         else:
+            # the norm feeds the router, the experts and the shared
+            # expert; `_moe_mlp` names its own blocks
+            with block("moe"):
+                h2 = rms_norm(x, lp["post_attn_norm"]["scale"], cfg.rms_eps)
             y, c = _moe_mlp(params["moe"], l - Ld, h2, cfg, live)
-            x = x + y
-            counts = jnp.stack([c[0], counts[1] + c[1]])
+            with block("moe"):
+                x = x + y
+                counts = jnp.stack([c[0], counts[1] + c[1]])
     return x, kv_pages, counts
 
 
@@ -507,8 +520,9 @@ def prefill_forward(params, cfg, tokens, positions, kv_pages, page_table,
     x, kv_pages, _ = _run_layers(
         params, cfg, x, kv_pages, "prefill", page_table, prefix_lens,
         seq_lens, positions, None, live=_suffix_live(tokens, seq_lens))
-    idx = jnp.maximum(seq_lens - 1, 0)
-    last = x[jnp.arange(x.shape[0]), idx]
+    with block("head"):
+        idx = jnp.maximum(seq_lens - 1, 0)
+        last = x[jnp.arange(x.shape[0]), idx]
     return _unembed(params, cfg, last), kv_pages
 
 
@@ -552,11 +566,12 @@ def embed_forward(params, cfg, tokens, seq_lens):
     x, _, _ = _run_layers(params, cfg, x, None, "prefill", None,
                           jnp.zeros((B,), jnp.int32), seq_lens, positions,
                           None, live=_suffix_live(tokens, seq_lens))
-    from ..ops.attention import rms_norm as _rms
-    x = _rms(x, params["final_norm"]["scale"], cfg.rms_eps)
-    mask = (jnp.arange(S)[None, :] < seq_lens[:, None])[..., None]
-    summed = jnp.sum(jnp.where(mask, x.astype(jnp.float32), 0.0), axis=1)
-    return summed / jnp.maximum(seq_lens[:, None], 1)
+    with block("head"):
+        x = rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps)
+        mask = (jnp.arange(S)[None, :] < seq_lens[:, None])[..., None]
+        summed = jnp.sum(jnp.where(mask, x.astype(jnp.float32), 0.0),
+                         axis=1)
+        return summed / jnp.maximum(seq_lens[:, None], 1)
 
 
 register_model_family(ModelFamily(

@@ -61,7 +61,7 @@ from ..ops.attention import (
 )
 from ..ops.ssm import causal_conv, conv_step, ssm_chunked_scan, ssm_update
 from ..parallel.sharding import ShardingRules
-from .base import ModelConfig, ModelFamily, register_model_family
+from .base import ModelConfig, ModelFamily, block, register_model_family
 
 Params = dict
 
@@ -238,6 +238,7 @@ def _embed(params: Params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
     return x * jnp.asarray(cfg.embed_multiplier, cfg.dtype)
 
 
+@block("head")
 def _unembed(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     x = rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps)
     logits = jnp.einsum("...d,vd->...v", x, params["embed"]["embedding"])
@@ -263,33 +264,37 @@ def prefill_forward(params: Params, cfg: ModelConfig,
     ssm, conv = [], []
     x = _embed(params, cfg, tokens)
     for layer, kind, i in _layers(cfg):
-        if kind == "mamba":
-            lp = _at(params["mamba"], i)
-            h = rms_norm(x, lp["norm"]["scale"], cfg.rms_eps)
-            z, xbc, dt = _split_in_proj(lp, h, cfg)
-            with jax.named_scope("ssm_conv"):
-                out, window = causal_conv(xbc, lp["conv"]["kernel"],
-                                          lp["conv"]["bias"], seq_lens)
-                xs, b, c = _split_conv(out, cfg)
-            with jax.named_scope("ssm_scan"):
-                dtv = jnp.where(valid[..., None], _dt(lp, dt), 0.0)
-                y, s = ssm_chunked_scan(xs, dtv, -jnp.exp(lp["A_log"]),
-                                        b, c, cfg.ssm_chunk)
-            mix = _mamba_out(lp, y, xs, z, cfg)
-            ssm.append(s)
-            conv.append(window.astype(cfg.dtype))
-        else:
-            lp = _at(params["attn"], i)
-            h = rms_norm(x, lp["norm"]["scale"], cfg.rms_eps)
-            q, k, v = _qkv(lp, h, cfg)
-            kv_pages = write_kv(kv_pages, i, _lanes(k, cfg), _lanes(v, cfg),
-                                page_table, zero, seq_lens)
-            mix = _attn_out(lp, prefill_attention(
-                q, k, v, None, None, None, zero, seq_lens,
-                scale=_attn_scale(cfg)), cfg)
-        x = x + r * mix
-        x = x + r * _mlp(_at(params["mlp"], layer), x, cfg)
-    last = x[jnp.arange(x.shape[0]), jnp.maximum(seq_lens - 1, 0)]
+        with block("ssm" if kind == "mamba" else "attn"):
+            if kind == "mamba":
+                lp = _at(params["mamba"], i)
+                h = rms_norm(x, lp["norm"]["scale"], cfg.rms_eps)
+                z, xbc, dt = _split_in_proj(lp, h, cfg)
+                with jax.named_scope("ssm_conv"):
+                    out, window = causal_conv(xbc, lp["conv"]["kernel"],
+                                              lp["conv"]["bias"], seq_lens)
+                    xs, b, c = _split_conv(out, cfg)
+                with jax.named_scope("ssm_scan"):
+                    dtv = jnp.where(valid[..., None], _dt(lp, dt), 0.0)
+                    y, s = ssm_chunked_scan(xs, dtv, -jnp.exp(lp["A_log"]),
+                                            b, c, cfg.ssm_chunk)
+                mix = _mamba_out(lp, y, xs, z, cfg)
+                ssm.append(s)
+                conv.append(window.astype(cfg.dtype))
+            else:
+                lp = _at(params["attn"], i)
+                h = rms_norm(x, lp["norm"]["scale"], cfg.rms_eps)
+                q, k, v = _qkv(lp, h, cfg)
+                kv_pages = write_kv(kv_pages, i, _lanes(k, cfg),
+                                    _lanes(v, cfg), page_table, zero,
+                                    seq_lens)
+                mix = _attn_out(lp, prefill_attention(
+                    q, k, v, None, None, None, zero, seq_lens,
+                    scale=_attn_scale(cfg)), cfg)
+            x = x + r * mix
+        with block("mlp"):
+            x = x + r * _mlp(_at(params["mlp"], layer), x, cfg)
+    with block("head"):
+        last = x[jnp.arange(x.shape[0]), jnp.maximum(seq_lens - 1, 0)]
     return (_unembed(params, cfg, last), kv_pages,
             {"ssm": jnp.stack(ssm), "conv": jnp.stack(conv)})
 
@@ -310,33 +315,36 @@ def decode_forward(params: Params, cfg: ModelConfig,
     ssm, conv = state["ssm"], state["conv"]
     x = _embed(params, cfg, tokens)
     for layer, kind, i in _layers(cfg):
-        if kind == "mamba":
-            lp = _at(params["mamba"], i)
-            h = rms_norm(x, lp["norm"]["scale"], cfg.rms_eps)
-            z, xbc, dt = _split_in_proj(lp, h, cfg)
-            with jax.named_scope("ssm_conv"):
-                out, window = conv_step(conv[i], xbc, lp["conv"]["kernel"],
-                                        lp["conv"]["bias"])
-                conv = conv.at[i].set(
-                    jnp.where(live[:, None, None], window, conv[i]))
-                xs, b, c = _split_conv(out, cfg)
-            with jax.named_scope("ssm_update"):
-                y, ssm = ssm_update(ssm, i, live, xs, _dt(lp, dt),
-                                    -jnp.exp(lp["A_log"]), b, c)
-            mix = _mamba_out(lp, y, xs, z, cfg)
-        else:
-            lp = _at(params["attn"], i)
-            h = rms_norm(x, lp["norm"]["scale"], cfg.rms_eps)
-            q, k, v = _qkv(lp, h, cfg)
-            kv_pages = write_kv(kv_pages, i, _lanes(k, cfg)[:, None],
-                                _lanes(v, cfg)[:, None], page_table,
-                                context_lens - 1,
-                                jnp.ones_like(context_lens))
-            mix = _attn_out(lp, paged_attention(
-                _lanes(q, cfg), kv_pages, i, page_table, context_lens,
-                scale=_attn_scale(cfg)), cfg)
-        x = x + r * mix
-        x = x + r * _mlp(_at(params["mlp"], layer), x, cfg)
+        with block("ssm" if kind == "mamba" else "attn"):
+            if kind == "mamba":
+                lp = _at(params["mamba"], i)
+                h = rms_norm(x, lp["norm"]["scale"], cfg.rms_eps)
+                z, xbc, dt = _split_in_proj(lp, h, cfg)
+                with jax.named_scope("ssm_conv"):
+                    out, window = conv_step(conv[i], xbc,
+                                            lp["conv"]["kernel"],
+                                            lp["conv"]["bias"])
+                    conv = conv.at[i].set(
+                        jnp.where(live[:, None, None], window, conv[i]))
+                    xs, b, c = _split_conv(out, cfg)
+                with jax.named_scope("ssm_update"):
+                    y, ssm = ssm_update(ssm, i, live, xs, _dt(lp, dt),
+                                        -jnp.exp(lp["A_log"]), b, c)
+                mix = _mamba_out(lp, y, xs, z, cfg)
+            else:
+                lp = _at(params["attn"], i)
+                h = rms_norm(x, lp["norm"]["scale"], cfg.rms_eps)
+                q, k, v = _qkv(lp, h, cfg)
+                kv_pages = write_kv(kv_pages, i, _lanes(k, cfg)[:, None],
+                                    _lanes(v, cfg)[:, None], page_table,
+                                    context_lens - 1,
+                                    jnp.ones_like(context_lens))
+                mix = _attn_out(lp, paged_attention(
+                    _lanes(q, cfg), kv_pages, i, page_table, context_lens,
+                    scale=_attn_scale(cfg)), cfg)
+            x = x + r * mix
+        with block("mlp"):
+            x = x + r * _mlp(_at(params["mlp"], layer), x, cfg)
     return _unembed(params, cfg, x), kv_pages, {"ssm": ssm, "conv": conv}
 
 

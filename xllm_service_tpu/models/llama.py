@@ -23,7 +23,7 @@ from ..ops.attention import (
 from ..parallel.sharding import ShardingRules
 from jax.sharding import PartitionSpec as P
 from ..parallel.mesh import AXIS_MODEL
-from .base import ModelConfig, ModelFamily, register_model_family
+from .base import ModelConfig, ModelFamily, block, register_model_family
 from .quant import quantized_einsum
 
 Params = dict
@@ -154,17 +154,21 @@ def _attn_mlp_residual(lp: Params, x: jax.Array, attn: jax.Array,
     """Fold the attention output and the MLP into the residual stream.
     sandwich_norms (gemma-2) norms the attention/MLP OUTPUTS as well:
     x += post_attn_norm(o_proj(attn)); x += post_ffw_norm(mlp(pre_ffw_norm(x)))."""
-    o = quantized_einsum("...f,fd->...d", attn, lp["o_proj"]["kernel"])
-    if cfg.sandwich_norms:
-        x = x + _norm(o, lp["post_attn_norm"]["scale"], cfg)
-        h2 = _norm(x, lp["pre_ffw_norm"]["scale"], cfg)
-        return x + _norm(_mlp(lp, h2, cfg),
-                         lp["post_ffw_norm"]["scale"], cfg)
-    x = x + o
-    h2 = _norm(x, lp["post_attn_norm"]["scale"], cfg)
-    return x + _mlp(lp, h2, cfg)
+    with block("attn"):
+        o = quantized_einsum("...f,fd->...d", attn, lp["o_proj"]["kernel"])
+        if cfg.sandwich_norms:
+            o = _norm(o, lp["post_attn_norm"]["scale"], cfg)
+        x = x + o
+    with block("mlp"):
+        if cfg.sandwich_norms:
+            h2 = _norm(x, lp["pre_ffw_norm"]["scale"], cfg)
+            return x + _norm(_mlp(lp, h2, cfg),
+                             lp["post_ffw_norm"]["scale"], cfg)
+        h2 = _norm(x, lp["post_attn_norm"]["scale"], cfg)
+        return x + _mlp(lp, h2, cfg)
 
 
+@block("head")
 def _unembed(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     x = _norm(x, params["final_norm"]["scale"], cfg)
     if cfg.tie_embeddings:
@@ -215,21 +219,23 @@ def prefill_from_embeddings(params: Params, cfg: ModelConfig,
     """
 
     for l in range(cfg.num_layers):
-        lp = jax.tree.map(lambda a, _l=l: a[_l], params["layers"])
-        h = _norm(x, lp["input_norm"]["scale"], cfg)
-        q, k, v = _project_qkv(lp, h, cfg, positions)
-        kv_pages = write_kv(kv_pages, l, k, v, page_table, prefix_lens,
-                            seq_lens)
-        attn = prefill_attention(q, k, v, kv_pages, l,
-                                 page_table, prefix_lens, seq_lens,
-                                 **_attn_opts(cfg, l))
-        attn = attn.reshape(*attn.shape[:-2], cfg.q_size)
+        with block("attn"):
+            lp = jax.tree.map(lambda a, _l=l: a[_l], params["layers"])
+            h = _norm(x, lp["input_norm"]["scale"], cfg)
+            q, k, v = _project_qkv(lp, h, cfg, positions)
+            kv_pages = write_kv(kv_pages, l, k, v, page_table, prefix_lens,
+                                seq_lens)
+            attn = prefill_attention(q, k, v, kv_pages, l,
+                                     page_table, prefix_lens, seq_lens,
+                                     **_attn_opts(cfg, l))
+            attn = attn.reshape(*attn.shape[:-2], cfg.q_size)
         x = _attn_mlp_residual(lp, x, attn, cfg)
     if all_logits:
         return _unembed(params, cfg, x), kv_pages
     # Last valid token's hidden state per row.
-    idx = jnp.maximum(seq_lens - 1, 0)
-    last = x[jnp.arange(x.shape[0]), idx]
+    with block("head"):
+        idx = jnp.maximum(seq_lens - 1, 0)
+        last = x[jnp.arange(x.shape[0]), idx]
     return _unembed(params, cfg, last), kv_pages
 
 
@@ -247,21 +253,24 @@ def embed_forward(params: Params, cfg: ModelConfig,
     x = _embed(params, cfg, tokens)
 
     def layer_body(l, x):
-        lp = jax.tree.map(lambda a, _l=l: a[_l], params["layers"])
-        h = _norm(x, lp["input_norm"]["scale"], cfg)
-        q, k, v = _project_qkv(lp, h, cfg, positions)
-        attn = prefill_attention(q, k, v, None, None, None,
-                                 jnp.zeros((B,), jnp.int32), seq_lens,
-                                 **_attn_opts(cfg, l))
-        attn = attn.reshape(*attn.shape[:-2], cfg.q_size)
+        with block("attn"):
+            lp = jax.tree.map(lambda a, _l=l: a[_l], params["layers"])
+            h = _norm(x, lp["input_norm"]["scale"], cfg)
+            q, k, v = _project_qkv(lp, h, cfg, positions)
+            attn = prefill_attention(q, k, v, None, None, None,
+                                     jnp.zeros((B,), jnp.int32), seq_lens,
+                                     **_attn_opts(cfg, l))
+            attn = attn.reshape(*attn.shape[:-2], cfg.q_size)
         return _attn_mlp_residual(lp, x, attn, cfg)
 
     for l in range(cfg.num_layers):
         x = layer_body(l, x)
-    x = _norm(x, params["final_norm"]["scale"], cfg)
-    mask = (jnp.arange(S)[None, :] < seq_lens[:, None])[..., None]
-    summed = jnp.sum(jnp.where(mask, x.astype(jnp.float32), 0.0), axis=1)
-    return summed / jnp.maximum(seq_lens[:, None], 1)
+    with block("head"):
+        x = _norm(x, params["final_norm"]["scale"], cfg)
+        mask = (jnp.arange(S)[None, :] < seq_lens[:, None])[..., None]
+        summed = jnp.sum(jnp.where(mask, x.astype(jnp.float32), 0.0),
+                         axis=1)
+        return summed / jnp.maximum(seq_lens[:, None], 1)
 
 
 def verify_forward(params: Params, cfg: ModelConfig,
@@ -302,13 +311,14 @@ def decode_forward(params: Params, cfg: ModelConfig,
         rope_positions = positions
 
     for l in range(cfg.num_layers):
-        lp = jax.tree.map(lambda a, _l=l: a[_l], params["layers"])
-        h = _norm(x, lp["input_norm"]["scale"], cfg)
-        q, k, v = _project_qkv(lp, h, cfg, rope_positions)        # [B, H, hd]
-        attn, kv_pages = decode_attention_step(
-            q, k, v, kv_pages, l, page_table, context_lens,
-            **_attn_opts(cfg, l))
-        attn = attn.reshape(*attn.shape[:-2], cfg.q_size)
+        with block("attn"):
+            lp = jax.tree.map(lambda a, _l=l: a[_l], params["layers"])
+            h = _norm(x, lp["input_norm"]["scale"], cfg)
+            q, k, v = _project_qkv(lp, h, cfg, rope_positions)    # [B, H, hd]
+            attn, kv_pages = decode_attention_step(
+                q, k, v, kv_pages, l, page_table, context_lens,
+                **_attn_opts(cfg, l))
+            attn = attn.reshape(*attn.shape[:-2], cfg.q_size)
         x = _attn_mlp_residual(lp, x, attn, cfg)
     return _unembed(params, cfg, x), kv_pages
 
@@ -348,21 +358,22 @@ def mixed_decode_chunk_forward(
     chunk_lens = chunk_valid[None]                             # [1]
 
     for l in range(cfg.num_layers):
-        lp = jax.tree.map(lambda a, _l=l: a[_l], params["layers"])
-        h = _norm(x, lp["input_norm"]["scale"], cfg)
-        q, k, v = _project_qkv(lp, h, cfg, rope_pos)          # [B+c, H, hd]
-        # Chunk KV lands in the pool FIRST (its own pages; decode rows
-        # belong to different sequences, so order is immaterial there).
-        kv_pages = write_kv(kv_pages, l, k[None, B:], v[None, B:],
-                            chunk_pt, chunk_prefix, chunk_lens)
-        attn_d, kv_pages = decode_attention_step(
-            q[:B], k[:B], v[:B], kv_pages, l, dec_pt, dec_clens,
-            **_attn_opts(cfg, l))
-        attn_c = prefill_attention(
-            q[None, B:], k[None, B:], v[None, B:], kv_pages, l,
-            chunk_pt, chunk_prefix, chunk_lens, **_attn_opts(cfg, l))
-        attn = jnp.concatenate([attn_d, attn_c[0]])
-        attn = attn.reshape(B + c, cfg.q_size)
+        with block("attn"):
+            lp = jax.tree.map(lambda a, _l=l: a[_l], params["layers"])
+            h = _norm(x, lp["input_norm"]["scale"], cfg)
+            q, k, v = _project_qkv(lp, h, cfg, rope_pos)      # [B+c, H, hd]
+            # Chunk KV lands in the pool FIRST (its own pages; decode rows
+            # belong to different sequences, so order is immaterial there).
+            kv_pages = write_kv(kv_pages, l, k[None, B:], v[None, B:],
+                                chunk_pt, chunk_prefix, chunk_lens)
+            attn_d, kv_pages = decode_attention_step(
+                q[:B], k[:B], v[:B], kv_pages, l, dec_pt, dec_clens,
+                **_attn_opts(cfg, l))
+            attn_c = prefill_attention(
+                q[None, B:], k[None, B:], v[None, B:], kv_pages, l,
+                chunk_pt, chunk_prefix, chunk_lens, **_attn_opts(cfg, l))
+            attn = jnp.concatenate([attn_d, attn_c[0]])
+            attn = attn.reshape(B + c, cfg.q_size)
         x = _attn_mlp_residual(lp, x, attn, cfg)
     return _unembed(params, cfg, x[:B]), kv_pages
 
