@@ -254,6 +254,35 @@ def kernel_arms(devices):
     yield "paged_granite_h_micro", paged(32, 64, 2048, heads=(32, 8),
                                          scale=1 / 64)
 
+    def prefill(S, max_pages, pool, heads, lanes=hd, **kw):
+        from xllm_service_tpu.ops.pallas_prefill_attention import (
+            prefill_attention_pallas)
+
+        def thunk():
+            n_q, n_kv = heads
+            fn = jax.jit(lambda q, kv, ly, pt, pl_, sl:
+                         prefill_attention_pallas(q, kv, ly, pt, pl_, sl,
+                                                  **kw))
+            return fn.lower(
+                f((1, S, n_q, lanes), bf16),
+                f((POOL_LAYERS, 2, pool, n_kv, ps, lanes), bf16),
+                f((1,), i32), f((1, max_pages), i32), f((1,), i32),
+                f((1,), i32)).compile()
+        return thunk
+
+    # The prefill kernel at the four configurations' heads, table width
+    # and top bucket (one admission a call): groups of 7, 8 and 4 rows a
+    # position, and kanana-2's 32 heads over one latent head of 640 lanes.
+    yield "prefill_qwen25_7b", prefill(1536, 96, 2048, (28, 4))
+    yield "prefill_qwen25_3b", prefill(2048, 128, 4096, (16, 2))
+    yield "prefill_granite_h_micro", prefill(1024, 64, 2048, (32, 8),
+                                             scale=1 / 64)
+    yield "prefill_kanana2_latent", prefill(3072, 192, 4096, (32, 1),
+                                            lanes=640, scale=192 ** -0.5)
+    yield "prefill_gemma2", prefill(512, 128, 2048, (n_q, n_kv),
+                                    softcap=50.0, window=4096 // 8,
+                                    scale=256 ** -0.5)
+
     def ssm_update():
         # The state-update kernel at granite-4.0-h-micro's widths: 36
         # layers x 32 slots of [128, 4096] float32, updated in place.

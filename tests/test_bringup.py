@@ -148,7 +148,7 @@ class TestAttentionPathRecord:
         assert paths["decode_multi"] == {
             "paged_attention": "xla (cpu backend)"}
         assert paths["prefill_install"] == {
-            "prefill_attention": "xla-dense"}
+            "prefill_attention": "xla-dense (cpu backend)"}
 
     def test_one_context_routes_ring_and_context_parallel(self):
         """`trace_program` alone carries what the dispatchers need: the

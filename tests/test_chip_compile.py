@@ -55,12 +55,17 @@ def chip():
     "paged_b16", "paged_b64", "gemma2_softcap", "gemma2_window",
     "paged_qwen25_7b", "paged_qwen25_3b", "paged_granite_h_micro",
     "ssm_update_granite_h_micro", "page_gather_l32",
-    "page_scatter_l32", "cp_partial_stats", "paged_shard_map_tp4"])
+    "page_scatter_l32", "cp_partial_stats", "paged_shard_map_tp4",
+    "prefill_qwen25_7b", "prefill_qwen25_3b", "prefill_granite_h_micro",
+    "prefill_kanana2_latent", "prefill_gemma2"])
 def test_kernel_compiles_for_v5e(chip, arm):
     """Each served-path Pallas kernel at Llama-3-8B head shapes (the
     decode kernel also at the benchmark's two configurations' heads, batch
-    and table width: chunks of 16 pages with the run copy in them) is
-    accepted by Mosaic and stays a kernel in the compiled program."""
+    and table width: chunks of 16 pages with the run copy in them; the
+    prefill kernel at the four configurations' heads, table and top
+    bucket, kanana-2's 32 heads over one latent head of 640 lanes among
+    them) is accepted by Mosaic and stays a kernel in the compiled
+    program."""
     compiled = dict(gate.kernel_arms(chip))[arm]()
     assert compiled.as_text().count("tpu_custom_call") >= 1
 
@@ -86,6 +91,31 @@ def test_decode_step_full_width_one_chip(chip):
     assert prog["fits_hbm"]
     assert out["attention_paths"]["decode_multi"] == {
         "paged_attention": "pallas"}
+
+
+def test_prefill_holds_one_attention_kernel_call_a_layer(chip):
+    """`prefill_install` at Llama-3-8B widths, 2 layers: ONE kernel call a
+    layer whatever the prefix (the XLA form compiles the attention up to
+    five times a layer: a `cond` over a `switch` of span-bucketed
+    gathers), and the record names the path."""
+    out = gate.compile_engine_programs(_two_layer_cfg(), device=chip[0],
+                                       horizons=(), buckets=(1024,))
+    prog = out["prefill_install_nc_s1024"]
+    assert prog["tpu_custom_calls"] == 2 and prog["whiles"] == 0
+    assert prog["fits_hbm"]
+    assert out["attention_paths"]["prefill_install"] == {
+        "prefill_attention": "pallas"}
+
+
+def test_prefill_kernel_runs_per_head_shard_tp4(chip):
+    """The same program over the 4-device model mesh: the prefill kernel
+    under shard_map on its device's heads, as the decode kernel."""
+    out = gate.compile_engine_programs(
+        _two_layer_cfg(), mesh=gate.model_mesh(chip, 4), horizons=(),
+        buckets=(128,))
+    assert out["prefill_install_nc_s128"]["tpu_custom_calls"] == 2
+    assert out["attention_paths"]["prefill_install"] == {
+        "prefill_attention": "pallas (shard_map model=4)"}
 
 
 def test_decode_step_holds_no_second_pool(chip):

@@ -54,3 +54,26 @@ class TestPallasEngineRouting:
         assert _greedy(engine, PROMPT) == baseline
         assert engine.stats()["attention_paths"]["decode_multi"] == {
             "paged_attention": "pallas"}
+
+    def test_kernel_prefill_serves_cold_and_prefix_hit_alike(self,
+                                                             monkeypatch):
+        """`prefill_install` through the prefill kernel: a cold admission
+        (prefix 0) and one that finds two hash blocks cached (a 64-token
+        prefix, the suffix in a smaller bucket) serve the tokens the XLA
+        form serves, in float32, and the record says which form ran."""
+        shared = list(range(3, 67))                # two hash blocks of 32
+        prompts = [shared + [71, 73, 79], shared + [83, 89, 97, 101, 103]]
+
+        def serve(engine):
+            out = [_greedy(engine, p, n=5) for p in prompts]
+            hits = engine.telemetry.counters["prefix_hit_tokens"]
+            return out, hits
+
+        baseline, hits = serve(_pallas_capable_engine())
+        assert hits == 64 and all(len(t) == 5 for t in baseline)
+        monkeypatch.setenv("XLLM_PALLAS_INTERPRET", "1")
+        engine = _pallas_capable_engine()
+        assert serve(engine) == (baseline, 64)
+        paths = engine.stats()["attention_paths"]
+        assert paths["prefill_install"] == {"prefill_attention": "pallas"}
+        assert paths["decode_multi"] == {"paged_attention": "pallas"}

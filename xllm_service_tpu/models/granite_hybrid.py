@@ -284,12 +284,12 @@ def prefill_forward(params: Params, cfg: ModelConfig,
                 lp = _at(params["attn"], i)
                 h = rms_norm(x, lp["norm"]["scale"], cfg.rms_eps)
                 q, k, v = _qkv(lp, h, cfg)
-                kv_pages = write_kv(kv_pages, i, _lanes(k, cfg),
-                                    _lanes(v, cfg), page_table, zero,
+                k, v = _lanes(k, cfg), _lanes(v, cfg)
+                kv_pages = write_kv(kv_pages, i, k, v, page_table, zero,
                                     seq_lens)
                 mix = _attn_out(lp, prefill_attention(
-                    q, k, v, None, None, None, zero, seq_lens,
-                    scale=_attn_scale(cfg)), cfg)
+                    _lanes(q, cfg), k, v, kv_pages, i, page_table, zero,
+                    seq_lens, scale=_attn_scale(cfg)), cfg)
             x = x + r * mix
         with block("mlp"):
             x = x + r * _mlp(_at(params["mlp"], layer), x, cfg)

@@ -16,9 +16,10 @@ Layouts:
 
 Numerics: matmuls in model dtype (bf16 on TPU), softmax in f32.
 
-The XLA paged-attention path below is the portable implementation (runs on
-CPU test meshes and compiles well on TPU); `ops/pallas_paged_attention.py`
-provides the hand-written TPU kernel and the engine selects per backend.
+The XLA paths below are the portable implementation (they run on CPU test
+meshes and compile well on TPU); `ops/pallas_paged_attention.py` (decode)
+and `ops/pallas_prefill_attention.py` (prefill) provide the hand-written
+TPU kernels and the dispatchers select per backend and shape.
 """
 
 from __future__ import annotations
@@ -285,6 +286,59 @@ def gather_pages(pool: jax.Array, layer: int,
     return g[0], g[1]
 
 
+def prefill_attention_path(backend: str, interpret: bool, S: int,
+                           head_dim: int, n_heads: int, n_kv: int, dtype,
+                           tp: int = 1, pool: bool = True,
+                           ring: bool = False,
+                           seq_sharded: bool = False) -> str:
+    """The path `prefill_attention` takes and the string
+    `/stats`.attention_paths records for it: `ring`, `pallas` (the tiled
+    kernel over the pool's pages, `ops/pallas_prefill_attention.py`), or
+    `xla-dense (<why>)`, the plain form.
+
+    The kernel's eligibility is the decode kernel's (`attention_path`:
+    lane-multiple head dim, integer group, bf16/f32, TPU or interpret
+    mode, head counts that divide over `tp`) and a query tile for the
+    bucket (`page_walk.prefill_query_tile`). It reads every key from the
+    pool, so a call without one (the embeddings path) keeps the plain
+    form, as does a pool sharded over the seq axis. Soft cap and window
+    ride the kernel as static parameters, as they do in decode."""
+    if ring:
+        return "ring"
+    if not pool:
+        return "xla-dense (no pool: the embeddings path)"
+    if seq_sharded:
+        return f"xla-dense (pool sharded over {AXIS_SEQ})"
+    path = attention_path(backend, interpret, head_dim, n_heads, n_kv,
+                          dtype, tp)
+    if path.startswith("xla"):
+        return "xla-dense" + path[len("xla"):]
+    from .page_walk import prefill_query_tile
+
+    if not prefill_query_tile(S, n_heads // tp, head_dim,
+                              jnp.dtype(dtype).itemsize):
+        return (f"xla-dense (shape outside the kernel's tiling: no query "
+                f"tile for S={S} heads={n_heads // tp} hd={head_dim})")
+    return path
+
+
+def _on_head_shards(kernel, mesh, heads: P, n_replicated: int):
+    """`kernel(q, pool, *replicated)` as it runs under the program's mesh.
+    Tensor parallel: GSPMD cannot partition a Mosaic kernel, so each
+    device runs it on its own heads: q (spec `heads`) and the pool are
+    head-sharded over `model` (KV_PAGES_SPEC), the layer id, the page
+    table and the lengths replicated. GQA groups stay whole because both
+    head counts divide by tp. pallas_call outputs carry no varying-axes
+    metadata, hence check_vma=False."""
+    if mesh is None:
+        return kernel
+    pool_spec = P(None, None, None, AXIS_MODEL, None, None)
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(heads, pool_spec) + (P(),) * n_replicated,
+        out_specs=heads, check_vma=False)
+
+
 def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       pool: jax.Array | None, layer: int | None,
                       page_table: jax.Array | None,
@@ -296,13 +350,18 @@ def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     q/k/v: [B, S, n(_kv), hd] for the *suffix* being prefilled; queries also
     attend to the cached prefix (first prefix_lens[b] tokens) read from
     layer `layer` of the paged pool (`pool` None: no cache at all, the
-    embeddings path). seq_lens[b] = valid suffix length (padding masked
-    out).
+    embeddings path), into which the caller has ALREADY written the
+    suffix's k and v (`write_kv`): the kernel reads every key from there.
+    seq_lens[b] = valid suffix length (padding masked out; the padding
+    rows' outputs are unspecified and unused).
     Returns [B, S, n_heads, hd].
 
     softcap > 0 tanh-caps the attention scores; window > 0 restricts each
     query to the trailing `window` key positions (gemma-2 local layers).
-    Both take the XLA path — the ring does not implement them.
+    The ring does not implement them. Which form runs is
+    `prefill_attention_path`'s word: on `pallas` ONE kernel call, whatever
+    the prefix; the XLA form below is the plain one (the CPU backend, no
+    pool, shapes outside the kernel's tiling).
     """
     B, S, n_heads, hd = q.shape
     n_kv = k.shape[2]
@@ -317,7 +376,12 @@ def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             "ring attention does not support attn softcap/sliding window; "
             "the engine must not enable sequence-parallel prefill for "
             "gemma-2-style models")
-    note_path("prefill_attention", "ring" if prog.ring else "xla-dense")
+    mesh, tp = _axis_mesh(AXIS_MODEL)
+    path = prefill_attention_path(
+        _backend(), _pallas_interpret(), S, hd, n_heads, n_kv, q.dtype, tp,
+        pool=pool is not None, ring=prog.ring,
+        seq_sharded=_axis_mesh(AXIS_SEQ)[0] is not None)
+    note_path("prefill_attention", path)
     if prog.ring:
         # Context-parallel path: ring attention over the seq mesh axis.
         # Queries past seq_lens are end-padding; causal masking keeps them
@@ -329,6 +393,16 @@ def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
         return ring_attention(q, k, v, prog.mesh, seq_axis=AXIS_SEQ,
                               scale=scale)
+    if path.startswith("pallas"):
+        from .pallas_prefill_attention import prefill_attention_pallas
+
+        kernel = functools.partial(
+            prefill_attention_pallas, interpret=_pallas_interpret(),
+            scale=scale, softcap=softcap, window=window)
+        return _on_head_shards(
+            kernel, mesh, P(None, None, AXIS_MODEL, None), 4)(
+                q, pool, jnp.full((1,), layer, jnp.int32), page_table,
+                prefix_lens, seq_lens)
 
     kf = _repeat_kv(k, n_rep).astype(jnp.float32)
     vf = _repeat_kv(v, n_rep).astype(jnp.float32)
@@ -593,19 +667,6 @@ def paged_attention(q: jax.Array, pool: jax.Array, layer: int,
     kernel = functools.partial(paged_attention_pallas,
                                interpret=_pallas_interpret(),
                                scale=scale, softcap=softcap, window=window)
-    layer_id = jnp.full((1,), layer, jnp.int32)
-    if mesh is None:
-        return kernel(q, pool, layer_id, page_table, context_lens)
-    # Tensor parallel: GSPMD cannot partition a Mosaic kernel, so each
-    # device runs it on its own heads — q and the pool are head-sharded
-    # over `model` (KV_PAGES_SPEC), the layer id, the page table and the
-    # lengths replicated. GQA groups stay whole because both head counts
-    # divide by tp. pallas_call outputs carry no varying-axes metadata,
-    # hence check_vma=False.
-    heads = P(None, AXIS_MODEL, None)
-    pool_spec = P(None, None, None, AXIS_MODEL, None, None)
-    return jax.shard_map(
-        kernel, mesh=mesh,
-        in_specs=(heads, pool_spec, P(), P(), P()),
-        out_specs=heads, check_vma=False,
-    )(q, pool, layer_id, page_table, context_lens)
+    return _on_head_shards(kernel, mesh, P(None, AXIS_MODEL, None), 3)(
+        q, pool, jnp.full((1,), layer, jnp.int32), page_table,
+        context_lens)

@@ -27,3 +27,27 @@ def walk_run_counts(row, n_pages: int, chunk: int) -> tuple[int, int]:
                     .reshape(full, chunk), axis=1)
     runs = (steps == 1).all(axis=1) | (steps == -1).all(axis=1)
     return -(-n_pages // chunk), int(runs.sum())
+
+
+PREFILL_TILE_STATE = 12 << 20   # bytes of VMEM a query tile's state may take
+
+
+def prefill_query_tile(S: int, n_heads: int, head_dim: int,
+                       itemsize: int) -> int:
+    """Suffix rows in one query tile of the prefill kernel
+    (`ops/pallas_prefill_attention.py`), or 0 where the shape has none and
+    prefill keeps the XLA form. The largest of 128 ... 8 rows that divides
+    the bucket, fills whole sublane tiles of the query's type (8 rows of
+    float32, 16 of bfloat16) and keeps the tile's state under
+    `PREFILL_TILE_STATE`: a row of every head holds its float32
+    accumulator, the query three times (the pipeline's two blocks and the
+    head-major copy), the output twice, and the lane-wide m and l. No more
+    than 128 rows: a tile past `seq_len` is skipped whole, so a finer tile
+    skips more of a bucket's padding, and a group of heads already stacks
+    `group x` rows into each product."""
+    row = n_heads * (head_dim * (4 + 5 * itemsize) + 2 * 128 * 4)
+    for tq in (128, 64, 32, 16, 8):
+        if (tq * itemsize >= 32 and S % tq == 0
+                and tq * row <= PREFILL_TILE_STATE):
+            return tq
+    return 0
