@@ -297,6 +297,38 @@ def kernel_arms(devices):
 
     yield "ssm_update_granite_h_micro", ssm_update
 
+    def retention(which):
+        # The two power-retention kernels at Brumby-14B's widths (40 query
+        # and 8 KV heads of 128; 8 layers x 12 slots of float32 state, the
+        # prefill's sub-chunk of 1024 tokens).
+        from xllm_service_tpu.ops import pallas_retention as pr
+        from xllm_service_tpu.ops.retention import slabs, z_rows
+
+        L, B, Hq, Hk, d, C = 8, 12, 40, 8, 128, 1024
+        M, Mz, G, f32 = slabs(d), z_rows(d), Hq // Hk, jnp.float32
+
+        def update():
+            return jax.jit(
+                lambda s, z, *a: pr.retention_update_pallas(s, z, *a, 1e-6),
+                donate_argnums=(0, 1)).lower(
+                f((L, B, Hk, M, d, d), f32), f((L, B, Hk, Mz, d), f32),
+                f((), i32), f((B,), jnp.bool_), f((B, Hq, d), bf16),
+                f((B, Hk, d), bf16), f((B, Hk, d), bf16),
+                f((B, Hk), f32)).compile()
+
+        def prefill():
+            return jax.jit(lambda *a: pr.retention_cross_pallas(
+                *a, bf16)).lower(
+                f((C, Hk, G, d), bf16), f((C, Hk, G, d), f32),
+                f((C, Hk, d), bf16), f((C, Hk, d), f32), f((C, Hk, d), bf16),
+                f((Hk,), f32), f((Hk, M, d, d), f32),
+                f((Hk, Mz, d), f32)).compile()
+
+        return {"update": update, "prefill": prefill}[which]
+
+    yield "retention_update_brumby", retention("update")
+    yield "retention_prefill_brumby", retention("prefill")
+
     def mover(which):
         from xllm_service_tpu.ops import pallas_page_dma as dma
 

@@ -38,8 +38,10 @@ from xllm_service_tpu.models.base import BLOCK_PREFIX, BLOCKS  # noqa: E402
 # Pallas call under a marker is that scope's kernel.
 INNER = ("moe.plan", "jit(_moe_experts_impl)", "jit(_paged_attention_impl)",
          "jit(_prefill_attention_impl)",
-         "jit(_ssm_update_impl)", "moe.route", "moe.experts", "mla.decode",
-         "ssm_conv", "ssm_update", "ssm_scan")
+         "jit(_ssm_update_impl)", "jit(_retention_update_impl)",
+         "jit(_retention_prefill_impl", "moe.route", "moe.experts",
+         "mla.decode", "ssm_conv", "ssm_update", "ssm_scan", "ret.update",
+         "ret.prefill")
 MARKERS = INNER + tuple(BLOCK_PREFIX + b for b in BLOCKS)
 
 

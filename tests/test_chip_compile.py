@@ -54,7 +54,8 @@ def chip():
 @pytest.mark.parametrize("arm", [
     "paged_b16", "paged_b64", "gemma2_softcap", "gemma2_window",
     "paged_qwen25_7b", "paged_qwen25_3b", "paged_granite_h_micro",
-    "ssm_update_granite_h_micro", "page_gather_l32",
+    "ssm_update_granite_h_micro", "retention_update_brumby",
+    "retention_prefill_brumby", "page_gather_l32",
     "page_scatter_l32", "cp_partial_stats", "paged_shard_map_tp4",
     "prefill_qwen25_7b", "prefill_qwen25_3b", "prefill_granite_h_micro",
     "prefill_kanana2_latent", "prefill_gemma2"])
@@ -162,6 +163,31 @@ def test_state_space_decode_step_keeps_its_state_in_place(chip):
         "paged_attention": "pallas", "ssm_update": "pallas"}
 
 
+def test_attention_free_decode_step_keeps_its_state_in_place(chip):
+    """`brumby-14b-base` as the benchmark serves it (8 layers, B 12,
+    horizon 8): one kernel call a layer, the retention update on `pallas`
+    and neither attention kernel anywhere, a pool of no bytes, and
+    temporaries that are a sixth of the 3.3 GB of per-slot state: no copy
+    of the state or of a weight stack."""
+    from chipbench.engine_setup import build_engine_config
+
+    name = "brumby-14b-base"
+    ecfg, _ = build_engine_config(REPO / "chipbench" / "configs" / name, 0,
+                                  name)
+    m = ecfg.model
+    assert (m.kv_layers, m.num_layers, ecfg.prefill_chunk_tokens) == (
+        0, 8, 1024)
+    out = gate.compile_engine_programs(ecfg, device=chip[0],
+                                       horizons=(ecfg.decode_horizon,),
+                                       buckets=())
+    prog = out[f"decode_multi_h{ecfg.decode_horizon}"]
+    assert prog["tpu_custom_calls"] == m.num_layers
+    assert prog["fits_hbm"] and prog["temp_gib"] < 0.7
+    assert 10.8 < prog["argument_gib"] < 11.0
+    assert out["attention_paths"]["decode_multi"] == {
+        "retention_update": "pallas"}
+
+
 def test_decode_step_full_width_tp4(chip):
     """The same program partitioned over the 4-device model mesh: GSPMD
     cannot partition a Mosaic kernel, so it sits under shard_map on a
@@ -224,16 +250,25 @@ ENTRY %main.8 (x: f32[8]) -> f32[8] {
 # What stays outside every block, by design: the embedding lookup, the
 # page-table arithmetic, the unpacking of a prefill's packed upload, the
 # scan's stacking of its outputs and a stateful family's install of the
-# admitted slot's state. Each is a bare primitive at the program's top
+# admitted slot's state (and, where its prefill carries state, the read of
+# the slot's state the tokens start from). Each is a bare primitive at the program's top
 # level: an op under any scope that is not a block's fails the test.
 OUTSIDE_EVERY_BLOCK = {
     "gather", "slice", "select_n", "lt", "broadcast_in_dim",
-    "dynamic_update_slice", "bitcast_convert_type", "concatenate", "scatter"}
+    "dynamic_update_slice", "dynamic_slice", "bitcast_convert_type",
+    "concatenate", "scatter"}
 BLOCKS_OF = {
     "tiny-qwen2": {"attn", "mlp", "head", "sample"},
     "tiny-granite-hybrid": {"attn", "ssm", "mlp", "head", "sample"},
     "tiny-kanana-moe": {"attn", "mlp", "moe", "head", "sample"},
+    "tiny-power-retention": {"ret", "mlp", "head", "sample"},
 }
+# The share of executed ops the compiler made itself (no `op_name`). The
+# retention toy's head of 16 is outside its kernels' tiling, so the chip's
+# compiler is given the plain form, whose stack of 9 rotations a head becomes
+# a chain of update fusions it names itself; at the benchmark's head of 128
+# the kernel builds them in VMEM (12 of the real `decode_multi`'s 257 ops).
+UNNAMED_SHARE = {"tiny-power-retention": 0.3}
 
 
 def executed_ops(hlo_text: str) -> list:
@@ -325,8 +360,8 @@ def test_every_device_op_of_a_served_program_lies_in_one_block(chip, name):
         outside = [i for i, b in blocks if b == ""]
         assert len(outside) <= 0.12 * len(ops), (program, len(outside),
                                                  len(ops))
-        assert len(ops) - len(named) <= 0.2 * len(ops), (
-            program, len(ops) - len(named), len(ops))
+        assert len(ops) - len(named) <= UNNAMED_SHARE.get(name, 0.2) * len(
+            ops), (program, len(ops) - len(named), len(ops))
         # the kernels are custom calls inside their block
         kernels = [(i, n) for i, k, n in named if k == "custom-call"
                    and "pallas_call" in n]

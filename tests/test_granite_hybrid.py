@@ -443,3 +443,44 @@ def test_what_the_state_cannot_follow_is_refused_at_start(toy, kw, why):
     with pytest.raises(ValueError, match=why) as e:
         _engine(mcfg, params, **kw)
     assert "per-slot recurrent state" in str(e.value)
+
+
+@pytest.mark.parametrize("family,kw,refused", [
+    ("granite_hybrid", {"prefill_chunk_tokens": 32}, "starts from an empty"),
+    ("granite_hybrid", {"prefill_chunk_tokens": 0}, None),
+    ("power_retention", {"prefill_chunk_tokens": 32}, None),
+    ("power_retention", {"prefill_chunk_tokens": 0}, None),
+    ("power_retention", {"role": InstanceType.PREFILL}, "role PREFILL"),
+    ("power_retention", {"role": InstanceType.DECODE}, "role DECODE"),
+    ("power_retention", {"speculate_k": 2}, "speculate_k=2 is refused"),
+    ("llama", {"prefill_chunk_tokens": 32}, None)])
+def test_who_is_refused_chunked_prefill_and_who_is_served(
+        toy, family, kw, refused):
+    """A prefill chunk needs the state of the chunk before it: a family
+    whose `prefill_forward` takes and returns state says so
+    (`ModelFamily.prefill_carries_state`) and is served; one whose prefill
+    starts from an empty state is refused, as before; one without such
+    state never was. What no stateful family can run stays refused."""
+    from xllm_service_tpu.models import power_retention as pr
+    from xllm_service_tpu.models.base import get_model_family, tiny_config
+
+    mcfg = {"granite_hybrid": toy[0],
+            "power_retention": pr.toy_config(dtype=jnp.float32),
+            "llama": tiny_config(dtype=jnp.float32)}[family]
+    assert get_model_family(family).prefill_carries_state == (
+        family == "power_retention")
+    cfg = EngineConfig(**{**dict(
+        model=mcfg, model_family=family, num_pages=64, page_size=16,
+        hash_block_size=32, max_batch_size=2, max_seq_len=128,
+        prefill_buckets=(32, 64, 128)), **kw})
+    if refused:
+        with pytest.raises(ValueError, match=refused) as e:
+            InferenceEngine(cfg)
+        assert "per-slot recurrent state" in str(e.value)
+        return
+    eng = InferenceEngine(cfg)
+    r = _req("r", TOKS[:45], 3)
+    run_requests(eng, [r])
+    assert len(r.on_output.tokens) == 3
+    chunked = kw.get("prefill_chunk_tokens", 0) > 0
+    assert eng.telemetry.counters["prefill_chunks"] == (1 if chunked else 0)

@@ -30,8 +30,8 @@ def _ir(calls):
 
 
 def test_the_trace_is_summed_by_the_scope_an_instruction_was_traced_under():
-    assert st.MARKERS[-6:] == ("blk.attn", "blk.mlp", "blk.moe", "blk.ssm",
-                               "blk.head", "blk.sample")
+    assert st.MARKERS[-7:] == ("blk.attn", "blk.mlp", "blk.moe", "blk.ssm",
+                               "blk.ret", "blk.head", "blk.sample")
     assert st.scope_of(NAMES[PID]["fusion.1"]) == "moe.plan"
     assert st.scope_of(NAMES[PID]["fusion.5"]) == "blk.moe"
     assert st.scope_of("") == "-"

@@ -493,15 +493,23 @@ class InferenceEngine:
                 why + f"role {cfg.role.value} is refused (a PD handoff "
                 "carries KV pages only; the state at the prompt's end "
                 "would be lost); run it as MIX")
-        if cfg.prefill_chunk_tokens > 0:
+        if (cfg.prefill_chunk_tokens > 0
+                and not self.family.prefill_carries_state):
             raise ValueError(
                 why + f"prefill_chunk_tokens={cfg.prefill_chunk_tokens} is "
-                "refused (a prefill chunk does not carry the state of the "
-                "chunk before it); use 0")
+                "refused (this family's prefill starts from an empty state: "
+                "a chunk would not carry the state of the chunk before it; "
+                "ModelFamily.prefill_carries_state); use 0")
         if self.mesh is not None and self.mesh.size > 1:
             raise ValueError(
                 why + f"a mesh of {self.mesh.size} devices is refused (the "
                 "state buffers and their update kernel are not sharded)")
+        if cfg.speculate_k > 0 and cfg.model.kv_layers == 0:
+            raise ValueError(
+                why + f"speculate_k={cfg.speculate_k} is refused (a rejected "
+                "draft cannot be taken back out of a state that has already "
+                "absorbed it, and this family keeps no keys to verify "
+                "against); use 0")
 
     # ---------------------------------------------------------- properties
     @property
@@ -722,6 +730,23 @@ class InferenceEngine:
 
         V = mcfg.vocab_size
 
+        def slot_state_in(d, slot, prefix_len) -> dict:
+            """`state=` for the prefill of a family whose prefill carries
+            state (nothing for another): the slot's own where tokens of
+            this prompt came before (`prefix_len` > 0: the chunk before
+            left it), zeros for the prompt's first tokens, which is what
+            clears the last occupant's."""
+            if not fam.prefill_carries_state:
+                return {}
+            return {"state": {
+                k: jnp.where(prefix_len > 0, jax.lax.dynamic_index_in_dim(
+                    d[k], slot, axis=1), 0) for k in state_keys}}
+
+        def slot_state_out(d, slot, state) -> dict:
+            return dict(d, **{
+                k: d[k].at[:, slot].set(v[:, 0].astype(d[k].dtype))
+                for k, v in state.items()})
+
         def make_prefill_install(use_ring: bool, with_counts: bool):
             """Prefill one sequence + install it into batch slot `slot`.
 
@@ -799,12 +824,11 @@ class InferenceEngine:
                         logits, kv, state = fam.prefill_forward(
                             params, mcfg, tokens, positions, d["kv"],
                             page_row[None, :], prefix_len[None],
-                            seq_len[None])
+                            seq_len[None], **slot_state_in(d, slot,
+                                                           prefix_len))
                         # The admitted slot's state, whole: that is also
                         # what clears the last occupant's.
-                        d = dict(d, **{
-                            k: d[k].at[:, slot].set(v[:, 0].astype(
-                                d[k].dtype)) for k, v in state.items()})
+                        d = slot_state_out(d, slot, state)
                     else:
                         logits, kv = fam.prefill_forward(
                             params, mcfg, tokens, positions, d["kv"],
@@ -1157,7 +1181,9 @@ class InferenceEngine:
             """One non-final chunk of a chunked prefill: writes the
             chunk's KV (attending to the already-written prefix) and
             discards logits. ints: [P + 2] = [page_row(P), prefix_len,
-            seq_len]. mm: this chunk's visual-embedding slice (VL; dummy
+            seq_len] (and, for a family whose prefill carries state, the
+            slot: the chunk reads the slot's state and leaves its own
+            there). mm: this chunk's visual-embedding slice (VL; dummy
             otherwise) — placeholders in the chunk consume it in order.
             pos3: [S, 3] host-computed M-RoPE position ids for the chunk
             (VL family; unused dummy otherwise)."""
@@ -1174,9 +1200,14 @@ class InferenceEngine:
                 else:
                     positions = prefix_len + jnp.arange(
                         tokens.shape[1], dtype=jnp.int32)[None, :]
-                    _, kv = fam.prefill_forward(
+                    slot = ints[P + 2] if stateful else None
+                    _, kv, *state = fam.prefill_forward(
                         params, mcfg, tokens, positions, d["kv"],
-                        page_row[None, :], prefix_len[None], seq_len[None])
+                        page_row[None, :], prefix_len[None], seq_len[None],
+                        **(slot_state_in(d, slot, prefix_len)
+                           if stateful else {}))
+                    if stateful:
+                        d = slot_state_out(d, slot, state[0])
             return pin(dict(d, kv=kv))
 
         self._prefill_chunk = prefill_chunk
@@ -1251,7 +1282,7 @@ class InferenceEngine:
         floats = np.concatenate([
             np.asarray([1.0, 0.0, 1.0, 0.0, 0.0, 1.0], np.float32),
             np.zeros((NB,), np.float32)])
-        for S in self.cfg.prefill_buckets:
+        for S in self._install_buckets():
             head = [np.zeros((S,), np.int32)]
             if self.cfg.model_family == "qwen2_vl":
                 # VL layout: [pos3(3S) | mrope_delta(1)] after the tokens.
@@ -1280,7 +1311,21 @@ class InferenceEngine:
                     calls.append(
                         (prog, (packed_by_counts[with_counts], mm), True))
 
-        workers = min(len(calls), max(1, (os.cpu_count() or 1) // 3))
+        # The standalone chunk program of a family whose chunks carry
+        # state (it rides no decode call): an empty chunk against slot 0,
+        # which leaves that slot's state zeroed. It returns the decode
+        # state alone.
+        chunks = []
+        if self._slot_state_keys and self.cfg.prefill_chunk_tokens > 0:
+            C = self.cfg.prefill_chunk_tokens
+            cints = np.full((P + 3,), GARBAGE_PAGE, np.int32)
+            cints[P:] = 0          # written so far, valid tokens, slot
+            chunks.append((self._prefill_chunk, (
+                jnp.zeros((1, C), jnp.int32), jnp.asarray(cints),
+                mm_shapes[0], jnp.zeros((C, 3), jnp.int32))))
+
+        workers = min(len(calls) + len(chunks),
+                      max(1, (os.cpu_count() or 1) // 3))
         if workers > 1 and jax.config.jax_compilation_cache_dir:
             from concurrent.futures import ThreadPoolExecutor
 
@@ -1288,7 +1333,7 @@ class InferenceEngine:
                 list(pool.map(
                     lambda c: c[0].lower(self.params, self._dstate,
                                          *c[1]).compile(),
-                    [c for c in calls if hasattr(c[0], "lower")]))
+                    [c for c in calls + chunks if hasattr(c[0], "lower")]))
         t1 = time.monotonic()
         for prog, rest, clear in calls:
             self._dstate, packed = prog(self.params, self._dstate, *rest)
@@ -1299,10 +1344,13 @@ class InferenceEngine:
             self._fetch(packed)
             if clear:
                 self._dstate = self._clear_slot(self._dstate, 0)
+        for prog, rest in chunks:
+            self._dstate = prog(self.params, self._dstate, *rest)
         logger.info("program warmup: %d programs (%d horizons, %d prefill "
                     "buckets) compiled in %.1fs by %d workers, run in %.1fs",
-                    len(calls), self.cfg.decode_horizon.bit_length(),
-                    len(self.cfg.prefill_buckets), t1 - t0, workers,
+                    len(calls) + len(chunks),
+                    self.cfg.decode_horizon.bit_length(),
+                    len(self._install_buckets()), t1 - t0, workers,
                     time.monotonic() - t1)
 
     # ------------------------------------------------------------ lifecycle
@@ -2085,10 +2133,16 @@ class InferenceEngine:
         # per engine step) measured 1.7x worse delivered tok/s on the
         # CPU serve bench. Truly long suffixes (> 4 chunks) always
         # chunk: stalling running decodes for their install dominates.
+        # A family whose chunks carry a recurrent state has ONE rule: a
+        # suffix longer than a chunk is chunked, so that no install program
+        # ever holds more than a chunk (its buckets above that are never
+        # compiled: `_install_buckets`).
         C = cfg.prefill_chunk_tokens
         suffix = len(prompt) - matched
-        queue_pressure = bool(self._waiting) and suffix <= 4 * C
+        queue_pressure = (bool(self._waiting) and suffix <= 4 * C
+                          and not self._slot_state_keys)
         if C > 0 and suffix > C and not queue_pressure:
+            self.telemetry.counters["prefill_chunked_admissions"] += 1
             self._prefillings.append(
                 {"seq": seq, "req": req, "prompt": prompt,
                  "cache_matched": matched,
@@ -2165,11 +2219,14 @@ class InferenceEngine:
         P = self.cfg.pages_per_seq
         chunk = np.asarray([prompt[st["written"]:st["written"] + C]],
                            np.int32)
-        ints = np.full((P + 2,), GARBAGE_PAGE, np.int32)
+        ints = np.full((P + 2 + bool(self._slot_state_keys),),
+                       GARBAGE_PAGE, np.int32)
         pages = seq.pages.all_pages
         ints[:len(pages)] = pages
         ints[P] = st["written"]
         ints[P + 1] = C
+        if self._slot_state_keys:
+            ints[P + 2] = seq.slot
         mm_arr = self._mm_chunk_array(req, prompt, st["written"],
                                       st["written"] + C)
         if self.cfg.model_family == "qwen2_vl":
@@ -2178,13 +2235,13 @@ class InferenceEngine:
         else:
             pos3 = np.zeros((C, 3), np.int32)
         try:
-            self._dstate = self._prefill_chunk(
-                self.params, self._dstate, jnp.asarray(chunk),
-                jnp.asarray(ints), mm_arr, jnp.asarray(pos3))
+            with self.telemetry.chunk_dispatched(C):
+                self._dstate = self._prefill_chunk(
+                    self.params, self._dstate, jnp.asarray(chunk),
+                    jnp.asarray(ints), mm_arr, jnp.asarray(pos3))
         except Exception as e:  # noqa: BLE001
             self._fail_admission(seq, req, e)
             raise
-        self.telemetry.count_by("prefill_calls", "chunk")
         st["written"] += C
         self._prefillings.append(st)   # back of the round-robin
         return True
@@ -2419,6 +2476,17 @@ class InferenceEngine:
             if n <= b:
                 return b
         return self.cfg.prefill_buckets[-1]
+
+    def _install_buckets(self) -> tuple[int, ...]:
+        """The buckets an install program can meet: all of them, but for a
+        family whose chunks carry state under chunked prefill, where every
+        suffix longer than a chunk is chunked (`_start_sequence`): those up
+        to the one that holds a chunk."""
+        buckets = self.cfg.prefill_buckets
+        C = self.cfg.prefill_chunk_tokens
+        if C > 0 and self._slot_state_keys:
+            return buckets[:buckets.index(self._bucket_for(C)) + 1]
+        return buckets
 
     def _count_placeholders(self, tokens: list[int]) -> int:
         tid = self.cfg.model.image_token_id
@@ -2687,10 +2755,11 @@ class InferenceEngine:
             context += seq.context_len
             row = seq.pages.all_pages
             pages += len(row)
-            walked = min(-(-(seq.context_len + 1) // ps), len(row))
-            n, n_run = walk_run_counts(row, walked, chunk)
-            chunks += n
-            run_chunks += n_run
+            if self.cfg.model.kv_layers:    # else no kernel walks pages
+                walked = min(-(-(seq.context_len + 1) // ps), len(row))
+                n, n_run = walk_run_counts(row, walked, chunk)
+                chunks += n
+                run_chunks += n_run
         self.telemetry.decode_dispatched(key, steps, len(snapshot), context,
                                          pages, chunks, run_chunks)
 

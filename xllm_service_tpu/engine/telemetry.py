@@ -17,7 +17,11 @@ horizon, reason or phase); `summarize` nests them for output:
               whose KV was not recomputed: the block-aligned match after the
               trim that keeps one suffix token, cold-tier onloads included),
               prefix_onload_tokens (the part of them restored from DRAM/SSD),
-              prefill_calls/<bucket|chunk>,
+              prefill_calls/<bucket|chunk>, prefill_chunks and
+              prefill_chunk_tokens (standalone `prefill_chunk` calls and
+              the tokens they held), prefill_chunked_admissions (prompts
+              that were prefilled in chunks: chunks / these + 1 install is
+              the programs an admission took),
               prefill_padded_tokens (bucket - suffix),
               prefix_skipped_stateful (admissions whose prompt the prefix
               cache was not asked about, and whose blocks it was not given:
@@ -95,6 +99,7 @@ _SCALARS = (
     "live_slot_steps", "context_token_steps", "sarathi_rides",
     "pages_reserved_steps", "walk_chunks", "walk_run_chunks",
     "prefix_skipped_stateful", "state_bytes_reserved",
+    "prefill_chunks", "prefill_chunk_tokens", "prefill_chunked_admissions",
     "moe_steps", "moe_tokens_routed", "moe_experts_touched")
 
 
@@ -207,6 +212,16 @@ class EngineTelemetry:
         c["pages_reserved_steps"] += pages_reserved * steps
         c["walk_chunks"] += walk_chunks * steps
         c["walk_run_chunks"] += walk_run_chunks * steps
+
+    def chunk_dispatched(self, tokens: int) -> TraceAnnotation:
+        """Counts one standalone `prefill_chunk` call of `tokens` tokens
+        and returns its span, `engine.prefill_chunk`, for the dispatch to
+        run under (inside the `prefill_dispatch` phase; inert without a
+        profiler session)."""
+        self.count_by("prefill_calls", "chunk")
+        self.counters["prefill_chunks"] += 1
+        self.counters["prefill_chunk_tokens"] += tokens
+        return TraceAnnotation("engine.prefill_chunk")
 
     def mark_decode_landed(self, live: int, horizon: int) -> None:
         """One `TraceAnnotation` entered and left at once, just after a

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 # engine's program tails is traced under exactly one of them, in every
 # family, so that a device trace can be summed by block (docs/
 # observability.md "By block"; the benchmark's `block.*` metrics).
-BLOCKS = ("attn", "mlp", "moe", "ssm", "head", "sample")
+BLOCKS = ("attn", "mlp", "moe", "ssm", "ret", "head", "sample")
 BLOCK_PREFIX = "blk."
 
 
@@ -136,6 +136,9 @@ class ModelConfig:
     attn_multiplier: float = 0.0
     logits_scaling: float = 1.0
     kv_held_dim: int = 0
+    # Power retention (models/power_retention.py): `layer_types` names every
+    # layer "retention" (no layer holds keys, so `kv_layers` is 0 and the
+    # pool has no plane).
 
     @property
     def kv_layers(self) -> int:
@@ -227,6 +230,11 @@ class ModelFamily:
     # no prefix to reuse and nothing to hand off: engine.py refuses what
     # it cannot run at start.
     slot_state: Optional[Callable[..., Any]] = None
+    # With `slot_state`: `prefill_forward` also TAKES state (`state=`
+    # {name: [layers, 1, ...]}: what the tokens start from) and the state
+    # it returns is the state after them, so a prompt may be prefilled in
+    # chunks that hand the slot's state on (engine.py `prefill_chunk`).
+    prefill_carries_state: bool = False
     # Optional decode step of a family that routes tokens to experts:
     # `decode_forward`'s arguments and `live=` ([B] bool: the rows that
     # hold a running request; the others reach no expert), returning a
@@ -261,6 +269,8 @@ def get_model_family(name: str) -> ModelFamily:
             from . import mixtral  # noqa: F401
         elif name == "granite_hybrid":
             from . import granite_hybrid  # noqa: F401
+        elif name == "power_retention":
+            from . import power_retention  # noqa: F401
     fam = _REGISTRY.get(name)
     if fam is None:
         raise ValueError(f"unknown model family: {name}")

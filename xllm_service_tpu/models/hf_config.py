@@ -11,8 +11,9 @@ to every engine); this module is the TPU framework's equivalent entry:
 
 Families map to the registered model families (models/__init__.py):
 llama / qwen2 (qkv-bias llama) / gemma2 / mixtral / deepseek_v2(.5) /
-qwen2_vl / granitemoehybrid (dense: Mamba-2 + GQA). Anything else raises
-with the offending model_type.
+qwen2_vl / granitemoehybrid (dense: Mamba-2 + GQA) / brumby (power
+retention in every layer). Anything else raises with the offending
+model_type.
 """
 
 from __future__ import annotations
@@ -91,6 +92,31 @@ def _granite_hybrid(hf: dict, ckpt_dir) -> dict[str, Any]:
         # heads of 64 are outside the paged kernel's tiling (head_dim %
         # 128): the family holds them zero-padded to the lane width
         kv_held_dim=-(-kw["head_dim"] // 128) * 128)
+    return kw
+
+
+def _power_retention(hf: dict, ckpt_dir) -> dict[str, Any]:
+    """Brumby (models/power_retention.py): Qwen3's keys, a power-retention
+    mixer in every layer. What the family does not compute is refused."""
+    def refuse(why: str):
+        raise ValueError(f"brumby under {ckpt_dir}: {why}")
+
+    if hf.get("attention_bias"):
+        refuse("projection biases are not implemented")
+    if hf.get("tie_word_embeddings", False):
+        refuse("a tied output head is not implemented")
+    if hf.get("rope_scaling"):
+        refuse(f"rope_scaling {hf['rope_scaling']!r}: the family rotates by "
+               "the plain half-split embedding")
+    if hf.get("use_sliding_window") or hf.get("sliding_window"):
+        refuse("a sliding window has no meaning for a retention layer")
+    kw = _common(hf)
+    if kw["num_heads"] % kw["num_kv_heads"] or kw["head_dim"] % 2:
+        refuse(f"{kw['num_heads']} query heads over {kw['num_kv_heads']} KV "
+               f"heads of {kw['head_dim']}: the state is shared by an "
+               "integer group, folded along an even head size")
+    kw.update(name="power_retention",
+              layer_types=("retention",) * kw["num_layers"])
     return kw
 
 
@@ -215,11 +241,13 @@ def model_config_from_hf(ckpt_dir: str | Path, *,
                 spatial_merge_size=merge))
     elif mt == "granitemoehybrid":
         kw = _granite_hybrid(hf, ckpt_dir)
+    elif mt == "brumby":
+        kw = _power_retention(hf, ckpt_dir)
     else:
         raise ValueError(
             f"unsupported HF model_type {mt!r} under {ckpt_dir} — "
             f"supported: llama, qwen2, gemma2, mixtral, deepseek_v2/3, "
-            f"qwen2_vl, granitemoehybrid")
+            f"qwen2_vl, granitemoehybrid, brumby")
 
     if dtype is not None:
         kw["dtype"] = dtype
