@@ -11,7 +11,8 @@ import pytest
 
 from xllm_service_tpu.common.request import RequestOutput, SamplingParams
 from xllm_service_tpu.engine.config import EngineConfig
-from xllm_service_tpu.engine.engine import EngineRequest, InferenceEngine
+from xllm_service_tpu.engine.engine import (LOOK_AHEAD_MARGIN_S,
+                                            EngineRequest, InferenceEngine)
 from xllm_service_tpu.engine.kv_cache import KVPageManager
 from xllm_service_tpu.models.base import tiny_config
 
@@ -846,11 +847,44 @@ class Pinned(list):
 #: 93 ms, a one-step call of 11.6 ms, beside a pump that needs 0.5 ms.
 LONG_CALL = (0.0005, 0.093)
 ONE_STEP_CALL = (0.0005, 0.0116)
+#: ... and horizon-8 calls steady to 0.4 ms: the pump looks ahead late, a
+#: margin before the newest call's time is up.
+STEADY_LONG_CALLS = (0.0005, 0.0930, 0.0934, 0.0931)
+MARGIN = LOOK_AHEAD_MARGIN_S
 
 
-def pin_measurements(engine, turnaround_s, call_s):
+class AnyHorizon:
+    """Pinned calls are samples of whatever horizon the rule asks for."""
+
+    def __eq__(self, other):
+        return True
+
+
+def pin_measurements(engine, turnaround_s, *call_s):
+    """The rule's inputs: one turn-around, and the times of the last calls
+    (one: no error of the estimate is known, so a long call is fetched
+    first; several steady ones: a long call is looked ahead of late)."""
     engine._turnaround_s = Pinned([turnaround_s])
-    engine._call_s = Pinned([call_s])
+    engine._call_s = Pinned([(AnyHorizon(), s) for s in call_s])
+
+
+class FakeClock:
+    """The pump's clock and its sleep, in a test's hands."""
+
+    def __init__(self, t=100.0):
+        self.t = t
+        self.slept = []
+
+    def __call__(self):
+        return self.t
+
+    def sleep(self, s):
+        self.slept.append(s)
+        self.t += s
+
+    def drive(self, engine):
+        engine._clock, engine._sleep = self, self.sleep
+        return self
 
 
 def greedy_req(rid, prompt, n, **kw):
@@ -893,29 +927,124 @@ class TestLookAheadRule:
         from xllm_service_tpu.engine.engine import look_ahead_pays
         assert look_ahead_pays(turnaround_s, call_s) is ahead
 
+    #: began, the last calls' times (newest last), the turn-around, now,
+    #: hold, multi-host -> action, the moment of a late dispatch. `now`
+    #: None: a clock that fails the test when read.
+    @pytest.mark.parametrize(
+        "began,call_s,turnaround_s,now,hold,multi_host,action,at", [
+            # no estimate: fetch first, as before
+            (10.0, (), 0.0005, None, False, False, "fetch", 0.0),
+            (10.0, (0.093,), 0.0, None, False, False, "fetch", 0.0),
+            # one sample says nothing of the estimate's error
+            (10.0, (0.093,), 0.0005, None, False, False, "fetch", 0.0),
+            # a call short against the turn-around: ahead at once (PR 29)
+            (10.0, (0.0116, 0.0117), 0.0005, None, False, False, "ahead",
+             0.0),
+            (10.0, (0.0116,), 0.0005, None, False, False, "ahead", 0.0),
+            # a long steady call: wait until its end less the margin
+            (10.0, (0.0930, 0.0934, 0.0931), 0.0005, 10.05, False, False,
+             "wait", 10.0 + 0.0931 - MARGIN),
+            # ... and dispatch once that moment has come
+            (10.0, (0.0930, 0.0934, 0.0931), 0.0005, 10.0 + 0.0931 - MARGIN,
+             False, False, "ahead", 10.0 + 0.0931 - MARGIN),
+            # the newest sample is the estimate, not a median: cell 4's
+            # call follows its live rows
+            (10.0, (0.050, 0.049, 0.040, 0.0395, 0.039, 0.0385), 0.0005,
+             10.0, False, False, "wait", 10.0 + 0.0385 - MARGIN),
+            # a recent error that the margin still covers moves nothing
+            (10.0, (0.0930, 0.0950, 0.0931), 0.0005, 10.05, False, False,
+             "wait", 10.0 + 0.0931 - MARGIN),
+            # an estimate whose recent error is past the margin: fetch
+            (10.0, (0.040, 0.039, 0.039 + MARGIN), 0.0005, None, False,
+             False, "fetch", 0.0),
+            # ... the turn-around is part of what the margin has to cover
+            (10.0, (0.093, 0.092, 0.091 + MARGIN), 0.0015, None, False,
+             False, "fetch", 0.0),
+            (10.0, (0.093, 0.092, 0.091 + MARGIN), 0.0005, 10.0, False,
+             False, "wait", 10.0 + 0.091),
+            # an old jump no longer counts (the last three steps do)
+            (10.0, (0.040, 0.050, 0.0500, 0.0501, 0.0500), 0.0005, 10.0,
+             False, False, "wait", 10.0 + 0.0500 - MARGIN),
+            # the caller wants the call's tokens first
+            (10.0, (0.0930, 0.0934, 0.0931), 0.0005, None, True, False,
+             "fetch", 0.0),
+            # ... which a short call never waited for
+            (10.0, (0.0116, 0.0117), 0.0005, None, True, False, "ahead",
+             0.0),
+            # a multi-host mesh: ahead, whatever was measured, no clock
+            (10.0, (), 0.0, None, False, True, "ahead", 0.0),
+            (10.0, (0.0930, 0.0934, 0.0931), 0.0005, None, True, True,
+             "ahead", 0.0),
+        ])
+    def test_the_seams_rule_is_a_pure_function(self, began, call_s,
+                                               turnaround_s, now, hold,
+                                               multi_host, action, at):
+        from xllm_service_tpu.engine.engine import look_ahead_plan
+
+        def clock():
+            assert now is not None, "the clock was read"
+            return now
+
+        plan = look_ahead_plan(began, call_s, turnaround_s, clock,
+                               hold=hold, multi_host=multi_host)
+        assert plan.action == action
+        assert plan.at == pytest.approx(at)
+        if plan.at:
+            assert plan.estimate_s == call_s[-1]
+            assert turnaround_s + plan.error_s <= MARGIN
+
+    def test_a_multi_host_engine_reads_no_clock(self, monkeypatch):
+        engine = make_engine(decode_horizon=8)
+        pin_measurements(engine, *STEADY_LONG_CALLS)
+        req = greedy_req("a", list(range(10, 40)), 30)
+        engine.submit(req)
+        step_until_decoding(engine)
+
+        def clock():
+            raise AssertionError("a wall-clock decision in lockstep")
+
+        engine._clock = clock
+        monkeypatch.setattr(jax, "process_count", lambda: 2)
+        plan = engine._seam_plan(engine._pending_decode)
+        assert (plan.action, plan.at) == ("ahead", 0.0)
+        assert engine.stats()["look_ahead"]["ahead"]
+
     def test_an_engine_without_measurements_does_not_look_ahead(self):
         engine = make_engine(decode_horizon=8)
-        assert not engine._look_ahead()
-        engine._call_s.append(0.01)          # one of the two is not enough
-        assert not engine._look_ahead()
+        assert not engine.stats()["look_ahead"]["ahead"]
+        engine._call_s.append((8, 0.01))     # one of the two is not enough
+        assert not engine.stats()["look_ahead"]["ahead"]
 
     def test_stats_say_what_the_rule_measured_and_decided(self):
         engine = make_engine(decode_horizon=8)
         assert engine.stats()["look_ahead"] == {
-            "turnaround_ms": 0.0, "call_ms": 0.0, "ahead": False}
+            "turnaround_ms": 0.0, "call_ms": 0.0, "ahead": False,
+            "estimate_ms": 0.0, "margin_ms": MARGIN * 1000, "error_ms": 0.0}
         pin_measurements(engine, *ONE_STEP_CALL)
         assert engine.stats()["look_ahead"] == {
             "turnaround_ms": pytest.approx(0.5),
-            "call_ms": pytest.approx(11.6), "ahead": True}
+            "call_ms": pytest.approx(11.6), "ahead": True,
+            "estimate_ms": pytest.approx(11.6), "margin_ms": MARGIN * 1000,
+            "error_ms": 0.0}
+        # one long call: no error of the estimate is known yet
+        pin_measurements(engine, *LONG_CALL)
+        assert engine.stats()["look_ahead"]["error_ms"] is None
+        # long calls, steady to 0.4 ms: what a late dispatch goes by
+        pin_measurements(engine, *STEADY_LONG_CALLS)
+        assert engine.stats()["look_ahead"] == {
+            "turnaround_ms": pytest.approx(0.5),
+            "call_ms": pytest.approx(93.1), "ahead": False,
+            "estimate_ms": pytest.approx(93.1), "margin_ms": MARGIN * 1000,
+            "error_ms": pytest.approx(0.4)}
 
     def test_the_rule_reads_medians_so_one_slow_turnaround_is_not_a_flip(
             self):
         engine = make_engine(decode_horizon=8)
-        engine._call_s.extend([0.093] * 9)
+        engine._call_s.extend([(8, 0.093)] * 9)
         engine._turnaround_s.extend([0.0005] * 8 + [0.050])
-        assert not engine._look_ahead()
+        assert not engine.stats()["look_ahead"]["ahead"]
         engine._turnaround_s.extend([0.050] * 5)
-        assert engine._look_ahead()
+        assert engine.stats()["look_ahead"]["ahead"]
 
     @pytest.mark.parametrize("measured,overlaps", [
         (ONE_STEP_CALL, True), (LONG_CALL, False)],
@@ -960,6 +1089,7 @@ class TestArrivalAtTheSeam:
     def _arrival_during_decode(self, measured):
         engine = make_engine(decode_horizon=8)
         pin_measurements(engine, *measured)
+        FakeClock().drive(engine)        # no time passes: a call just begun
         a, b = greedy_req("a", self.A, 30), greedy_req("b", self.B, 20)
         engine.submit(a)
         step_until_decoding(engine)
@@ -1104,6 +1234,335 @@ class TestArrivalAtTheSeam:
         trace = " ".join(events)
         assert "pick read" not in trace
         assert trace.startswith("read pick dispatch pick dispatch read")
+
+
+def late_engine(**kw):
+    """An engine that looks ahead late, on a clock of the test's own. The
+    CPU has every result ready at once; a chip that is still running the
+    call is played by `_result_ready`."""
+    engine = make_engine(decode_horizon=8, **kw)
+    pin_measurements(engine, *STEADY_LONG_CALLS)
+    clock = FakeClock().drive(engine)
+    engine._result_ready = lambda call: False
+    return engine, clock
+
+
+def spy_on_programs(engine, clock, events):
+    """Every dispatch and every fetch as (name, the clock then)."""
+    real = (engine._prefill_install_nc, engine._decode_multi, engine._fetch)
+
+    def install_spy(*args):
+        events.append(("install", clock.t))
+        return real[0](*args)
+
+    def decode_spy(*args):
+        events.append(("decode", clock.t))
+        return real[1](*args)
+
+    def fetch_spy(arr):
+        events.append(("fetch-call" if arr.ndim == 3 else "fetch", clock.t))
+        return real[2](arr)
+
+    (engine._prefill_install_nc, engine._decode_multi,
+     engine._fetch) = install_spy, decode_spy, fetch_spy
+
+
+class TestLateLookAhead:
+    A, B, C = list(range(10, 40)), list(range(100, 150)), list(range(60, 90))
+
+    def test_the_next_call_is_dispatched_a_margin_before_the_end(self):
+        engine, clock = late_engine()
+        a = greedy_req("a", self.A, 40)
+        engine.submit(a)
+        step_until_decoding(engine)
+        began, events = clock.t, []
+        spy_on_programs(engine, clock, events)
+        engine.step()
+        moment = began + 0.0931 - MARGIN
+        assert [name for name, _ in events] == ["decode", "fetch-call"]
+        assert all(t == pytest.approx(moment, abs=1e-6) for _, t in events)
+        # waited in slices, so that a result that came early is seen
+        assert max(clock.slept) <= 0.001
+        assert sum(clock.slept) == pytest.approx(moment - began)
+        c = engine.telemetry.counters
+        assert c["look_ahead_late/hit"] == 1
+        assert c["host_s/fetch_wait"] > 0
+        finish(engine, [a])
+        assert a.on_output.tokens == naive_greedy(engine, self.A, 40)
+        assert "look_ahead_late/late" not in c
+
+    def test_an_arrival_before_the_moment_has_its_prefill_next(self):
+        """b arrives while the pump waits for call k's moment: its install
+        goes onto the queue behind k, at the moment; k's tokens go out
+        while it runs; call k+1 follows. c arrives just after k+1 was
+        dispatched: it waits for k+1's moment, a whole call."""
+        engine, clock = late_engine()
+        a, b, c = (greedy_req("a", self.A, 60), greedy_req("b", self.B, 4),
+                   greedy_req("c", self.C, 4))
+        engine.submit(a)
+        step_until_decoding(engine)
+        began, events = clock.t, []
+        spy_on_programs(engine, clock, events)
+        real_sleep, real_decode = clock.sleep, engine._decode_multi
+
+        def sleep(s):                    # b: 40 ms into the wait
+            real_sleep(s)
+            if b.t_submit == 0.0 and clock.t > began + 0.040:
+                engine.submit(b)
+
+        def decode_then_arrive(*args):   # c: just behind the dispatch
+            out = real_decode(*args)
+            if c.t_submit == 0.0:
+                engine.submit(c)
+            return out
+
+        engine._sleep, engine._decode_multi = sleep, decode_then_arrive
+        engine.step()
+        moment = began + 0.0931 - MARGIN
+        assert [name for name, _ in events] == [
+            "install", "fetch-call", "fetch", "decode"]
+        assert events[0][1] == pytest.approx(moment, abs=1e-6)
+        counters = engine.telemetry.counters
+        assert counters["admissions"] == 2
+        # a margin of a 93 ms call is still to run: one step of eight
+        assert counters["prefill_behind_steps"] == 1
+        assert counters["look_ahead_late/hit"] == 1
+        del events[:]
+        engine.step()
+        assert [name for name, _ in events] == [
+            "install", "fetch-call", "fetch", "decode"]
+        # c waited out call k+1
+        assert events[0][1] == pytest.approx(moment + 0.0931 - MARGIN,
+                                             abs=1e-6)
+        assert counters["admissions"] == 3
+        finish(engine, [a, b, c])
+        for req, prompt, n in ((a, self.A, 60), (b, self.B, 4),
+                               (c, self.C, 4)):
+            assert req.on_output.tokens == naive_greedy(engine, prompt, n)
+
+    def test_a_budget_that_ends_in_the_call_is_not_served_by_the_next(self):
+        """a's 9 tokens are its prefill's and call k's eight: call k+1,
+        dispatched before k's result is in, neither holds it nor counts
+        it, in the counters or in the `decode_live` marker."""
+        engine, clock = late_engine()
+        a, b = greedy_req("a", self.A, 9), greedy_req("b", self.B, 30)
+        engine.submit(a)
+        engine.submit(b)
+        step_until_decoding(engine)
+        k = engine._pending_decode
+        assert sorted(s.req.service_request_id
+                      for s in k.snapshot.values()) == ["a", "b"]
+        counters = engine.telemetry.counters
+        live_before = counters["live_slot_steps"]
+        marks = []
+        engine.telemetry.mark_decode_landed = (
+            lambda live, horizon: marks.append((live, horizon)))
+        engine.step()
+        k1 = engine._pending_decode
+        assert k1 is not k and k.late == "hit"
+        assert [s.req.service_request_id
+                for s in k1.snapshot.values()] == ["b"]
+        assert counters["live_slot_steps"] - live_before == 1 * 8
+        engine.step()
+        assert marks == [(2, 8), (1, 8)]
+        finish(engine, [a, b])
+        assert a.on_output.tokens == naive_greedy(engine, self.A, 9)
+        assert b.on_output.tokens == naive_greedy(engine, self.B, 30)
+
+    def test_nothing_goes_ahead_of_a_call_every_budget_ends_in(self):
+        engine, clock = late_engine()
+        a = greedy_req("a", self.A, 9)
+        engine.submit(a)
+        step_until_decoding(engine)
+        events = []
+        spy_on_programs(engine, clock, events)
+        engine.step()
+        # (the call dispatched behind the fetch is the one the pump has
+        # always dispatched before a landed call's tokens are out)
+        assert [name for name, _ in events][0] == "fetch-call"
+        assert clock.slept == [] and a.on_output.done.is_set()
+        assert engine.telemetry.counters["look_ahead_late/skipped"] == 1
+
+    def test_a_waiting_request_without_a_slot_is_fetched_for_first(self):
+        """One slot, taken: the running call's tokens may free it, so the
+        pump fetches first, as it did before the late look-ahead."""
+        engine, clock = late_engine(max_batch_size=1)
+        a, b = greedy_req("a", self.A, 9), greedy_req("b", self.B, 2)
+        engine.submit(a)
+        step_until_decoding(engine)
+        engine.submit(b)
+        events = []
+        spy_on_programs(engine, clock, events)
+        engine.step()
+        assert [name for name, _ in events][:2] == ["fetch-call", "install"]
+        assert clock.slept == []
+        counters = engine.telemetry.counters
+        assert counters["look_ahead_late/skipped"] == 1
+        assert counters["admissions"] == 2 and a.on_output.done.is_set()
+        assert counters["prefill_behind_steps"] == 0
+
+    def test_a_waiting_request_without_pages_is_fetched_for_first(self):
+        """7 usable pages: a holds 6, b needs 5. The step that finds b
+        blocked has already waited call k out; from then on, while b
+        waits, every call is fetched first: its tokens may end a and
+        return the pages."""
+        engine, clock = late_engine(num_pages=8)
+        a, b = greedy_req("a", self.A, 60), greedy_req("b", self.B, 20)
+        engine.submit(a)
+        step_until_decoding(engine)
+        engine.submit(b)
+        engine.step()
+        counters = engine.telemetry.counters
+        assert counters["admissions_blocked/no_pages"] == 1
+        assert "look_ahead_late/skipped" not in counters
+        naps = len(clock.slept)
+        engine.step()
+        assert counters["look_ahead_late/skipped"] == 1
+        assert len(clock.slept) == naps and counters["admissions"] == 1
+        finish(engine, [a, b])
+        assert b.on_output.tokens == naive_greedy(engine, self.B, 20)
+
+    def test_a_result_that_comes_early_is_fetched_at_once(self):
+        engine, clock = late_engine()
+        a = greedy_req("a", self.A, 40)
+        engine.submit(a)
+        step_until_decoding(engine)
+        began, events = clock.t, []
+        spy_on_programs(engine, clock, events)
+        engine._result_ready = lambda call: clock.t >= began + 0.080
+        engine.step()
+        assert [name for name, _ in events] == ["fetch-call", "decode"]
+        assert events[0][1] == pytest.approx(began + 0.080, abs=1.1e-3)
+        assert engine.telemetry.counters["look_ahead_late/late"] == 1
+
+    def test_a_result_ready_at_the_dispatch_counts_as_late(self):
+        engine, clock = late_engine()
+        a = greedy_req("a", self.A, 40)
+        engine.submit(a)
+        step_until_decoding(engine)
+        real_decode = engine._decode_multi
+
+        def slow_dispatch(*args):        # the call ends under the dispatch
+            engine._result_ready = lambda call: True
+            return real_decode(*args)
+
+        engine._decode_multi = slow_dispatch
+        engine.step()
+        counters = engine.telemetry.counters
+        assert counters["look_ahead_late/late"] == 1
+        assert "look_ahead_late/hit" not in counters
+
+    def test_the_pump_thread_waits_on_the_real_clock(self):
+        """The loop thread itself, on the wall clock, with calls said to
+        last 40 ms: every seam is waited out in real time, requests that
+        arrive meanwhile are served, and stop() is not held up."""
+        engine = make_engine(decode_horizon=8)
+        pin_measurements(engine, 0.0005, 0.0400, 0.0401, 0.0400)
+        engine._result_ready = lambda call: False
+        reqs = [greedy_req("a", self.A, 40), greedy_req("b", self.B, 20),
+                greedy_req("c", self.C, 12)]
+        engine.start()
+        try:
+            t0 = time.monotonic()
+            for r in reqs:
+                engine.submit(r)
+                time.sleep(0.03)
+            for r in reqs:
+                assert r.on_output.done.wait(60)
+            took = time.monotonic() - t0
+        finally:
+            engine.stop()
+        counters = engine.telemetry.counters
+        assert counters["look_ahead_late/hit"] >= 4
+        # each hit waited for most of 40 ms, under fetch_wait
+        assert counters["host_s/fetch_wait"] >= \
+            0.03 * counters["look_ahead_late/hit"]
+        assert took >= 0.03 * counters["look_ahead_late/hit"]
+        for r, (prompt, n) in zip(reqs, ((self.A, 40), (self.B, 20),
+                                         (self.C, 12))):
+            assert r.on_output.tokens == naive_greedy(engine, prompt, n)
+
+    @staticmethod
+    def _mixed_run(order):
+        """Six requests over four slots, arriving at fixed steps of the
+        loop: greedy, seeded sampling, top-k logprobs, budgets that end in
+        the middle of a call."""
+        engine = make_engine(decode_horizon=8)
+        if order == "late":
+            pin_measurements(engine, *STEADY_LONG_CALLS)
+            FakeClock().drive(engine)
+            engine._result_ready = lambda call: False
+        else:
+            pin_measurements(engine, *{"fetch-first": LONG_CALL,
+                                       "always-ahead": ONE_STEP_CALL}[order])
+
+        def req(rid, start, n, max_tokens, **kw):
+            kw.setdefault("temperature", 0.0)
+            return EngineRequest(
+                rid, token_ids=list(range(start, start + n)),
+                sampling=SamplingParams(max_tokens=max_tokens,
+                                        ignore_eos=True, **kw),
+                on_output=Collector())
+
+        arrivals = {
+            0: [req("g1", 10, 30, 37), req("s1", 100, 50, 21,
+                                           temperature=0.9, seed=7)],
+            2: [req("l1", 60, 20, 12, logprobs=True, top_logprobs=3)],
+            3: [req("g2", 150, 40, 9), req("s2", 30, 35, 26,
+                                           temperature=0.7, top_k=20,
+                                           seed=11, logprobs=True,
+                                           top_logprobs=2)],
+            5: [req("g3", 200, 25, 18)],
+        }
+        reqs = [r for rs in arrivals.values() for r in rs]
+        for i in range(400):
+            for r in arrivals.get(i, ()):
+                engine.submit(r)
+            engine.step()
+            if i > 5 and all(r.on_output.done.is_set() for r in reqs):
+                break
+        assert all(r.on_output.done.is_set() for r in reqs)
+        served = {}
+        for r in reqs:
+            lps = [lp for o in r.on_output.outputs for s in o.outputs
+                   for lp in s.logprobs]
+            served[r.service_request_id] = (
+                r.on_output.tokens,
+                [(lp.token_id, lp.logprob,
+                  [(t.token_id, t.logprob) for t in lp.top_logprobs])
+                 for lp in lps])
+        return engine, served
+
+    def test_the_three_dispatch_orders_serve_the_same_tokens(self):
+        runs = {order: self._mixed_run(order)
+                for order in ("fetch-first", "always-ahead", "late")}
+        counters = {o: e.telemetry.counters for o, (e, _) in runs.items()}
+        # each run took the order it was meant to take
+        assert counters["late"]["look_ahead_late/hit"] >= 5
+        assert counters["fetch-first"]["look_ahead_late/skipped"] >= 5
+        assert "look_ahead_late/hit" not in counters["fetch-first"]
+        assert not any(k.startswith("look_ahead_late")
+                       for k in counters["always-ahead"])
+        assert counters["always-ahead"]["prefill_behind_steps"] > \
+            counters["late"]["prefill_behind_steps"] > 0 == \
+            counters["fetch-first"]["prefill_behind_steps"]
+        want = runs["fetch-first"][1]
+        assert sorted(want) == ["g1", "g2", "g3", "l1", "s1", "s2"]
+        assert [len(want[r][0]) for r in sorted(want)] == [
+            37, 9, 18, 12, 21, 26]
+        assert len(want["l1"][1]) == 12 and len(want["s2"][1]) == 26
+        for order in ("always-ahead", "late"):
+            got = runs[order][1]
+            for rid, (tokens, lps) in want.items():
+                assert got[rid][0] == tokens, (order, rid)
+                assert len(got[rid][1]) == len(lps)
+                for (tok, lp, top), (tok2, lp2, top2) in zip(lps,
+                                                             got[rid][1]):
+                    assert tok == tok2 and [t for t, _ in top] == [
+                        t for t, _ in top2], (order, rid)
+                    assert lp2 == pytest.approx(lp, abs=1e-5)
+                    assert [v for _, v in top2] == pytest.approx(
+                        [v for _, v in top], abs=1e-5)
 
 
 class TestSamplingKeys:

@@ -18,8 +18,10 @@ from xllm_service_tpu.engine.engine import EngineRequest
 from xllm_service_tpu.ops.page_walk import walk_run_counts
 
 from test_e2e_real_engine import _base, cluster  # noqa: F401 (fixture)
-from test_engine import (LONG_CALL, ONE_STEP_CALL, Collector, make_engine,
-                         pin_measurements, run_requests, step_until_decoding)
+from test_engine import (LONG_CALL, ONE_STEP_CALL, STEADY_LONG_CALLS,
+                         Collector, FakeClock, finish, late_engine,
+                         make_engine, pin_measurements, run_requests,
+                         step_until_decoding)
 
 PROMPT = list(range(1, 71))     # 70 tokens: two whole hash blocks of 32
 
@@ -182,23 +184,61 @@ def test_walk_counters_are_the_kernels_rule_on_the_live_rows():
     assert 0 < recent["walk_run_chunks"] < recent["walk_chunks"] <= want[0]
 
 
-def test_prefill_behind_steps_is_what_was_in_flight_at_the_dispatch():
+@pytest.mark.parametrize("measured,behind", [
+    (ONE_STEP_CALL, 4), (LONG_CALL, 0), (STEADY_LONG_CALLS, 1)],
+    ids=["ahead-at-once", "fetch-first", "late"])
+def test_prefill_behind_steps_is_what_was_in_flight_at_the_dispatch(
+        measured, behind):
     """Nothing runs when the first request is admitted: 0. The second is
-    dispatched behind whatever decode call is unfetched then: a whole call
-    of 4 steps where the pump looks ahead, nothing where it does not."""
-    for measured, behind in ((ONE_STEP_CALL, 4), (LONG_CALL, 0)):
-        e = make_engine(decode_horizon=4)
-        pin_measurements(e, *measured)
-        a, b = _req("a", max_tokens=20), _req("b", list(range(100, 140)))
-        e.submit(a)
-        step_until_decoding(e)
-        assert e.telemetry.counters["prefill_behind_steps"] == 0
-        e.submit(b)
+    dispatched behind whatever decode call is unfetched then, counted in
+    the steps the chip still has to run: a whole call of 4 steps where the
+    pump looks ahead at once, nothing where it fetches first, and one step
+    where it looks ahead late (a margin of a 93 ms call is left)."""
+    e = make_engine(decode_horizon=4)
+    pin_measurements(e, *measured)
+    FakeClock().drive(e)
+    e._result_ready = lambda call: False        # the chip is still at it
+    a, b = _req("a", max_tokens=20), _req("b", list(range(100, 140)))
+    e.submit(a)
+    step_until_decoding(e)
+    assert e.telemetry.counters["prefill_behind_steps"] == 0
+    e.submit(b)
+    e.step()
+    assert e.telemetry.counters["admissions"] == 2
+    assert e.telemetry.counters["prefill_behind_steps"] == behind
+    view = T.summarize([e.telemetry])
+    assert view["total"]["prefill_behind_steps"] == behind
+
+
+def test_the_seams_outcomes_are_counted_in_total_and_recent():
+    """hit: the call's result was not ready when the next program went
+    onto the queue; late: it was; skipped: the pump fetched first."""
+    e, clock = late_engine()
+    e.telemetry._t_snapshot -= T.SNAPSHOT_S     # the pump's copy is due:
+    e.telemetry.tick()                          # what `recent` starts from
+    a = _req("a", max_tokens=60)
+    e.submit(a)
+    step_until_decoding(e)
+    for _ in range(3):
         e.step()
-        assert e.telemetry.counters["admissions"] == 2
-        assert e.telemetry.counters["prefill_behind_steps"] == behind
-        view = T.summarize([e.telemetry])
-        assert view["total"]["prefill_behind_steps"] == behind
+    assert e.telemetry.counters["look_ahead_late/hit"] == 3
+    began = clock.t
+    e._result_ready = lambda call: clock.t >= began + 0.050   # early
+    e.step()
+    e._result_ready = lambda call: False
+    e._prefillings.append(None)      # a chunked prefill in flight: hold
+    plan = e._seam_plan(e._pending_decode)
+    e._prefillings.clear()
+    assert plan.action == "fetch" and plan.estimate_s > 0
+    finish(e, [a])                   # the last call: every budget ends
+    view = T.summarize([e.telemetry])
+    for part in ("total", "recent"):
+        seam = view[part]["look_ahead_late"]
+        assert seam["late"] == 1 and seam["skipped"] == 1
+        assert seam["hit"] >= 4
+    assert view["total"]["look_ahead_late"] == {
+        k.split("/")[1]: v for k, v in e.telemetry.counters.items()
+        if k.startswith("look_ahead_late/")}
 
 
 def test_turnaround_is_the_admit_and_decode_dispatch_phases():
@@ -508,14 +548,19 @@ def test_stats_metrics_and_the_prefill_span_carry_the_record(cluster):  # noqa: 
     assert recent["walk_chunks"] <= total["walk_chunks"]
     # the look-ahead rule as it stands, one entry an engine
     (rule,) = stats["look_ahead"]
-    assert set(rule) == {"turnaround_ms", "call_ms", "ahead"}
+    assert set(rule) == {"turnaround_ms", "call_ms", "ahead", "estimate_ms",
+                         "margin_ms", "error_ms"}
     assert rule["call_ms"] > 0 and isinstance(rule["ahead"], bool)
+    assert rule["estimate_ms"] >= 0
+    # every engine's first decode call is fetched first: nothing measured
+    assert total["look_ahead_late"]["skipped"] >= 1
 
     text = requests.get(f"http://{agent.name}/metrics", timeout=5).text
     for line in ("engine_admissions_total ", "engine_prefix_hit_tokens_total ",
                  "engine_preemptions_total 0", "engine_sarathi_rides_total ",
                  'engine_host_seconds_total{phase="fetch_wait"} ',
                  "engine_prefill_behind_steps_total ",
+                 'engine_look_ahead_late_total{outcome="skipped"} ',
                  "engine_walk_chunks_total ", "engine_walk_run_chunks_total ",
                  'engine_decode_calls_total{horizon="',
                  'engine_prefill_calls_total{bucket="'):

@@ -1085,7 +1085,8 @@ class EngineAgent:
         # The step loop's counters (engine_preemptions_total and
         # engine_sarathi_rides_total among them), by-key families labeled.
         labels = {"prefill_calls": "bucket", "decode_calls": "horizon",
-                  "admissions_blocked": "reason", "host_s": "phase"}
+                  "admissions_blocked": "reason", "host_s": "phase",
+                  "look_ahead_late": "outcome"}
         for name, v in self.engine_trace()["total"].items():
             metric = ("engine_host_seconds_total" if name == "host_s"
                       else f"engine_{name}_total")

@@ -22,11 +22,17 @@ become Pallas/XLA"). Design points for XLA:
   N+1's dispatch (host emit hides behind device compute; snapshot
   ownership guards slot reuse), and a burst of arrivals dispatches every
   prefill install into the device queue before fetching any result.
-  Round N's result is *fetched* before round N+1 is dispatched wherever
-  a call is long against the pump's turn-around (`look_ahead_pays`): an
-  arrival's prefill is then the next thing the chip runs, not queued
-  behind a call dispatched before the request existed. One-step calls
-  and spec rounds dispatch N+1 before fetching N.
+  Wherever a call is long against the pump's turn-around, round N+1 is
+  dispatched *late* (`look_ahead_plan`): the pump waits until a few
+  milliseconds before round N's predicted end, admits what has arrived,
+  queues round N+1 (or the arrivals' prefills) behind round N and only
+  then fetches N. The chip goes from one program straight to the next,
+  and an arrival's prefill is still the next thing the chip runs, not
+  queued behind a call dispatched before the request existed, unless it
+  falls inside those milliseconds. Where the pump has no steady estimate
+  of a call, or round N's tokens may make room for a waiting request, it
+  fetches N first. One-step calls, spec rounds and a multi-host mesh
+  dispatch N+1 at once, before fetching N.
 - **Per-slot budgets on device**: a slot freezes at its max_total_len
   like a stop-token hit, so the batch horizon follows the LONGEST
   remaining budget; while requests stay queued past an admission pass,
@@ -37,6 +43,7 @@ become Pallas/XLA"). Design points for XLA:
 
 from __future__ import annotations
 
+import math
 import os
 import statistics
 import threading
@@ -44,7 +51,7 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any, Callable, Optional
+from typing import Any, Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -83,31 +90,91 @@ logger = get_logger(__name__)
 # stop check covers the rest; the device just can't freeze the slot early.
 NUM_STOP_IDS = 4
 
-# The pump dispatches decode call k+1 before it fetches call k (one call of
-# look-ahead) only where its own turn-around between two calls (result
-# landed -> next decode program dispatched) is more than this share of a
-# call's time. The look-ahead hides the seam between two calls from the
-# chip, and costs every arrival one whole call: its prefill queues behind a
-# call that was dispatched before the request existed. Chosen on one v5e
-# chip (PERF.md section 6, PR 29): the seam the chip sees is ~1.3 ms a call,
-# of which the pump can measure only its own part, 0.46-0.56 ms (the rest
-# passes inside the runtime, between the program's end and the fetch's
-# return). At horizon 8 a call is 93 ms: 1.4% of the chip against 93 ms of
-# every first token, and the pump reads 0.5-0.6%: no look-ahead. At one
-# step a call, 11.6 ms, the same seam is 11% of the chip against 12 ms, and
-# the pump reads 4-5%: look ahead. 2% puts the change-over at calls of ~25
-# ms and leaves both sides a factor of two or more (at 1% a traced run's
-# slower pump crossed it on 5 of 89 admissions).
+# The pump dispatches decode call k+1 AT ONCE, before it fetches call k (one
+# call of look-ahead), only where its own turn-around between two calls
+# (result landed -> next decode program dispatched) is more than this share
+# of a call's time. The look-ahead hides the seam between two calls from the
+# chip, and dispatched at once it costs every arrival one whole call: its
+# prefill queues behind a call that was dispatched before the request
+# existed. Chosen on one v5e chip (PERF.md section 6, PR 29): at horizon 8 a
+# call is 93 ms and the pump reads 0.5-0.6%: not at once. At one step a
+# call, 11.6 ms, the pump reads 4-5%: at once. 2% puts the change-over at
+# calls of ~25 ms and leaves both sides a factor of two or more (at 1% a
+# traced run's slower pump crossed it on 5 of 89 admissions).
 LOOK_AHEAD_TURNAROUND_SHARE = 0.02
-# Medians of this many samples feed the rule: one slow turn-around (a GC
-# pause, a descheduled pump) does not flip it.
+# Medians of this many turn-arounds feed the rule: one slow one (a GC
+# pause, a descheduled pump) does not flip it. As many calls of a horizon
+# are kept for the estimate of the next one.
 _LOOK_AHEAD_SAMPLES = 9
+# Where a call is long, the pump looks ahead LATE (PR 42): it waits until
+# this long before the moment it expects the running call's result, admits
+# whatever has arrived, dispatches call k+1 behind call k and only then
+# fetches k. The seam is hidden from the chip as above, and an arrival pays
+# a whole call only if it falls inside these milliseconds. Everything is
+# read on the pump's own clock, where a call ends when its fetch returns:
+# so the margin holds what passes between a program's end and that return
+# (1.2-1.5 ms on one v5e chip), the pump's turn-around (0.5 ms), a
+# dispatch's way to the device queue (~0.5 ms) and what the newest call's
+# time misses the next one by (cell 4's call moves 2-3 ms with a live row).
+# Too late leaves the seam where it was; too early costs the arrivals of
+# those milliseconds a call each, which is a millisecond of mean first
+# token a millisecond of margin, whatever the call's length. Where the
+# pump's turn-around and the estimate's recent error come to more than
+# this, it fetches first, as it did before (PERF.md section 6, PR 42).
+LOOK_AHEAD_MARGIN_S = 0.005
 
 
 def look_ahead_pays(turnaround_s: float, call_s: float) -> bool:
-    """Whether to dispatch the next decode call before fetching the one
-    that is running. Before the first measurement (0, 0): no."""
+    """Whether to dispatch the next decode call at once, before fetching
+    the one that is running. Before the first measurement (0, 0): no."""
     return turnaround_s > LOOK_AHEAD_TURNAROUND_SHARE * call_s
+
+
+class SeamPlan(NamedTuple):
+    """What the pump does about the decode call that is running."""
+    action: str             # "ahead" (dispatch now) | "wait" | "fetch"
+    at: float = 0.0         # a late dispatch's moment, on the pump's clock
+    estimate_s: float = 0.0     # the running call's predicted time
+    error_s: float = 0.0        # what that estimate missed recent calls by
+
+
+def look_ahead_plan(began_s: float, call_s: Sequence[float],
+                    turnaround_s: float, clock: Callable[[], float],
+                    hold: bool = False, multi_host: bool = False) -> SeamPlan:
+    """The rule of the seam, as a pure function of what the pump measured:
+    when the running call began, the times of recent calls of its horizon
+    (newest last), the pump's turn-around, the clock.
+
+    - A multi-host mesh dispatches ahead at once: its hosts run one
+      program sequence in lockstep, which no wall-clock decision may enter
+      (multihost_driver.py). The clock is not read.
+    - No estimate yet: fetch first.
+    - A call short against the turn-around: ahead at once
+      (`look_ahead_pays`, on the median call as before).
+    - A long call: the newest call is the estimate of this one (a call
+      follows its live rows, so no median), and what that estimate missed
+      the last three calls by is its error. Wait until the predicted end
+      less LOOK_AHEAD_MARGIN_S, then dispatch ("ahead" where that moment
+      has come). Fetch first where the margin does not cover the
+      turn-around and the error (with one sample nothing is known of it),
+      or the caller knows a reason to see the call's tokens first (`hold`:
+      a request waits that a landing could admit, every budget ends inside
+      the call, a chunked prefill already queues programs behind it)."""
+    if multi_host:
+        return SeamPlan("ahead")
+    if not call_s or not turnaround_s:
+        return SeamPlan("fetch")
+    estimate = call_s[-1]
+    if look_ahead_pays(turnaround_s, statistics.median(call_s)):
+        return SeamPlan("ahead", 0.0, estimate)
+    last = call_s[-4:]
+    error = max((abs(b - a) for a, b in zip(last, last[1:])),
+                default=float("inf"))
+    if hold or turnaround_s + error > LOOK_AHEAD_MARGIN_S:
+        return SeamPlan("fetch", 0.0, estimate, error)
+    at = began_s + estimate - LOOK_AHEAD_MARGIN_S
+    return SeamPlan("ahead" if clock() >= at else "wait", at, estimate,
+                    error)
 
 
 def seed_key_bits(seed: int) -> np.ndarray:
@@ -126,6 +193,11 @@ class _DecodeCall:
     horizon: int
     snapshot: dict                # {slot: _Sequence} it was dispatched for
     landed: Optional[np.ndarray] = None   # `packed` on the host, once read
+    # A late look-ahead's state: None (not one), "due" (the pump waited
+    # for its moment and nothing is queued behind the call yet), then
+    # "hit" (its result was not ready when the next program went onto the
+    # queue) or "late" (it was).
+    late: Optional[str] = None
 
 
 @dataclass
@@ -454,18 +526,27 @@ class InferenceEngine:
         # Decode pipeline: the last dispatched decode call whose tokens
         # have not been emitted yet. Host-side output processing of call
         # k overlaps the device executing call k+1 either way; whether
-        # call k+1 is dispatched before call k's result is *fetched* (one
-        # call of look-ahead) or right after it lands is `_look_ahead`'s
-        # decision. The speculative path keeps its own pending slot and
-        # always looks ahead: (packed, t_dispatch, cycles, snapshot, n).
+        # call k+1 is dispatched at once, a few milliseconds before call
+        # k's predicted end, or only after call k's result is *fetched* is
+        # `look_ahead_plan`'s decision (`_seam_plan`). The speculative
+        # path keeps its own pending slot and always looks ahead:
+        # (packed, t_dispatch, cycles, snapshot, n).
         self._pending_decode: Optional[_DecodeCall] = None
         self._pending_spec: Optional[tuple] = None
-        # The look-ahead rule's inputs, measured by the pump itself: its
-        # turn-around before a decode dispatch (steps that admit nothing)
-        # and the time each fetched call had the chip.
+        # The rule's inputs, measured by the pump itself on its own clock
+        # (`_clock`; a test hands in another, and the `_sleep` that goes
+        # with it): its turn-around before a decode dispatch (steps that
+        # admit nothing), the (horizon, time) of each fetched call, and
+        # when the last result came back, a decode call's or a prefill's.
+        self._clock: Callable[[], float] = time.monotonic
+        self._sleep: Callable[[float], None] = time.sleep
         self._turnaround_s: deque[float] = deque(maxlen=_LOOK_AHEAD_SAMPLES)
-        self._call_s: deque[float] = deque(maxlen=_LOOK_AHEAD_SAMPLES)
-        self._t_landed = 0.0          # time.monotonic() of the last fetch
+        self._call_s: deque[tuple[int, float]] = deque(
+            maxlen=_LOOK_AHEAD_SAMPLES)
+        self._t_landed = 0.0
+        # `_admit` left a request waiting for a slot or for pages: the
+        # running call's tokens may free them, so nothing goes ahead of it.
+        self._admit_blocked = False
         # Sarathi mixed decode+chunk steps (a mid chunk rides a decode
         # call when prefill_chunk_tokens > 0 and the family has a mixed
         # program, see _ride_chunk_args): chunks per ride under queue
@@ -1418,12 +1499,20 @@ class InferenceEngine:
                 "attention_paths": {k: dict(v)
                                     for k, v in self._paths.items()},
             }
-        # The look-ahead rule as it stands: its two measured inputs and
-        # what it decides on them.
+        # The look-ahead rule as it stands: its two measured inputs, what
+        # it decides on them, and what a late dispatch would go by (for a
+        # call the pump would hold: no clock is read).
         turnaround_s, call_s = self._look_ahead_inputs()
-        out["look_ahead"] = {"turnaround_ms": turnaround_s * 1000,
-                             "call_ms": call_s * 1000,
-                             "ahead": self._look_ahead()}
+        plan = look_ahead_plan(0.0, call_s, turnaround_s, self._clock,
+                               hold=True, multi_host=jax.process_count() > 1)
+        out["look_ahead"] = {
+            "turnaround_ms": turnaround_s * 1000,
+            "call_ms": statistics.median(call_s) * 1000 if call_s else 0.0,
+            "ahead": plan.action == "ahead",
+            "estimate_ms": plan.estimate_s * 1000,
+            "margin_ms": LOOK_AHEAD_MARGIN_S * 1000,
+            "error_ms": (plan.error_s * 1000
+                         if math.isfinite(plan.error_s) else None)}
         if self.tier_store is not None:
             out["kv_tier"] = self.tier_store.stats()
         return out
@@ -1700,24 +1789,34 @@ class InferenceEngine:
         return np.asarray(arr)
 
     def step(self) -> bool:
-        """One engine iteration: land the running decode call's result
-        (unless the pump looks a call ahead), process cancellations, admit
-        (short prompts are never stuck behind an in-flight long prefill),
-        decode one horizon, advance one chunk of one in-flight chunked
-        prefill (round-robin). Chunked prefill keeps long-prompt admission
-        from stalling running decodes.
+        """One engine iteration: deal with the running decode call as the
+        seam's rule says (`_seam_plan`: fetch its result first, wait for a
+        moment just before its end, or neither), process cancellations,
+        admit (short prompts are never stuck behind an in-flight long
+        prefill), decode one horizon, advance one chunk of one in-flight
+        chunked prefill (round-robin). Chunked prefill keeps long-prompt
+        admission from stalling running decodes.
 
-        At the seam between two decode calls nothing is queued behind the
-        running one, so what admission dispatches is the next thing the
-        chip runs. A landed call's tokens are emitted after the next
-        program is on the device queue: after the next decode dispatch, or,
-        with admissions, while their prefills run (`_admit`)."""
+        A long call is fetched before anything is dispatched, or has the
+        next program queued behind it a few milliseconds before it ends:
+        either way what admission dispatches is the next thing the chip
+        runs. A landed call's tokens are emitted after the next program is
+        on the device queue: after the next decode dispatch, or, with
+        admissions, while their prefills run (`_admit`)."""
         tel = self.telemetry
         tel.tick()
         call = self._pending_decode
-        if (call is not None and call.landed is None
-                and not self._look_ahead()):
-            self._land_decode(call)
+        if call is not None and call.landed is None:
+            plan = self._seam_plan(call)
+            late = plan.at > 0
+            if late and self._wait_for_seam(call, plan.at):
+                call.late = "due"
+            elif late or plan.action == "fetch":
+                # fetch first: the result came before the moment did, or
+                # the rule said so
+                tel.count_by("look_ahead_late",
+                             "late" if late else "skipped")
+                self._land_decode(call)
         turnaround_from = tel.turnaround_s()
         with tel.phase("admit"):
             self._process_cancellations()
@@ -1730,8 +1829,8 @@ class InferenceEngine:
         self._rode_chunk = False
         with tel.phase("decode_dispatch"):
             # A step that admits nothing spends its time up to the decode
-            # dispatch on the pump's turn-around alone: a sample for
-            # `_look_ahead`.
+            # dispatch on the pump's turn-around alone: a sample for the
+            # rule.
             decoded = self._decode(
                 None if worked or self._prefillings else turnaround_from)
         if self._prefillings and not self._rode_chunk:
@@ -1739,21 +1838,64 @@ class InferenceEngine:
                 worked = self._advance_prefill() or worked
         return worked or decoded
 
-    def _look_ahead(self) -> bool:
-        """Dispatch decode call k+1 before fetching call k? The pump's own
-        measurements decide (`look_ahead_pays`). A multi-host mesh always
-        does: its hosts run one program sequence in lockstep, which no
-        wall-clock decision may enter (multihost_driver.py)."""
-        return jax.process_count() > 1 or look_ahead_pays(
-            *self._look_ahead_inputs())
+    def _seam_plan(self, call: _DecodeCall) -> SeamPlan:
+        """`look_ahead_plan` on the pump's own measurements, for the
+        running call. Nothing goes ahead of it late (`hold`) where its
+        tokens may make room for a waiting request, a chunked prefill
+        already queues its programs behind it, or every running budget
+        ends inside it."""
+        with self._lock:
+            blocked = bool(self._waiting) and (
+                self._admit_blocked or not self._free_slots)
+        hold = (blocked or bool(self._prefillings)
+                or not self._live_after(call))
+        turnaround_s, call_s = self._look_ahead_inputs(call.horizon)
+        return look_ahead_plan(
+            max(call.t0, self._t_landed), call_s, turnaround_s, self._clock,
+            hold=hold, multi_host=jax.process_count() > 1)
 
-    def _look_ahead_inputs(self) -> tuple[float, float]:
-        """(turn-around s, call s): the medians of the pump's last
-        measurements of each; (0, 0) until it has both."""
-        if not self._turnaround_s or not self._call_s:
-            return 0.0, 0.0
-        return (statistics.median(self._turnaround_s),
-                statistics.median(self._call_s))
+    def _look_ahead_inputs(
+            self, horizon: Optional[int] = None) -> tuple[float, list[float]]:
+        """(the median of the pump's last turn-arounds, the times of its
+        last calls of `horizon`, newest last; None: of the horizon sampled
+        last). A call began at its dispatch, or at the landing before it
+        if it was queued behind that program, and ended at its own."""
+        turnarounds, calls = list(self._turnaround_s), list(self._call_s)
+        if horizon is None and calls:
+            horizon = calls[-1][0]
+        return (statistics.median(turnarounds) if turnarounds else 0.0,
+                [s for h, s in calls if h == horizon])
+
+    def _wait_for_seam(self, call: _DecodeCall, at: float) -> bool:
+        """Wait for the moment of a late dispatch, under `fetch_wait`: the
+        pump is waiting for the chip's result, as it was when it blocked
+        in the fetch. False where the result came first: the pump asks
+        once a turn-around (asking oftener would get nothing onto the
+        queue sooner), so a call that ended before its time is not slept
+        through."""
+        poll_s = statistics.median(list(self._turnaround_s))
+        with self.telemetry.phase("fetch_wait"):
+            while not self._result_ready(call):
+                left = at - self._clock()
+                if left <= 0:
+                    return True
+                if self._stopped.is_set():
+                    break
+                self._sleep(min(left, poll_s))
+        return False
+
+    @staticmethod
+    def _result_ready(call: _DecodeCall) -> bool:
+        return call.packed.is_ready()
+
+    def _settle_seam(self, call: Optional[_DecodeCall]) -> None:
+        """Called when a program has gone onto the device queue, or when
+        `call` has landed: the first of the two after the pump waited
+        `call` out says whether its seam was hidden."""
+        if call is not None and call.late == "due":
+            call.late = ("late" if call.landed is not None
+                         or self._result_ready(call) else "hit")
+            self.telemetry.count_by("look_ahead_late", call.late)
 
     def _process_cancellations(self) -> None:
         with self._lock:
@@ -1809,6 +1951,7 @@ class InferenceEngine:
 
     def _admit(self) -> bool:
         admitted = False
+        self._admit_blocked = False
         C = self.cfg.prefill_chunk_tokens
         deferred: list[EngineRequest] = []
         # Prefill installs dispatched but not yet completed: every waiting
@@ -1826,7 +1969,8 @@ class InferenceEngine:
         def _complete_batch():
             if batch:
                 # The installs are on the device queue: the landed decode
-                # call's tokens go out while they run.
+                # call's tokens go out while they run (a call they were
+                # queued behind a moment before its end lands first).
                 self._emit_landed()
             while batch:
                 entry = batch.pop(0)
@@ -1851,6 +1995,7 @@ class InferenceEngine:
                     continue    # its tokens may have finished a sequence
                 if req is None:
                     if blocked:
+                        self._admit_blocked = True
                         self.telemetry.count_by("admissions_blocked",
                                                 "no_slot")
                     _requeue_deferred()
@@ -1903,6 +2048,7 @@ class InferenceEngine:
                         if self._start_sequence(req, batch=batch):
                             admitted = True
                             continue
+                    self._admit_blocked = True
                     self.telemetry.count_by("admissions_blocked", "no_pages")
                     with self._lock:
                         self._waiting.appendleft(req)
@@ -2574,11 +2720,21 @@ class InferenceEngine:
         return self._rng.integers(0, 1 << 32, size=2, dtype=np.uint32)
 
     def _steps_in_flight(self) -> int:
-        """Decode steps dispatched and not yet fetched: what the chip runs
-        before it reaches a program dispatched now."""
+        """Decode steps dispatched and not yet fetched that the chip still
+        has to run before it reaches a program dispatched now: of the
+        running call, by the pump's estimate of its end where it has one
+        (a call two milliseconds from its end is not a whole horizon)."""
         call, spec = self._pending_decode, self._pending_spec
-        return ((call.horizon if call and call.landed is None else 0)
-                + (spec[2] if spec else 0))
+        steps = 0
+        if call is not None and call.landed is None:
+            steps = call.horizon
+            call_s = self._look_ahead_inputs(call.horizon)[1]
+            if call_s:
+                left = (max(call.t0, self._t_landed) + call_s[-1]
+                        - self._clock())
+                steps = math.ceil(
+                    steps * min(1.0, max(0.0, left / call_s[-1])))
+        return steps + (spec[2] if spec else 0)
 
     def _dispatch_prefill_install(self, seq: _Sequence, prompt: list[int],
                                   matched: int) -> jax.Array:
@@ -2661,6 +2817,7 @@ class InferenceEngine:
                     else self._prefill_install_nc)
         self._dstate, packed = prog(
             self.params, self._dstate, jnp.asarray(packed_in), mm_arr)
+        self._settle_seam(self._pending_decode)
         return packed
 
     def _complete_prefill_install(
@@ -2668,6 +2825,12 @@ class InferenceEngine:
             packed: jax.Array) -> tuple[int, Optional[LogProb]]:
         with self.telemetry.phase("fetch_wait"):
             packed_np = self._fetch(packed)
+        call = self._pending_decode
+        if call is None or call.landed is not None:
+            # The chip has just ended this prefill: a decode call
+            # dispatched now begins now. (A call still unfetched was
+            # dispatched ahead at once; its own landing says when it ended.)
+            self._t_landed = self._clock()
         K = self.cfg.max_top_logprobs
         token = int(packed_np[0])
         lp = self._make_logprob(token, float(packed_np[1]),
@@ -2713,7 +2876,7 @@ class InferenceEngine:
                   default=horizon)
         if 0 < rem < horizon:
             horizon = min(1 << (rem - 1).bit_length(), horizon)
-        t0 = time.monotonic()
+        t0 = self._clock()
         ride = self._ride_chunk_args(horizon)
         if ride is not None:
             self._dstate, packed = self._decode_chunk_multi(
@@ -2723,6 +2886,7 @@ class InferenceEngine:
         else:
             self._dstate, packed = self._decode_multi(
                 self.params, self._dstate, horizon)
+        self._settle_seam(self._pending_decode)
         if turnaround_from is not None:
             self._turnaround_s.append(
                 self.telemetry.turnaround_s() - turnaround_from)
@@ -2730,17 +2894,34 @@ class InferenceEngine:
         # outputs while the device executes this one. Token emission (incl.
         # detokenize + callbacks, real host cost per horizon) is thereby
         # hidden behind device compute instead of serializing with it.
-        # (The previous call has landed already unless the pump looks a
-        # call ahead; then its fetch blocks here, behind this dispatch.)
-        snapshot = {slot: seq for slot, seq in self._running.items()
-                    if not seq.finished}
+        # (The previous call has landed already unless the pump looked
+        # ahead of it; then its fetch blocks here, behind this dispatch.)
+        prev = self._pending_decode
+        if prev is not None and prev.late:
+            # Dispatched late, behind a call whose tokens the host has not
+            # seen: it serves the sequences that can be live in it. The
+            # call ahead exhausts some budgets, and the device freezes
+            # those slots by itself.
+            snapshot = self._live_after(prev)
+        else:
+            snapshot = {slot: seq for slot, seq in self._running.items()
+                        if not seq.finished}
         self._count_decode_call(horizon, horizon, snapshot)
-        prev, self._pending_decode = (self._pending_decode, _DecodeCall(
-            packed, t0, horizon, snapshot))
+        self._pending_decode = _DecodeCall(packed, t0, horizon, snapshot)
         if prev is not None:
             with self.telemetry.phase("emit"):
                 self._drain_one_decode(prev)
         return True
+
+    def _live_after(self, flying: _DecodeCall) -> dict[int, _Sequence]:
+        """{slot: sequence} of the running sequences that have budget left
+        once `flying`, a dispatched call whose tokens are not out yet, has
+        run: `output_ids` lags that call by its horizon."""
+        return {
+            slot: seq for slot, seq in self._running.items()
+            if not seq.finished
+            and seq.max_total_len - seq.prompt_len - len(seq.output_ids)
+            > (flying.horizon if flying.snapshot.get(slot) is seq else 0)}
 
     def _count_decode_call(self, key, steps: int, snapshot: dict) -> None:
         """Telemetry of one dispatched decode call (O(batch)): the
@@ -2784,10 +2965,12 @@ class InferenceEngine:
         return True
 
     def _emit_landed(self) -> bool:
-        """Emit the pending decode call's tokens if its result has landed
-        (a call the pump looked ahead of has not: it stays pending)."""
+        """Emit the pending decode call's tokens if its result has landed,
+        or is about to (a call the pump waited out: its end is a margin
+        away). A call the pump looked ahead of at once stays pending."""
         call = self._pending_decode
-        return (call is not None and call.landed is not None
+        return (call is not None
+                and (call.landed is not None or call.late is not None)
                 and self._drain_pending_decode())
 
     def _land_decode(self, call: _DecodeCall) -> None:
@@ -2801,10 +2984,14 @@ class InferenceEngine:
             # the router's counts of each step, behind the batch's rows
             rows, touched = call.landed[:, B, :2].sum(axis=0)
             self.telemetry.moe_landed(call.horizon, int(rows), int(touched))
-        now = time.monotonic()
-        # The chip ran it from its dispatch, or from when the call before
-        # it landed if it was queued behind that one.
-        self._call_s.append(now - max(call.t0, self._t_landed))
+        # (late, where the pump waited it out and then fetched it with
+        # nothing queued behind it: admission wanted its tokens first)
+        self._settle_seam(call)
+        now = self._clock()
+        # The chip ran it from its dispatch, or from the landing before it
+        # if it was queued behind that program.
+        self._call_s.append(
+            (call.horizon, now - max(call.t0, self._t_landed)))
         self._t_landed = now
         ms_per_tok = (now - call.t0) * 1000 / max(1, call.horizon)
         with self._telemetry_lock:

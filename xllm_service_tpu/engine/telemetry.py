@@ -33,10 +33,23 @@ horizon, reason or phase); `summarize` nests them for output:
               admissions_blocked/<no_slot|no_pages> (loop iterations that
               left a waiting request unadmitted), preemptions, cancelled,
               finished, prefill_behind_steps (decode steps dispatched and
-              not yet fetched when an admission's install program was
-              dispatched, summed over admissions: what the chip runs
-              before it reaches the prefill; 0 where the pump does not
-              look a decode call ahead)
+              not yet fetched that the chip still had to run, by the
+              pump's estimate of the running call's end, when an
+              admission's install program was dispatched, summed over
+              admissions: what the chip runs before it reaches the
+              prefill; 0 where the pump fetches first, a step or so where
+              it looks ahead late, a whole call where it looks ahead at
+              once)
+  seam        look_ahead_late/<hit|late|skipped>: what became of the seam
+              behind a decode call long enough to be looked ahead of late
+              (engine.look_ahead_plan). hit: the pump waited for the
+              moment before the call's predicted end, and the call's
+              result was not ready when the next program went onto the
+              queue behind it: the chip went straight on. late: it was
+              ready (then, or before the moment came): the seam stayed.
+              skipped: the pump fetched first (no steady estimate, a
+              waiting request the call's tokens might make room for, a
+              chunked prefill in flight, every budget ending in the call)
   decode      decode_calls/<horizon|spec>, decode_steps (sum of horizons),
               live_slot_steps (live x horizon), context_token_steps (sum of
               the live sequences' `context_len` at dispatch x horizon: the
