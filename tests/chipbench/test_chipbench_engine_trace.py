@@ -8,6 +8,7 @@ from pathlib import Path
 import pytest
 
 from chipbench import harness, hostspans
+from chipbench_entries import due, per_layer, stands_after
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH, SEARCH = harness.load_bench(ROOT / "BENCHMARK.json")
@@ -200,15 +201,29 @@ def test_kv_bandwidth_share_counts_what_the_family_counts(pump_in_the_gap):
     assert read(dict(ctx, family=Quarter)) == pytest.approx(read(ctx) / 4)
 
 
+THIRTEEN = [                       # the per-layer entries PR 23 brought
+    "master.schedule_ms", "agent.first_delta_ms", "engine.queue_ms",
+    "engine.prefill_ms", "client.ttft_mean_ms",
+    "agent.first_delta_ms.agent-prefix", "engine.queue_ms.agent-prefix",
+    "engine.prefill_ms.agent-prefix", "prog.decode_step_ms",
+    "prog.prefill_call_ms", "kernel.paged_attn_ms", "device.idle_pct",
+    "device.decode_weight_bw_pct"]
+SIX = ["engine.prefix_hit_pct", "engine.batch_live_mean",
+       "engine.kv_used_of_reserved_pct", "engine.host_busy_pct",
+       "kernel.paged_attn_kv_bw_pct", "device.idle_unfed_pct"]
+
+
 def test_the_six_follow_the_thirteen_and_are_reported_where_they_read():
-    names = [m["name"] for m in BENCH["per_layer"]]
-    assert names[13:19] == [       # after the thirteen PR 23 brought
-        "engine.prefix_hit_pct", "engine.batch_live_mean",
-        "engine.kv_used_of_reserved_pct", "engine.host_busy_pct",
-        "kernel.paged_attn_kv_bw_pct", "device.idle_unfed_pct"]
-    for wl in BENCH["workloads"]:
-        got = {m["name"] for m in harness.metrics_for(BENCH, "per_layer",
-                                                      wl["name"])}
-        want = set(EXPECTED) - ({"engine.prefix_hit_pct"}
-                                if wl["name"] != CELL2 else set())
-        assert got & set(EXPECTED) == want
+    """PR 24's six in their order after PR 23's thirteen, reported in the
+    two cells of their day as they were (the prefix cache's share in the
+    cell that shares prefixes alone), and each due in every cell of the
+    list it has since (a reader of keys lists the cells that hold any)."""
+    assert set(SIX) == set(EXPECTED)
+    assert stands_after(BENCH, SIX, THIRTEEN)
+    for cell in ("qwen25-7b-int8.chat", CELL2):
+        got = {n for n in SIX if due(BENCH, n, cell)}
+        assert got == set(SIX) - ({"engine.prefix_hit_pct"}
+                                  if cell != CELL2 else set())
+    for name in SIX:
+        for cell in per_layer(BENCH, name).get("workloads", ()):
+            assert due(BENCH, name, cell)

@@ -18,6 +18,8 @@ import pytest
 
 from chipbench import harness
 from chipbench.engine_setup import build_engine_config
+from chipbench_entries import (but_its_list, due, first_token_is_read,
+                               per_layer, stands_after)
 
 ROOT = Path(__file__).resolve().parents[2]
 DATA = ROOT / "tests/chipbench/data"
@@ -163,20 +165,21 @@ def test_the_cell_resolves_and_is_due_every_standing_metric_it_moves():
     assert cell.mix["ramp_s"] == 8 and cell.mix["shared_prefix"] is None
     assert (cell.check_requests, cell.check_logprobs) == (6, 5)
     e2e = {m["name"] for m in harness.metrics_for(bench, "end_to_end", CELL)}
-    assert e2e == {"tpot_ms.p90", "gap_ms.p95", "out_tok_per_s", "setup_s"}
-    due = {m["name"] for m in harness.metrics_for(bench, "per_layer", CELL)}
-    new = {"kernel.retention_update_ms", "kernel.retention_update_state_bw_pct",
-           "kernel.retention_prefill_ms", "kernel.retention_prefill_mxu_pct",
-           "block.ret_ms", "engine.prefill_chunks_per_admission",
-           "client.ttft_mean_ms.doc-long", "engine.queue_ms.doc-long",
-           "engine.prefill_ms.doc-long", "prog.prefill_chunk_ms"}
+    assert e2e - {"ttft_ms.mean"} == {"tpot_ms.p90", "gap_ms.p95",
+                                      "out_tok_per_s", "setup_s"}
+    # the first token: judged, or read through the cell's own three entries
+    assert first_token_is_read(bench, CELL, "doc-long")
+    got = {m["name"] for m in harness.metrics_for(bench, "per_layer", CELL)}
+    new = {n for n in ADDED if per_layer(bench, n) is not None}
+    assert new >= set(ADDED) - {f"{n}.doc-long" for n in (
+        "client.ttft_mean_ms", "engine.queue_ms", "engine.prefill_ms")}
     assert new | {"prog.decode_step_ms", "prog.prefill_call_ms",
                   "device.decode_weight_bw_pct", "block.mlp_ms",
-                  "block.known_ops_pct", "device.idle_pct"} <= due
+                  "block.known_ops_pct", "device.idle_pct"} <= got
     # the six readers of keys and of a pool find nothing to read here, so
-    # each lists the four standing cells and is not due in this one
+    # each lists the cells that hold keys and is not due in this one
     # (`test_a_reader_of_keys_lists_the_standing_cells_and_is_not_due_here`)
-    assert not due & set(SILENT)
+    assert not got & set(SILENT)
     # and no other cell is given the new ones, nor loses one it had
     for other in STANDING_CELLS:
         names = {m["name"] for m in harness.metrics_for(
@@ -212,21 +215,21 @@ SILENT = {
 
 @pytest.mark.parametrize("name", sorted(SILENT))
 def test_a_reader_of_keys_lists_the_standing_cells_and_is_not_due_here(name):
-    """What the standing tests of these entries held and still holds: the
-    entry as it was written but for the list, due in each cell that stood;
-    and the reader finds nothing in a run of this family (conftest.py
-    beside this file names the three tests that held the entries to having
-    no list, or to being due in every cell)."""
+    """The entry as it was written but for its list; a list that begins
+    with the four cells that stood when this one came and never holds this
+    one; due in each of those four and not here; and the reader finds
+    nothing in a run of this family. Of the cells that came after, nothing:
+    one whose model holds keys reads them through entries of its own."""
     bench, search = harness.load_bench(ROOT / "BENCHMARK.json")
-    (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
+    entry = per_layer(bench, name)
     unit, better, source, layer, moves = SILENT[name]
-    assert entry == {"name": name, "unit": unit, "better": better,
-                     "source": source, "layer": layer, "moves": moves,
-                     "workloads": STANDING_CELLS}
-    for wl in bench["workloads"]:
-        due = name in [m["name"] for m in harness.metrics_for(
-            bench, "per_layer", wl["name"])]
-        assert due == (wl["name"] != CELL)
+    assert but_its_list(entry) == {
+        "name": name, "unit": unit, "better": better, "source": source,
+        "layer": layer, "moves": moves}
+    assert entry["workloads"][:len(STANDING_CELLS)] == STANDING_CELLS
+    assert CELL not in entry["workloads"]
+    assert all(due(bench, name, cell) for cell in STANDING_CELLS)
+    assert not due(bench, name, CELL)
     ir, spans = _toy_trace()
     assert _reader(name)(_ctx(ir, spans)) is None
 
@@ -252,26 +255,39 @@ STANDING = [
     "block.prefill_attn_ms", "block.known_ops_pct",
     "engine.prefill_padding_pct", "kernel.prefill_attn_ms"]
 
+# The ten per-layer entries PR 41 appended, each listing this cell alone.
+ADDED = [
+    "kernel.retention_update_ms", "kernel.retention_update_state_bw_pct",
+    "kernel.retention_prefill_ms", "kernel.retention_prefill_mxu_pct",
+    "block.ret_ms", "engine.prefill_chunks_per_admission",
+    "client.ttft_mean_ms.doc-long", "engine.queue_ms.doc-long",
+    "engine.prefill_ms.doc-long", "prog.prefill_chunk_ms"]
+
 
 def test_the_benchmark_gained_entries_and_lost_or_edited_none():
-    """The 43 per-layer entries that stood before this cell, in their
-    order, then this PR's ten, each of which lists the new cell alone; of
-    those that stood, the six of `SILENT` gained the list of the cells that
-    stood and every other is as the parent wrote it (`git show
-    HEAD:BENCHMARK.json` is the parent's in a PR's working tree, so the
-    test reads the names and the keys a list could have been slipped into)."""
+    """The 43 per-layer entries that stood before this cell come first, in
+    their order, then PR 41's ten, each of which lists this cell alone; of
+    those that stood the six of `SILENT` gained a list that begins with the
+    cells that stood and never holds this one. An entry of either list
+    that a `benchmark` PR has taken away since is that PR's to name
+    (`BENCHMARK_EDITS`, test_chipbench_files.py); the rest keep their
+    places. The configuration is there, and the cells begin with the four
+    that stood and this one. Of what came after, nothing."""
     bench, _ = harness.load_bench(ROOT / "BENCHMARK.json")
-    entries = bench["per_layer"]
-    names = [m["name"] for m in entries]
-    assert names[:len(STANDING)] == STANDING
-    added = entries[len(STANDING):]
-    assert len(added) == 10 and all(m["workloads"] == [CELL] for m in added)
-    for m in entries[:len(STANDING)]:
-        assert CELL not in m.get("workloads", ())
-        if m["name"] in SILENT:
-            assert m["workloads"] == STANDING_CELLS
-    assert [c["name"] for c in bench["configs"]][-1] == "brumby-14b-base"
-    assert [w["name"] for w in bench["workloads"]] == STANDING_CELLS + [CELL]
+    names = [m["name"] for m in bench["per_layer"]]
+    held = [n for n in STANDING + ADDED if n in names]
+    assert len(held) >= len(STANDING + ADDED) - 9     # most still stand
+    assert names[:len(held)] == held and stands_after(bench, ADDED, STANDING)
+    for name in held:
+        entry = per_layer(bench, name)
+        if name in ADDED:
+            assert entry["workloads"] == [CELL]
+        if name in SILENT:
+            assert CELL not in entry["workloads"]
+            assert entry["workloads"][:len(STANDING_CELLS)] == STANDING_CELLS
+    assert "brumby-14b-base" in [c["name"] for c in bench["configs"]]
+    assert [w["name"] for w in bench["workloads"]][
+        :len(STANDING_CELLS) + 1] == STANDING_CELLS + [CELL]
 
 
 # What the chip read in this cell (TPU v5e, PR 41, `run.py --control`): each

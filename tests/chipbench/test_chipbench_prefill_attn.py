@@ -7,6 +7,7 @@ from pathlib import Path
 import pytest
 
 from chipbench import harness
+from chipbench_entries import but_its_list, due, per_layer, stands_after
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH, SEARCH = harness.load_bench(ROOT / "BENCHMARK.json")
@@ -86,11 +87,17 @@ def test_neither_kernels_reader_takes_the_others_events():
 
 
 def test_the_entry_is_a_kernels_metric_of_every_cell():
-    entry = next(m for m in BENCH["per_layer"] if m["name"] == METRIC)
-    assert entry == {"name": METRIC, "unit": "ms", "better": "lower",
-                     "source": "device_trace", "layer": "kernels",
-                     "moves": "gap_ms.p95"}
-    assert BENCH["per_layer"][-1] is entry         # appended, nothing moved
-    for wl in BENCH["workloads"]:
-        assert METRIC in [m["name"] for m in harness.metrics_for(
-            BENCH, "per_layer", wl["name"])]
+    """The entry as PR 40 wrote it but for its list (it named no cell then;
+    since a cell whose model holds no key it lists the cells that read it),
+    appended after the entries that stood before it, and due in each cell
+    of its list, the four it was written for among them."""
+    entry = per_layer(BENCH, METRIC)
+    assert but_its_list(entry) == {
+        "name": METRIC, "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": "kernels", "moves": "gap_ms.p95"}
+    assert stands_after(BENCH, [METRIC], [
+        "block.prefill_attn_ms", "block.known_ops_pct",
+        "engine.prefill_padding_pct"])       # appended, nothing moved
+    cells = entry.get("workloads", [w["name"] for w in BENCH["workloads"]])
+    assert cells[:4] == [w["name"] for w in BENCH["workloads"][:4]]
+    assert all(due(BENCH, METRIC, cell) for cell in cells)

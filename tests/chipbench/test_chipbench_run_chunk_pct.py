@@ -7,6 +7,7 @@ from pathlib import Path
 import pytest
 
 from chipbench import harness
+from chipbench_entries import but_its_list, due, per_layer, stands_after
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH, SEARCH = harness.load_bench(ROOT / "BENCHMARK.json")
@@ -29,10 +30,16 @@ def test_the_reader_gives_the_ratio_or_nothing(recent, want):
 
 
 def test_both_cells_print_it_and_it_moves_the_pace():
-    (entry,) = [m for m in BENCH["per_layer"] if m["name"] == NAME]
-    assert entry == {"name": NAME, "unit": "%", "better": "higher",
-                     "source": "program_counter", "layer": "kernels",
-                     "moves": "tpot_ms.p90"}
-    for cell in BENCH["workloads"]:
-        assert NAME in [m["name"] for m in harness.metrics_for(
-            BENCH, "per_layer", cell["name"])]
+    """The entry as PR 33 wrote it but for its list, after the entry that
+    stood before it, and due in each cell of its list: the two cells of its
+    day first."""
+    entry = per_layer(BENCH, NAME)
+    assert but_its_list(entry) == {
+        "name": NAME, "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "kernels",
+        "moves": "tpot_ms.p90"}
+    assert stands_after(BENCH, [NAME], ["device.idle_unfed_pct",
+                                        "engine.prefill_behind_steps"])
+    cells = entry.get("workloads", [w["name"] for w in BENCH["workloads"]])
+    assert cells[:2] == ["qwen25-7b-int8.chat", "qwen25-3b-bf16.agent-prefix"]
+    assert all(due(BENCH, NAME, cell) for cell in cells)
